@@ -19,14 +19,16 @@ import numpy as np
 from deepspeed_tpu.utils.logging import logger
 
 
+# Scale compute_floor_seconds ranks on when the device has no peak entry.
+_RANKING_NOMINAL_KIND = "TPU v5 lite"
+
+
 def step_flops_bytes(engine, batches, lr) -> Dict[str, float]:
     """flops / bytes-accessed of the engine's CURRENT fused step, from
     the compiled executable's cost analysis (the XLA compilation cache
     dedupes the binary against the step the engine runs anyway)."""
     lowered = engine._train_step.lower(engine.state, batches, lr)
     cost = lowered.compile().cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0))}
 
@@ -36,15 +38,19 @@ def compute_floor_seconds(flops: float, bytes_accessed: float,
                           dtype: str) -> float:
     """Roofline floor of the whole global step: the slower of the
     compute and HBM ceilings at the chip-kind peaks (flops_profiler's
-    tables — the one source every MFU divides by)."""
+    tables — the one source every MFU divides by). A device kind with
+    no table entry (the CPU test mesh) is ranked on the v5e scale: the
+    result only orders candidates against each other and never leaves
+    the ranking, so a nominal scale is enough there."""
     from deepspeed_tpu.profiling.flops_profiler import (peak_hbm_gbps,
                                                         peak_tflops)
 
     chips = max(int(n_chips), 1)
-    f = (flops / (chips * peak_tflops(device_kind, dtype) * 1e12)
-         if flops > 0 else 0.0)
-    b = (bytes_accessed / (chips * peak_hbm_gbps(device_kind) * 1e9)
-         if bytes_accessed > 0 else 0.0)
+    tflops = (peak_tflops(device_kind, dtype)
+              or peak_tflops(_RANKING_NOMINAL_KIND, dtype))
+    gbps = peak_hbm_gbps(device_kind) or peak_hbm_gbps(_RANKING_NOMINAL_KIND)
+    f = flops / (chips * tflops * 1e12) if flops > 0 else 0.0
+    b = bytes_accessed / (chips * gbps * 1e9) if bytes_accessed > 0 else 0.0
     return max(f, b)
 
 

@@ -90,7 +90,7 @@ from deepspeed_tpu.comm.quantize import (dequantize_blockwise,
                                          roundtrip_error_parts)
 from deepspeed_tpu.parallel.mesh import (DATA_AXIS, DCN_AXIS,
                                          axes_size as mesh_axes_size)
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from deepspeed_tpu.utils.logging import log_dist
 
 _MB = 1 << 20

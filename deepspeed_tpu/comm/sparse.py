@@ -23,7 +23,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.parallel.mesh import DATA_AXIS

@@ -4,6 +4,7 @@ builders, reports the JAX/TPU stack and which framework features are
 usable in this environment."""
 
 import importlib
+import os
 import sys
 
 
@@ -33,30 +34,39 @@ def collect_report() -> dict:
     for pkg in ("jax", "jaxlib", "flax", "optax", "orbax.checkpoint", "numpy"):
         report["packages"][pkg] = _try_import(pkg)
 
-    try:
-        import jax
+    # A backend that cannot start raises here: a report that printed
+    # "unavailable" and went on to list features would hide the failure.
+    import jax
 
-        report["platform"] = jax.devices()[0].platform
-        report["devices"] = [str(d) for d in jax.devices()]
-        report["process_count"] = jax.process_count()
-    except Exception as e:  # no backend
-        report["platform"] = f"unavailable ({e})"
+    from deepspeed_tpu.ops.kernel_cases import kernel_cases
+    from deepspeed_tpu.profiling.flops_profiler import TPU_PEAK_TFLOPS
+    from deepspeed_tpu.utils.compile_cache import (CACHE_DIR_ENV,
+                                                   DEFAULT_CACHE_DIR)
 
-    on_tpu = report["platform"] == "tpu"
-    # Enumerated, not a single boolean: which Pallas kernels are LIVE in
-    # this environment (compiled on TPU; all of them run through the
-    # interpreter for off-TPU parity tests, which is not "live").
-    report["pallas_kernels"] = {
-        "flash_attention": on_tpu,
-        "sparse_attention": on_tpu,
-        "paged_decode_attention": on_tpu,
-        "chunked_prefill": on_tpu,
-        "fused_adam_update": on_tpu,
-    }
+    dev = jax.devices()[0]
+    report["platform"] = dev.platform
+    report["device_kind"] = dev.device_kind
+    report["device_count"] = len(jax.devices())
+    report["devices"] = [str(d) for d in jax.devices()]
+    report["process_count"] = jax.process_count()
+    # Is there a published peak for this chip? Without one no MFU,
+    # roofline verdict or bench row is reported (flops_profiler).
+    report["device_kind_in_peak_table"] = dev.device_kind in TPU_PEAK_TFLOPS
+    # The compile-cache directory in force for the repo's entry scripts
+    # (utils/compile_cache.py: the env var if set, else the in-checkout
+    # default). Read, not configured: a report changes nothing.
+    report["compile_cache_dir"] = (
+        os.environ.get(CACHE_DIR_ENV) or DEFAULT_CACHE_DIR)
+
+    # How the Pallas kernels DISPATCH here — not whether they work: that
+    # a kernel compiles for the chip is established by
+    # tests/test_tpu_lowering.py (chipless v5e compile) and that it is
+    # right there by chip_smoke.py, not by the platform string.
+    report["pallas_dispatch"] = (
+        "mosaic (compiled for the TPU)" if dev.platform == "tpu"
+        else "interpreter (off-TPU: correctness only)")
+    report["pallas_kernels"] = [case.name for case in kernel_cases()]
     report["features"] = {
-        "pallas_kernels": ", ".join(
-            k for k, ok in report["pallas_kernels"].items() if ok)
-        or "none (interpret-only off TPU)",
         "xla_reference_ops": report["packages"]["jax"] is not None,
         "multihost (jax.distributed)": report["packages"]["jax"] is not None,
         "zero_stages_0_3": True,
@@ -82,16 +92,21 @@ def main():
         mark = GREEN_OK if ver else RED_NO
         print(f"{pkg:22s} {mark} {ver or 'not installed'}")
     print(f"platform ............... {report['platform']}")
+    print(f"device kind ............ {report['device_kind']} "
+          f"x {report['device_count']}")
+    in_table = report["device_kind_in_peak_table"]
+    print(f"in peak tables ......... {GREEN_OK if in_table else RED_NO} "
+          f"{'MFU/roofline reported' if in_table else 'no MFU/roofline'}")
+    print(f"compile cache .......... {report['compile_cache_dir']}")
     for d in report["devices"]:
         print(f"  device: {d}")
     print("-" * 60)
+    print(f"pallas kernels dispatch to: {report['pallas_dispatch']}")
+    for k in report["pallas_kernels"]:
+        print(f"  {k}")
+    print("-" * 60)
     print("feature availability")
     for feat, ok in report["features"].items():
-        if feat == "pallas_kernels":
-            live = [k for k, on in report["pallas_kernels"].items() if on]
-            mark = GREEN_OK if live else RED_NO
-            print(f"  {mark} pallas_kernels: {ok}")
-            continue
         print(f"  {GREEN_OK if ok else RED_NO} {feat}")
     print("-" * 60)
     print("op registry (op_builder analogue)")

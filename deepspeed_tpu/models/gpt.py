@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.ops.transformer.attention import attention
 from deepspeed_tpu.ops.xent import fused_cross_entropy
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 from deepspeed_tpu.ops.dropout import dropout_module as _dropout_mod
@@ -158,7 +159,7 @@ def _use_fused_ln(cfg, x) -> frozenset:
     if mode is not True and mode not in ("auto", "qkv", "mlp"):
         raise ValueError(f"unknown fused_ln value {mode!r}: expected False, "
                          "True, 'auto', 'qkv', or 'mlp'")
-    if mode == "auto" and jax.devices()[0].platform != "tpu":
+    if mode == "auto" and not on_tpu():
         return frozenset()
     from deepspeed_tpu.ops.transformer.fused import ln_matmul_ok
 

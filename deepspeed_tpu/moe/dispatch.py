@@ -54,7 +54,7 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.parallel.mesh import (EXPERT_AXIS, axes_size,
                                          get_default_mesh,
                                          moe_dispatch_axes)
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _resolve_mesh(mesh):

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from deepspeed_tpu.ops.adam.fused_adam import AdamState, FusedAdam
+from deepspeed_tpu.utils.platform import on_tpu
 
 __all__ = ["fused_adam_leaf", "fused_adam_apply", "fused_update_cost"]
 
@@ -40,13 +41,6 @@ _LANE = 128
 # Max block rows per grid step; multiple of 16 so an optional bf16 cast
 # output tiles on the sublane dim too (f32 needs 8, bf16 needs 16).
 _MAX_ROWS = 256
-
-
-def _use_interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # pragma: no cover - no backend
-        return True
 
 
 def fused_adam_update_kernel(sc_ref, p_ref, g_ref, m_ref, v_ref, *out_refs,
@@ -91,7 +85,7 @@ def fused_adam_leaf(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
     ``(p', m', v')`` in the leaf's shape — plus ``p'.astype(cast_dtype)``
     when ``cast_dtype`` is set (the compute-param cast rides the same
     HBM round-trip)."""
-    interpret = _use_interpret() if interpret is None else interpret
+    interpret = not on_tpu() if interpret is None else interpret
     shape = p.shape
     n = int(p.size)
     if n == 0:

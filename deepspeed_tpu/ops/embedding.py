@@ -51,7 +51,7 @@ def _make_lookup_sparse(mesh, axes):
     tensors; here the exchange is an all_gather of (ids, per-token rows)
     inside the op's custom VJP, wire bytes ∝ batch tokens, then a local
     scatter-add rebuilds the dense gradient on every rank)."""
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.comm.sparse import row_sparse_allreduce, scatter_rows

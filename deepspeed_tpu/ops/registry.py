@@ -11,7 +11,7 @@ and whether the current backend can run them.
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional
 
-import jax
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 @dataclass(frozen=True)
@@ -25,15 +25,9 @@ class OpSpec:
 
     def available(self) -> bool:
         if self.available_fn is not None:
-            try:
-                return bool(self.available_fn())
-            except Exception:
-                return False
+            return bool(self.available_fn())
         if self.requires_tpu:
-            try:
-                return jax.devices()[0].platform == "tpu"
-            except Exception:
-                return False
+            return on_tpu()
         return True
 
     def load(self):

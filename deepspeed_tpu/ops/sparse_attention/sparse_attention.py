@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.transformer.flash_attention import _vmem_params
+from deepspeed_tpu.utils.platform import on_tpu
 
 NEG_INF = -1e30
 
@@ -476,7 +477,7 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(f"layout has {np.asarray(layout).shape[1]} blocks, "
                          f"sequence needs {s // block}")
     scale = softmax_scale if softmax_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    on_tpu = jax.devices()[0].platform == "tpu"
+    tpu = on_tpu()
     # Mosaic lane-alignment constraint: the masked kernels slice the
     # [B, 1, S] mask on its LANE dim at the dynamic per-row column offset
     # (col*block), which TPU lowering only admits when it is provably a
@@ -485,12 +486,12 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # mode (CPU) has no such constraint.
     masked_pallas_ok = key_mask is None or block % 128 == 0
     if impl == "auto":
-        impl = ("pallas" if on_tpu and masked_pallas_ok else "xla")
+        impl = ("pallas" if tpu and masked_pallas_ok else "xla")
     if impl == "xla":
         return _xla_sparse(q, k, v, layout, block, causal, scale, key_mask)
     if impl == "pallas":
         if interpret is None:
-            interpret = not on_tpu
+            interpret = not tpu
         if not interpret and not masked_pallas_ok:
             raise ValueError(
                 f"key_mask with block={block} cannot lower to Mosaic "

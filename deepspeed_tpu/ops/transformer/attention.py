@@ -17,17 +17,15 @@ tensors. ``impl``:
 """
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend
-        return False
+from deepspeed_tpu.utils.logging import logger
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -138,6 +136,61 @@ def _pallas_ok(q, k, bias, mask, dropout_active: bool = False):
     return True
 
 
+def _flash_per_shard(flash, mesh, q, k, v, kv_mask, dropout_rng):
+    """``flash(q, k, v, kv_mask, dropout_rng)`` — once per device shard on
+    a multi-device mesh.
+
+    GSPMD cannot partition a Mosaic custom call (jax refuses the lowering:
+    "Mosaic kernels cannot be automatically partitioned"), so under a
+    multi-device ``jit`` the kernel must sit in a region that is manual
+    over EVERY mesh axis. Attention is independent over batch and heads:
+    the batch splits over the data-like axes, the heads over the model
+    axis, and nothing else is split (a sequence-sharded input is gathered;
+    ring/Ulysses are the sequence-parallel implementations).
+
+    The mesh is the manual region's own when tracing inside one (pipeline
+    stages, the hierarchical grad sync), else ``mesh`` — the caller's, or
+    the one the tracing engine pinned. With no mesh known the kernel is
+    called as is: right on one device, and jax's own loud error on many.
+    """
+    from deepspeed_tpu.parallel.mesh import (DATA_AXIS, DCN_AXIS, MODEL_AXIS,
+                                             get_pinned_mesh)
+
+    ctx = jax.sharding.get_abstract_mesh()
+    if not ctx.empty:
+        mesh, manual = ctx, frozenset(ctx.manual_axes)
+    else:
+        mesh = mesh if mesh is not None else get_pinned_mesh()
+        manual = frozenset()
+    free = (frozenset(mesh.axis_names) - manual
+            if mesh is not None and mesh.size > 1 else frozenset())
+    if not free:        # one device, or already per-shard on every axis
+        return flash(q, k, v, kv_mask, dropout_rng)
+
+    def split_over(dim, names):
+        # the free axes among `names`, if together they divide `dim`
+        names = tuple(a for a in names if a in free and mesh.shape[a] > 1)
+        size = math.prod(mesh.shape[a] for a in names)
+        return names if names and dim % size == 0 else None
+
+    batch = split_over(q.shape[0], (DCN_AXIS, DATA_AXIS))
+    heads = split_over(q.shape[2], (MODEL_AXIS,))
+    qkv = PartitionSpec(batch, None, heads, None)
+    # kv_mask / dropout_rng may be None: an empty pytree, spec ignored
+    in_specs = (qkv, qkv, qkv, PartitionSpec(batch, None), PartitionSpec())
+
+    def shard(q, k, v, kv_mask, rng):
+        if rng is not None:
+            # decorrelate the in-kernel dropout masks across shards
+            for axis in (batch or ()) + (heads or ()):
+                rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
+        return flash(q, k, v, kv_mask, rng)
+
+    return jax.shard_map(shard, mesh=mesh, in_specs=in_specs, out_specs=qkv,
+                         axis_names=free, check_vma=False)(
+                             q, k, v, kv_mask, dropout_rng)
+
+
 def _padded_flash(q, k, v, *, causal, kv_mask, softmax_scale, dropout_rate,
                   dropout_rng, pad_to: int = 512):
     """Run the flash kernel on sequences padded up to a full-block multiple,
@@ -170,6 +223,15 @@ def _padded_flash(q, k, v, *, causal, kv_mask, softmax_scale, dropout_rate,
     return out[:, :sq]
 
 
+@functools.lru_cache(maxsize=None)
+def _log_auto_choice(impl: str, sq: int, sk: int, head_dim: int) -> None:
+    """Say once per shape which implementation ``impl="auto"`` resolved to
+    (the cache is the once) — a run that was meant to use the flash kernel
+    and landed on the XLA path must be visible in its log."""
+    logger.info(f"attention impl=auto -> {impl} "
+                f"(seq_q={sq}, seq_k={sk}, head_dim={head_dim})")
+
+
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = False,
               bias: Optional[jax.Array] = None,
@@ -183,17 +245,21 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Dispatching attention entry point used by every model family."""
     dropout_active = dropout_rate > 0.0 and not deterministic
     if impl == "auto":
-        impl = ("pallas" if _on_tpu() and _pallas_ok(
+        impl = ("pallas" if on_tpu() and _pallas_ok(
             q, k, bias, mask, dropout_active) else "xla")
+        _log_auto_choice(impl, q.shape[1], k.shape[1], q.shape[-1])
     if impl == "pallas_pad":
         kv_mask = _as_kv_mask(mask, q.shape[0], k.shape[1])
         if bias is not None or (mask is not None and kv_mask is None):
             raise ValueError("impl='pallas_pad' takes only key-padding "
                              "masks, like impl='pallas'")
         rate = dropout_rate if dropout_active else 0.0
-        return _padded_flash(q, k, v, causal=causal, kv_mask=kv_mask,
-                             softmax_scale=softmax_scale, dropout_rate=rate,
-                             dropout_rng=dropout_rng)
+        return _flash_per_shard(
+            lambda q, k, v, kv_mask, rng: _padded_flash(
+                q, k, v, causal=causal, kv_mask=kv_mask,
+                softmax_scale=softmax_scale, dropout_rate=rate,
+                dropout_rng=rng),
+            mesh, q, k, v, kv_mask, dropout_rng)
     if impl == "pallas":
         kv_mask = _as_kv_mask(mask, q.shape[0], k.shape[1])
         if bias is not None or (mask is not None and kv_mask is None):
@@ -204,9 +270,12 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
 
         rate = dropout_rate if dropout_active else 0.0
-        return flash_attention(q, k, v, causal=causal, kv_mask=kv_mask,
-                               softmax_scale=softmax_scale,
-                               dropout_rate=rate, dropout_rng=dropout_rng)
+        return _flash_per_shard(
+            lambda q, k, v, kv_mask, rng: flash_attention(
+                q, k, v, causal=causal, kv_mask=kv_mask,
+                softmax_scale=softmax_scale, dropout_rate=rate,
+                dropout_rng=rng),
+            mesh, q, k, v, kv_mask, dropout_rng)
     if impl == "xla":
         return xla_attention(q, k, v, causal=causal, bias=bias, mask=mask,
                              dropout_rate=dropout_rate, dropout_rng=dropout_rng,

@@ -13,13 +13,15 @@ row of the block table, so segments of any length coexist in one launch
 and the program never retraces as traffic shifts (one compile ever —
 recompile-detector-proven in tests).
 
-Grid: ``(tokens, heads, table_width)`` with the table walk innermost —
-each ``(t, h)`` pair streams its sequence's pool blocks through VMEM
-accumulating the online-softmax running max / normaliser / fp32
-accumulator, exactly the ``paged_attention.py`` recurrence with a single
-query row. The per-token table (the sequence's block-table row, gathered
-host-side by slot) and positions ride as scalar prefetch so the DMA
-engine chases the block ids.
+The attention itself is the ``paged_attention.py`` kernel with one query
+row: a ragged token is a one-token "sequence" with its own block-table
+row and position, so the grid is ``(tokens, heads, table_width)`` with
+the table walk innermost — each ``(t, h)`` pair streams its sequence's
+pool blocks through VMEM under the same online-softmax recurrence. The
+per-token table (the sequence's block-table row, gathered host-side by
+slot) and positions ride as scalar prefetch so the DMA engine chases the
+block ids. One kernel body serves decode, speculative verify and the
+ragged mixed step, so there is one thing to keep lowering on the chip.
 
 Masking is per ragged segment: key position ``j`` is visible to token
 ``t`` iff ``j <= pos[t]`` — within a prefill chunk every token sees the
@@ -30,92 +32,18 @@ in-kernel with the PR 15 whole-heads ``[BS, H]`` scale-block layout.
 
 ``interpret=True`` (automatic off-TPU) runs the same kernel through the
 Pallas interpreter so CPU tier-1 parity tests cover the real kernel
-arithmetic.
+arithmetic. The compiled kernel's geometry gate is the kernel's own:
+``paged_attention.paged_decode_ok``.
 """
 
-import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.transformer.flash_attention import LANES, NEG_INF
+from deepspeed_tpu.ops.transformer.paged_attention import \
+    paged_decode_attention
 
-__all__ = ["chunked_prefill_attention", "chunked_prefill_ok"]
-
-
-def _use_interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # pragma: no cover - no backend
-        return True
-
-
-def chunked_prefill_ok(head_dim: int, block_size: int) -> bool:
-    """Auto-dispatch gate (same tiling law as ``paged_decode_ok``): the
-    lane dim is the head_dim (128-multiple) and each streamed K/V block
-    is a ``[block_size, head_dim]`` tile (sublane dim: 8-multiple). On
-    geometries that fail, the engine falls back to the bucketed path —
-    and the interpret path used by CPU tier-1 takes any shape."""
-    return head_dim % 128 == 0 and block_size % 8 == 0
-
-
-def chunked_prefill_attention_kernel(tbl_ref, pos_ref, *refs, scale: float,
-                                     block_size: int, int8: bool):
-    if int8:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr, acc = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc = refs
-        ks_ref = vs_ref = None
-    ti = pl.program_id(0)
-    hi = pl.program_id(1)
-    wi = pl.program_id(2)
-    num_w = pl.num_programs(2)
-
-    @pl.when(wi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc[...] = jnp.zeros_like(acc)
-
-    q = q_ref[0, 0, :][None, :].astype(jnp.float32) * scale   # [1, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                 # [BS, D]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    if int8:
-        # In-kernel dequant: whole-heads [BS, H] scale blocks, this
-        # head's column sliced in kernel (paged_attention.py layout).
-        ks = jax.lax.dynamic_slice_in_dim(ks_ref[0], hi, 1, axis=1)
-        vs = jax.lax.dynamic_slice_in_dim(vs_ref[0], hi, 1, axis=1)
-        k = k * ks                                            # [BS, 1]
-        v = v * vs
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [1, BS]
-    # Ragged-segment causal visibility: key j visible to this token iff
-    # j <= pos[t]. A prefill chunk's later tokens (written this same
-    # step at j > pos[t]) and scratch-pointing table tail entries are
-    # masked out exactly like the gather path's kpos <= qpos mask.
-    kpos = wi * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    s = jnp.where(kpos <= pos_ref[ti], s, NEG_INF)
-
-    m_prev = m_scr[:, 0]                                      # [1]
-    l_prev = l_scr[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1)
-    acc[...] = acc[...] * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
-
-    @pl.when(wi == num_w - 1)
-    def _finish():
-        l_safe = jnp.maximum(l_scr[:, 0], 1e-30)
-        o_ref[0, 0, :] = (acc[...] / l_safe[:, None])[0].astype(o_ref.dtype)
+__all__ = ["chunked_prefill_attention"]
 
 
 def chunked_prefill_attention(q: jax.Array, k_pool: jax.Array,
@@ -139,49 +67,10 @@ def chunked_prefill_attention(q: jax.Array, k_pool: jax.Array,
     written into the pools (``ChunkedLayerCache.update_attend`` does
     both).
     """
-    t, h, d = q.shape
-    wb = table.shape[1]
-    bs = int(block_size)
-    if k_pool.shape[1] != bs:
-        raise ValueError(f"pool block size {k_pool.shape[1]} != {bs}")
-    scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
-    interpret = _use_interpret() if interpret is None else interpret
-    int8 = k_scale is not None
-
-    kernel = functools.partial(chunked_prefill_attention_kernel,
-                               scale=float(scale), block_size=bs, int8=int8)
-    in_specs = [
-        pl.BlockSpec((1, 1, d), lambda ti, hi, wi, tb, p: (ti, hi, 0)),
-        pl.BlockSpec((1, bs, 1, d),
-                     lambda ti, hi, wi, tb, p: (tb[ti, wi], 0, hi, 0)),
-        pl.BlockSpec((1, bs, 1, d),
-                     lambda ti, hi, wi, tb, p: (tb[ti, wi], 0, hi, 0)),
-    ]
-    inputs = [q, k_pool, v_pool]
-    if int8:
-        in_specs += [
-            pl.BlockSpec((1, bs, h),
-                         lambda ti, hi, wi, tb, p: (tb[ti, wi], 0, 0)),
-            pl.BlockSpec((1, bs, h),
-                         lambda ti, hi, wi, tb, p: (tb[ti, wi], 0, 0)),
-        ]
-        inputs += [k_scale, v_scale]
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,        # per-token table + positions
-            grid=(t, h, wb),              # table walk innermost: scratch
-                                          # accumulates per (token, head)
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, d), lambda ti, hi, wi, tb, p: (ti, hi, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, LANES), jnp.float32),   # running max
-                pltpu.VMEM((1, LANES), jnp.float32),   # normaliser
-                pltpu.VMEM((1, d), jnp.float32),       # fp32 accumulator
-            ]),
-        out_shape=jax.ShapeDtypeStruct((t, h, d), q.dtype),
-        interpret=interpret,
-    )(table.astype(jnp.int32), pos.astype(jnp.int32), *inputs)
-    return out
+    # Token t == a paged "sequence" with one query at position pos[t]:
+    # the decode kernel's visibility rule j <= pos + i at i = 0.
+    out = paged_decode_attention(q[:, None], k_pool, v_pool, k_scale,
+                                 v_scale, table, pos, block_size=block_size,
+                                 softmax_scale=softmax_scale,
+                                 interpret=interpret)
+    return out[:, 0]

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.utils.platform import on_tpu
+
 # Measured on v5e at seq 4096 (fwd+bwd, d=64): 128x128 blocks run at
 # ~1 TF/s (grid/stream overhead dominates) while 512x1024 reaches ~31 TF/s
 # — large blocks keep the MXU fed and amortize the per-program K/V stream.
@@ -44,10 +46,6 @@ DEFAULT_BLOCK_K = 1024
 LANES = 128   # TPU lane width: per-row scalars (lse/delta) are broadcast
               # across the lane dim so their blocks satisfy (8,128) tiling
 NEG_INF = -1e30
-
-
-def _use_interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
 
 
 def fit_block(block: int, seq: int) -> int:
@@ -530,7 +528,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError(f"seq lengths ({sq},{sk}) must divide blocks "
                          f"({block_q},{block_k})")
     scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
-    interpret = _use_interpret() if interpret is None else interpret
+    interpret = not on_tpu() if interpret is None else interpret
     dropout_rate = float(dropout_rate)
     if dropout_rate > 0.0:
         if dropout_rng is None:

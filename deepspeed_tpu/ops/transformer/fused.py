@@ -33,8 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.transformer.flash_attention import (_use_interpret,
-                                                           _vmem_params)
+from deepspeed_tpu.ops.transformer.flash_attention import _vmem_params
+from deepspeed_tpu.utils.platform import on_tpu
 
 DEFAULT_BLOCK_ROWS = 512
 _SQRT_2_OVER_PI = 0.7978845608028654
@@ -265,7 +265,7 @@ def ln_matmul(x: jax.Array, gamma: jax.Array, beta: jax.Array,
         raise ValueError(f"shapes (n={n}, d={d}, f={f}) not tileable with "
                          f"block_rows={block_rows} — gate with "
                          "ln_matmul_ok()")
-    interpret = _use_interpret() if interpret is None else interpret
+    interpret = not on_tpu() if interpret is None else interpret
     out = _ln_matmul(x.reshape(n, d), gamma, beta, w, bias, float(eps),
                      activation, block_rows, interpret)
     return out.reshape(*lead, f)

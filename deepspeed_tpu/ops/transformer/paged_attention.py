@@ -44,26 +44,48 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.transformer.flash_attention import LANES, NEG_INF
+from deepspeed_tpu.utils.platform import on_tpu
 
 __all__ = ["paged_decode_attention", "paged_decode_ok"]
 
 
-def _use_interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # pragma: no cover - no backend
-        return True
-
-
 def paged_decode_ok(head_dim: int, block_size: int) -> bool:
     """Auto-dispatch gate (``attention.py`` style): can the compiled
-    kernel tile this cache geometry on the MXU/VPU? The lane dim is the
-    head_dim (must be a 128-multiple) and each streamed K/V block is a
-    ``[block_size, head_dim]`` tile (sublane dim: 8-multiple). Shapes
-    that fail fall back to the (capped) gather path — and the interpret
-    path used by CPU tier-1 takes any shape, so parity tests force
-    ``impl="kernel"`` instead of relying on this gate."""
+    kernel tile this cache geometry on the MXU/VPU? Each streamed K/V
+    block is a ``[block_size, head_dim]`` tile cut out of the pool's
+    ``[BS, H*D]`` rows: the lane dim is the head_dim (128-multiple, so a
+    head's columns start on a lane-tile boundary) and the sublane dim
+    the block size (8-multiple). Shapes that fail fall back to the
+    (capped) gather path — and the interpret path used by CPU tier-1
+    takes any shape, so parity tests force ``impl="kernel"`` instead of
+    relying on this gate. ``tests/test_tpu_lowering.py`` compiles the
+    kernel for a v5e at the smallest geometry this gate admits."""
     return head_dim % 128 == 0 and block_size % 8 == 0
+
+
+def _head_scale_column(scale_ref, hi):
+    """This head's ``[BS, 1]`` column of a whole-heads ``[BS, H]`` scale
+    block. A masked lane reduction, not a dynamic lane slice: Mosaic
+    refuses ``dynamic_slice`` at a traced offset on the lane axis."""
+    sc = scale_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    return jnp.sum(jnp.where(lane == hi, sc, 0.0), axis=1, keepdims=True)
+
+
+def _online_softmax_step(s, v, m_scr, l_scr, acc):
+    """One block of the online-softmax recurrence on masked scores ``s``
+    [S, BS] and values ``v`` [BS, D], updating the running max /
+    normaliser ([S, LANES], lane-broadcast) and the fp32 accumulator
+    [S, D] in VMEM scratch. Everything stays 2-D (keepdims) so a
+    single-row query (decode, ``S == 1``) tiles like any other."""
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new[:, :1])
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc[...] = acc[...] * alpha[:, :1] + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
 
 
 def _decode_kernel(bt_ref, pos_ref, *refs, scale: float, block_size: int,
@@ -83,20 +105,18 @@ def _decode_kernel(bt_ref, pos_ref, *refs, scale: float, block_size: int,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc[...] = jnp.zeros_like(acc)
 
-    hi = pl.program_id(1)
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale        # [S, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                # [BS, D]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32) * scale               # [S, D]
+    k = k_ref[...].astype(jnp.float32)                       # [BS, D]
+    v = v_ref[...].astype(jnp.float32)
     if int8:
         # In-kernel dequant: the pool's per-(token, head) RTNE scales
         # ride as whole-heads [BS, H] blocks (trailing dim equals the
-        # array's — mosaic tiling) and this head's column is sliced in
-        # kernel. Scale traffic stays proportional to the streamed
-        # blocks; the fp K/V copy exists only as this VMEM block.
-        ks = jax.lax.dynamic_slice_in_dim(ks_ref[0], hi, 1, axis=1)
-        vs = jax.lax.dynamic_slice_in_dim(vs_ref[0], hi, 1, axis=1)
-        k = k * ks                                           # [BS, 1]
-        v = v * vs
+        # array's — mosaic tiling). Scale traffic stays proportional to
+        # the streamed blocks; the fp K/V copy exists only as this VMEM
+        # block.
+        hi = pl.program_id(1)
+        k = k * _head_scale_column(ks_ref, hi)               # [BS, 1]
+        v = v * _head_scale_column(vs_ref, hi)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [S, BS]
@@ -109,22 +129,12 @@ def _decode_kernel(bt_ref, pos_ref, *refs, scale: float, block_size: int,
     qpos = pos_ref[bi] + jax.lax.broadcasted_iota(
         jnp.int32, (num_q, block_size), 0)
     s = jnp.where(kpos <= qpos, s, NEG_INF)
-
-    m_prev = m_scr[:, 0]                                     # [S]
-    l_prev = l_scr[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1)
-    acc[...] = acc[...] * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+    _online_softmax_step(s, v, m_scr, l_scr, acc)
 
     @pl.when(wi == num_w - 1)
     def _finish():
-        l_safe = jnp.maximum(l_scr[:, 0], 1e-30)
-        o_ref[0, :, 0, :] = (acc[...] / l_safe[:, None]).astype(o_ref.dtype)
+        l_safe = jnp.maximum(l_scr[:, :1], 1e-30)
+        o_ref[...] = (acc[...] / l_safe).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
@@ -147,35 +157,36 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     written into the pools (``PagedLayerCache.update_attend`` does both).
     """
     b, s, h, d = q.shape
+    n = k_pool.shape[0]
     wb = block_table.shape[1]
     bs = int(block_size)
     if k_pool.shape[1] != bs:
         raise ValueError(f"pool block size {k_pool.shape[1]} != {bs}")
     scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
-    interpret = _use_interpret() if interpret is None else interpret
+    interpret = not on_tpu() if interpret is None else interpret
     int8 = k_scale is not None
 
     kernel = functools.partial(_decode_kernel, scale=float(scale),
                                block_size=bs, num_q=s, int8=int8)
-    in_specs = [
-        pl.BlockSpec((1, s, 1, d), lambda bi, hi, wi, bt, p: (bi, 0, hi, 0)),
-        pl.BlockSpec((1, bs, 1, d),
-                     lambda bi, hi, wi, bt, p: (bt[bi, wi], 0, hi, 0)),
-        pl.BlockSpec((1, bs, 1, d),
-                     lambda bi, hi, wi, bt, p: (bt[bi, wi], 0, hi, 0)),
-    ]
-    inputs = [q, k_pool, v_pool]
+    # Heads fold into the lane axis ([.., H, D] -> [.., H*D], a free
+    # reshape of trailing contiguous dims — no relayout of the donated,
+    # per-step-rewritten pools): a block is then one head's D columns,
+    # (rows, D) on the last two dims, which is what Mosaic tiles. A
+    # (.., 1, D) block over [.., H, D] puts 1 on the sublane axis and is
+    # refused at lowering.
+    q_spec = pl.BlockSpec((None, s, d), lambda bi, hi, wi, bt, p: (bi, 0, hi))
+    kv_spec = pl.BlockSpec((None, bs, d),
+                           lambda bi, hi, wi, bt, p: (bt[bi, wi], 0, hi))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    inputs = [q.reshape(b, s, h * d), k_pool.reshape(n, bs, h * d),
+              v_pool.reshape(n, bs, h * d)]
     if int8:
-        # Whole-heads (1, BS, H) scale blocks straight from the pool
-        # layout — no relayout of the (donated, per-step-rewritten)
-        # scale pools; the kernel slices its head's column. H extra
-        # lanes per block is noise next to the [BS, D] K/V stream.
-        in_specs += [
-            pl.BlockSpec((1, bs, h),
-                         lambda bi, hi, wi, bt, p: (bt[bi, wi], 0, 0)),
-            pl.BlockSpec((1, bs, h),
-                         lambda bi, hi, wi, bt, p: (bt[bi, wi], 0, 0)),
-        ]
+        # Whole-heads (BS, H) scale blocks straight from the pool
+        # layout; the kernel picks its head's column. H extra lanes per
+        # block is noise next to the [BS, D] K/V stream.
+        sc_spec = pl.BlockSpec((None, bs, h),
+                               lambda bi, hi, wi, bt, p: (bt[bi, wi], 0, 0))
+        in_specs += [sc_spec, sc_spec]
         inputs += [k_scale, v_scale]
 
     out = pl.pallas_call(
@@ -185,14 +196,13 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
             grid=(b, h, wb),              # table walk innermost: scratch
                                           # accumulates per (seq, head)
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, s, 1, d), lambda bi, hi, wi, bt, p: (bi, 0, hi, 0)),
+            out_specs=q_spec,
             scratch_shapes=[
                 pltpu.VMEM((s, LANES), jnp.float32),   # running max
                 pltpu.VMEM((s, LANES), jnp.float32),   # normaliser
                 pltpu.VMEM((s, d), jnp.float32),       # fp32 accumulator
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
         interpret=interpret,
     )(block_table.astype(jnp.int32), pos.astype(jnp.int32), *inputs)
-    return out
+    return out.reshape(b, s, h, d)

@@ -12,6 +12,7 @@ collective and should ride ICI neighbours; pipe is outermost since stage p2p
 traffic is the lightest.
 """
 
+import contextlib
 import os
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
@@ -57,8 +58,7 @@ def init_distributed(dist_backend: str = "xla",
     # NB: must not touch jax.devices()/process_count() here — any backend
     # query initialises the local runtime and jax.distributed.initialize
     # would then be too late.
-    from deepspeed_tpu.utils.jax_compat import distributed_is_initialized
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return
     coordinator_address = coordinator_address or os.environ.get("DSTPU_COORDINATOR")
     if coordinator_address is None and "MASTER_ADDR" in os.environ:
@@ -194,7 +194,29 @@ def set_default_mesh(mesh: Optional[Mesh]) -> None:
 
 
 def get_default_mesh() -> Optional[Mesh]:
-    return _DEFAULT_MESH
+    return _PINNED_MESH if _PINNED_MESH is not None else _DEFAULT_MESH
+
+
+# Trace-scoped mesh: an engine pins ITS mesh while its model code is being
+# traced, so mesh-needing ops bind to the engine that is tracing them and
+# never to whichever engine registered the ambient default first (or to
+# one that no longer exists). Unlike the ambient default it is never left
+# behind: outside a pin there is none.
+_PINNED_MESH: Optional[Mesh] = None
+
+
+@contextlib.contextmanager
+def pinned_mesh(mesh: Mesh):
+    global _PINNED_MESH
+    prev, _PINNED_MESH = _PINNED_MESH, mesh
+    try:
+        yield
+    finally:
+        _PINNED_MESH = prev
+
+
+def get_pinned_mesh() -> Optional[Mesh]:
+    return _PINNED_MESH
 
 
 def data_sharding(mesh: Mesh, batch_axes: Sequence[str] = (DATA_AXIS,)) -> NamedSharding:

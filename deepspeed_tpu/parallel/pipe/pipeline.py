@@ -31,10 +31,11 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.parallel.mesh import PIPE_AXIS
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 def stack_blocks(block_params_list):
@@ -64,10 +65,7 @@ def default_skip_bubble() -> bool:
     v = os.environ.get("DSTPU_SKIP_BUBBLE", "")
     if v in ("0", "1"):
         return v == "1"
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover — no backend
-        return False
+    return on_tpu()
 
 
 # Cache of jitted pipelined programs: rebuilding shard_map+jit per call would
@@ -168,8 +166,8 @@ def pipeline_apply_manual(block_fn: Callable,
     if rank is None:
         # Fine under a fully-manual caller; the partial-manual
         # pipeline_apply path passes a sharded-iota rank instead because
-        # old jax lowers axis_index there to a PartitionId HLO the SPMD
-        # partitioner rejects (utils/jax_compat.py).
+        # axis_index there lowered to a PartitionId HLO the SPMD
+        # partitioner rejected when this was written.
         rank = jax.lax.axis_index(PIPE_AXIS)
     shift = [(i, (i + 1) % stages) for i in range(stages)]
 
@@ -278,16 +276,6 @@ def pipeline_apply(block_fn: Callable,
                                      pass_layer_idx=pass_layer_idx,
                                      block_aux=block_aux,
                                      skip_bubble=skip_bubble)
-
-    from deepspeed_tpu.utils.jax_compat import NATIVE_SHARD_MAP
-    if not NATIVE_SHARD_MAP:
-        # Old jax: the partial-manual pipeline program crashes (C-level
-        # abort) this XLA CPU backend during compilation. Fail as a
-        # catchable error instead of killing the host process.
-        raise NotImplementedError(
-            "pipeline parallelism (stages > 1) requires a jax with native "
-            "shard_map; this jax's XLA backend aborts compiling the "
-            "partial-manual pipeline program")
 
     compute_dtype = x.dtype
 

@@ -25,24 +25,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.parallel.mesh import SEQUENCE_AXIS
 
 NEG_INF = -1e30
-
-
-def _require_native_shard_map(what: str) -> None:
-    """Old jax's XLA CPU backend hard-aborts (C-level) compiling these
-    partial-manual sequence programs — raise a catchable error instead of
-    letting the process die (utils/jax_compat.py)."""
-    from deepspeed_tpu.utils.jax_compat import NATIVE_SHARD_MAP
-    if not NATIVE_SHARD_MAP:
-        raise NotImplementedError(
-            f"{what} over a sequence axis > 1 requires a jax with native "
-            "shard_map; this jax's XLA backend aborts compiling the "
-            "partial-manual program")
 
 
 def _chunk_attention_partial(q, k, v, scale, mask):
@@ -94,7 +82,6 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     s_global = q.shape[1]
     if s_global % n:
         raise ValueError(f"seq {s_global} not divisible by sequence axis {n}")
-    _require_native_shard_map("ring attention")
     chunk = s_global // n
     orig_dtype = q.dtype
 
@@ -165,7 +152,6 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     h = q.shape[2]
     if h % n:
         raise ValueError(f"{h} heads not divisible by sequence axis {n}")
-    _require_native_shard_map("Ulysses attention")
 
     def ulysses_fn(q_c, k_c, v_c):
         # [B, S/n, H, D] -> [B, S, H/n, D]: gather seq, scatter heads.

@@ -24,12 +24,16 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
-# Peak matmul throughput per chip kind and dtype (TFLOP/s). bf16 numbers
-# are the published MXU peaks; fp32 runs the MXU in multi-pass mode at
-# half rate. fp16 inputs go through the same bf16 MXU path on TPU. The
-# table is the ONE source every MFU in the tree divides by —
-# bench.py, engine/mfu (telemetry/goodput.py) and tools/goodput_report
-# all route through :func:`mfu` below.
+# Peak matmul throughput per chip kind and dtype (TFLOP/s), keyed by
+# ``jax.devices()[0].device_kind``. bf16 numbers are the published MXU
+# peaks (Google Cloud TPU documentation; v5e: 197 TFLOP/s bf16, 819 GB/s
+# HBM); fp32 runs the MXU in multi-pass mode at half rate. fp16 inputs go
+# through the same bf16 MXU path on TPU. The table is the ONE source
+# every MFU in the tree divides by — bench.py, engine/mfu
+# (telemetry/goodput.py) and tools/goodput_report all route through
+# :func:`mfu` below. A device kind that is not in the table has NO peak:
+# the lookups return ``None`` and every figure divided by a peak is then
+# absent rather than computed against somebody else's chip.
 TPU_PEAK_TFLOPS = {
     "TPU v4": {"bfloat16": 275.0, "float32": 137.5},
     "TPU v5 lite": {"bfloat16": 197.0, "float32": 98.5},
@@ -37,12 +41,10 @@ TPU_PEAK_TFLOPS = {
     "TPU v6 lite": {"bfloat16": 918.0, "float32": 459.0},
     "TPU v6e": {"bfloat16": 918.0, "float32": 459.0},
 }
-DEFAULT_PEAK_TFLOPS = 197.0  # v5e-class bf16 — the conservative fallback
 
 # Peak HBM bandwidth per chip kind (GB/s) — the denominator of the
 # roofline ridge point (telemetry/devicetime.py): ridge [flop/byte] =
-# peak_flops / peak_bytes_per_sec. Published chip numbers; the fallback
-# is v5e-class like DEFAULT_PEAK_TFLOPS.
+# peak_flops / peak_bytes_per_sec. Published chip numbers.
 TPU_PEAK_HBM_GBPS = {
     "TPU v4": 1228.0,
     "TPU v5 lite": 819.0,
@@ -50,14 +52,12 @@ TPU_PEAK_HBM_GBPS = {
     "TPU v6 lite": 1638.0,
     "TPU v6e": 1638.0,
 }
-DEFAULT_PEAK_HBM_GBPS = 819.0
 
 
-def peak_hbm_gbps(device_kind: Optional[str] = None) -> float:
-    """Per-chip peak HBM bandwidth (GB/s) with the conservative
-    v5e-class default for unknown kinds (CPU test meshes, future
-    chips)."""
-    return TPU_PEAK_HBM_GBPS.get(device_kind or "", DEFAULT_PEAK_HBM_GBPS)
+def peak_hbm_gbps(device_kind: Optional[str] = None) -> Optional[float]:
+    """Per-chip peak HBM bandwidth (GB/s); ``None`` for a device kind
+    that is not in the table (CPU test meshes, chips nobody entered)."""
+    return TPU_PEAK_HBM_GBPS.get(device_kind or "")
 
 _DTYPE_ALIASES = {
     "bf16": "bfloat16", "bfloat16": "bfloat16",
@@ -68,32 +68,34 @@ _DTYPE_ALIASES = {
 
 
 def peak_tflops(device_kind: Optional[str] = None,
-                dtype: str = "bfloat16") -> float:
-    """Per-chip peak TFLOP/s for a device kind + compute dtype, with the
-    conservative v5e-class default for unknown kinds (CPU test meshes,
-    future chips)."""
+                dtype: str = "bfloat16") -> Optional[float]:
+    """Per-chip peak TFLOP/s for a device kind + compute dtype; ``None``
+    for a device kind that is not in the table (CPU test meshes, chips
+    nobody entered) — an unknown device gets no peak, not v5e's."""
     dtype = _DTYPE_ALIASES.get(str(dtype).lower(), "bfloat16")
     kinds = TPU_PEAK_TFLOPS.get(device_kind or "")
     if kinds is None:
-        base = DEFAULT_PEAK_TFLOPS
-        return base / 2.0 if dtype == "float32" else base
+        return None
     return kinds.get(dtype, kinds["bfloat16"])
 
 
 def mfu(flops_per_step: Optional[float], step_time_s: float,
         n_chips: int = 1, peak_tflops_per_chip: Optional[float] = None,
         device_kind: Optional[str] = None,
-        dtype: str = "bfloat16") -> float:
+        dtype: str = "bfloat16") -> Optional[float]:
     """Model FLOPs utilisation: ``flops_per_step`` (the WHOLE global
     step's FLOPs, across all chips) / (step time × chips × per-chip
     peak). Pass ``peak_tflops_per_chip`` explicitly or let the
-    device-kind/dtype table supply it. Returns 0.0 for degenerate
-    inputs (no FLOPs, non-positive time) rather than raising — MFU is a
-    report field, not a control signal."""
-    if not flops_per_step or flops_per_step <= 0 or step_time_s <= 0:
-        return 0.0
+    device-kind/dtype table supply it. ``None`` when there is no peak to
+    divide by (device kind not in the table); 0.0 for degenerate inputs
+    (no FLOPs, non-positive time) rather than raising — MFU is a report
+    field, not a control signal."""
     if peak_tflops_per_chip is None:
         peak_tflops_per_chip = peak_tflops(device_kind, dtype)
+    if peak_tflops_per_chip is None:
+        return None
+    if not flops_per_step or flops_per_step <= 0 or step_time_s <= 0:
+        return 0.0
     denom = step_time_s * max(int(n_chips), 1) * peak_tflops_per_chip * 1e12
     return float(flops_per_step) / denom
 
@@ -173,8 +175,6 @@ class FlopsProfiler:
         lowered = jfn.lower(*args)
         compiled = lowered.compile()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0] if cost else {}
         result: Dict[str, Any] = {
             "flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
@@ -213,7 +213,7 @@ class FlopsProfiler:
             peak_tflops_per_chip: Optional[float] = None,
             n_chips: int = 1, flops: Optional[float] = None,
             device_kind: Optional[str] = None,
-            dtype: str = "bfloat16") -> float:
+            dtype: str = "bfloat16") -> Optional[float]:
         """MFU of the last profiled callable (or explicit ``flops``) at a
         measured step time — delegates to the module-level :func:`mfu`,
         the single MFU formula in the tree."""

@@ -29,6 +29,7 @@ adapters for flax modules live in ``deepspeed_tpu.models.adapter``.
 
 import collections
 import contextlib
+import functools
 import os
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -41,7 +42,7 @@ from deepspeed_tpu.config.config import ConfigError, DeepSpeedTPUConfig
 from deepspeed_tpu.config import constants as C
 from deepspeed_tpu.ops.adam.fused_adam import FusedAdam, FusedAdamW, HostOffloadAdam
 from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb
-from deepspeed_tpu.parallel.mesh import (DATA_AXIS, build_mesh,
+from deepspeed_tpu.parallel.mesh import (DATA_AXIS, build_mesh, pinned_mesh,
                                          set_default_mesh as
                                          mesh_lib_set_default)
 from deepspeed_tpu.runtime.lr_schedules import build_lr_schedule
@@ -157,7 +158,15 @@ class TPUEngine:
                  donate_state: bool = True,
                  sparse_gradients_handled: bool = False):
         self.config = config
-        self.loss_fn = loss_fn
+        # Model code is traced with THIS engine's mesh pinned, so
+        # mesh-needing ops (the flash kernel's per-shard region,
+        # ring/Ulysses attention) bind to the engine tracing them.
+        @functools.wraps(loss_fn)
+        def loss_fn_on_own_mesh(*args, **kwargs):
+            with pinned_mesh(self.mesh):
+                return loss_fn(*args, **kwargs)
+
+        self.loss_fn = loss_fn_on_own_mesh
         self.mesh = mesh if mesh is not None else build_mesh(
             data=-1, model=config.mesh.model, pipe=config.mesh.pipe,
             sequence=config.mesh.sequence, expert=config.mesh.expert,
@@ -1456,7 +1465,7 @@ class TPUEngine:
                                 if a in (comp_axis, dense_axis)))
         all_manual = tuple(sorted(manual_axes))
 
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         params_tree = self.state.params
         base_specs = self._base_specs
@@ -1482,8 +1491,7 @@ class TPUEngine:
             compute_params = precision.cast_params(params)
             rank = jax.lax.axis_index(comp_axis)
             if dense_axis is not None:
-                from deepspeed_tpu.utils.jax_compat import axis_size
-                rank = (rank * axis_size(dense_axis)
+                rank = (rank * jax.lax.axis_size(dense_axis)
                         + jax.lax.axis_index(dense_axis))
             sub = jax.random.fold_in(sub, rank)
             grads, loss = fwd_bwd(compute_params, grad_acc, sub, scale,
@@ -2003,12 +2011,7 @@ class TPUEngine:
             from deepspeed_tpu.profiling.flops_profiler import peak_tflops
             with g.measure("recompile"):
                 lowered = self._train_step.lower(self.state, batches, lr)
-                try:
-                    cost = lowered.cost_analysis() or {}
-                except Exception:  # noqa: BLE001 — older jax: compile path
-                    cost = lowered.compile().cost_analysis() or {}
-            if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-                cost = cost[0] if cost else {}
+                cost = lowered.cost_analysis() or {}
             flops = float(cost.get("flops", 0.0))
             bytes_per_step = float(cost.get("bytes accessed", 0.0))
             if self._fused_update:
@@ -2021,11 +2024,15 @@ class TPUEngine:
                 k_flops, k_bytes = fused_update_cost(self.state.params)
                 flops += k_flops
                 bytes_per_step += k_bytes
-            dev = jax.devices()[0]
+            kind = jax.devices()[0].device_kind
+            peak = peak_tflops(kind, dtype=self.precision.name)
+            if peak is None:
+                logger.info(
+                    "engine/mfu is not reported: device kind %r has no "
+                    "entry in profiling/flops_profiler.TPU_PEAK_TFLOPS",
+                    kind)
             g.set_flops(flops, n_chips=self.mesh.size,
-                        peak_tflops_per_chip=peak_tflops(
-                            getattr(dev, "device_kind", ""),
-                            dtype=self.precision.name),
+                        peak_tflops_per_chip=peak,
                         # bytes feed the devicetime roofline's operational
                         # intensity (telemetry/devicetime.py)
                         bytes_per_step=bytes_per_step)

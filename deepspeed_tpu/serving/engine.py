@@ -44,6 +44,7 @@ from deepspeed_tpu.serving.kv_cache import (BlockPool, ChunkedLayerCache,
 from deepspeed_tpu.serving.scheduler import (PrefixCache, Scheduler,
                                              Sequence)
 from deepspeed_tpu.utils.logging import log_dist
+from deepspeed_tpu.utils.platform import on_tpu
 
 # Every metric tag the serving engine can emit — pinned against
 # docs/OBSERVABILITY.md in both directions by tests/test_doc_lint.py.
@@ -101,7 +102,7 @@ class ServeEngine:
                  telemetry=None, capture_logits: bool = False,
                  measure_kv_quant_error: bool = False,
                  request_accountant=None, fault_plan=None):
-        from deepspeed_tpu.config.config import ServingConfig
+        from deepspeed_tpu.config.config import ConfigError, ServingConfig
         from deepspeed_tpu.telemetry import null_telemetry
 
         if engine.model_cfg is None or not hasattr(engine.module, "cfg"):
@@ -156,15 +157,26 @@ class ServeEngine:
             paged_decode_ok
         mode = self.scfg.decode_attention
         self._fast_path = mode != "gather"
+        # The compiled kernel only tiles head_dim % 128 / block % 8
+        # geometries; off-TPU the Pallas interpreter takes any shape.
+        tpu = on_tpu()
+        tiles = not tpu or paged_decode_ok(self.model_cfg.head_dim, bs)
+        geometry = f"head_dim={self.model_cfg.head_dim}, block_size={bs}"
         if mode == "kernel":
+            if not tiles:
+                raise ConfigError(
+                    f"serving.decode_attention='kernel' cannot compile on "
+                    f"this TPU: {geometry} does not tile the paged "
+                    f"decode kernel (needs head_dim % 128 == 0 and "
+                    f"block_size % 8 == 0) — use 'auto' or 'gather'")
             self._attn_impl = "kernel"
         elif mode == "auto":
-            on_tpu = jax.devices()[0].platform == "tpu"
-            self._attn_impl = (
-                "kernel" if on_tpu and paged_decode_ok(
-                    self.model_cfg.head_dim, bs) else "gather")
+            self._attn_impl = "kernel" if tpu and tiles else "gather"
         else:
             self._attn_impl = "gather"
+        log_dist(f"serving: decode_attention={mode!r} resolved to "
+                 f"{self._attn_impl!r} ({geometry}, platform "
+                 f"{jax.devices()[0].platform})", ranks=[0])
         self._decode_jits: Dict[Any, Any] = {}    # window bucket -> jit
         self._tail_prefill_jit: Dict[int, Any] = {}
         # -- speculative decoding ---------------------------------------
@@ -185,26 +197,19 @@ class ServeEngine:
         self._mixed_jit = None
         self._chunk_tokens_last = 0
         if self._chunked:
-            from deepspeed_tpu.ops.transformer.chunked_prefill import \
-                chunked_prefill_ok
-            on_tpu = jax.devices()[0].platform == "tpu"
-            if on_tpu and not chunked_prefill_ok(self.model_cfg.head_dim,
-                                                 bs):
-                # The bucketed path stays the auto fallback (and the
-                # parity oracle) on geometries the compiled kernel
-                # cannot tile; off-TPU the Pallas interpreter takes any
-                # shape.
-                log_dist(
-                    f"serving: chunked prefill requested but head_dim="
-                    f"{self.model_cfg.head_dim}/block_size={bs} does not "
-                    f"tile the kernel — falling back to bucketed "
-                    f"admission", ranks=[0])
-                self._chunked = False
-            else:
-                log_dist(
-                    f"serving: chunked prefill on — token budget "
-                    f"{self._chunk_budget}/step, one mixed program",
-                    ranks=[0])
+            if not tiles:
+                # The user asked for this admission path: an engine that
+                # quietly served bucketed instead would report success
+                # for a path that never ran.
+                raise ConfigError(
+                    f"serving.chunked_prefill cannot compile on this TPU: "
+                    f"{geometry} does not tile the ragged-prefill kernel "
+                    f"(needs head_dim % 128 == 0 and block_size % 8 == 0) "
+                    f"— turn chunked_prefill off for this model")
+            log_dist(
+                f"serving: chunked prefill on — token budget "
+                f"{self._chunk_budget}/step, one mixed program",
+                ranks=[0])
         # Request observatory (telemetry/requests.py): per-request SLO
         # ledger + engine serving-time partition. None (the default and
         # the telemetry.requests=off state) keeps every hook a single

@@ -116,6 +116,7 @@ class DeviceTimeObservatory:
         self.captures_done = 0
         self.last_analysis: Optional[Dict[str, Any]] = None
         self.last_breakdown: Optional[Dict[str, Any]] = None
+        self._warned_no_peak = False
 
     # -- scheduling ------------------------------------------------------
     def step_hook(self, step: int) -> None:
@@ -259,22 +260,36 @@ class DeviceTimeObservatory:
         intensity = None
         ridge = None
         if info is not None:
+            import jax
+
             from deepspeed_tpu.profiling.flops_profiler import (
                 mfu as _mfu, peak_hbm_gbps, peak_tflops)
+            kind = jax.devices()[0].device_kind
             peak = info.get("peak_tflops_per_chip")
             if peak is None:
-                peak = peak_tflops(self._device_kind())
+                peak = peak_tflops(kind)
             hbm = float(self.cfg.hbm_gbps) if self.cfg.hbm_gbps \
-                else peak_hbm_gbps(self._device_kind())
-            ridge = (peak * 1e12) / (hbm * 1e9) if hbm > 0 else 0.0
+                else peak_hbm_gbps(kind)
             if info.get("bytes_per_step"):
                 intensity = info["flops_per_step"] / info["bytes_per_step"]
-            if step_time:
-                mfu_measured = _mfu(info["flops_per_step"], step_time,
-                                    n_chips=info["n_chips"],
-                                    peak_tflops_per_chip=peak)
-                reg.gauge("devicetime/mfu_measured").set(mfu_measured,
-                                                         step=step)
+            if peak is None or hbm is None:
+                # No peak, no figure: the roofline verdict stays
+                # "unknown" and the measured-MFU gauge is absent.
+                if not self._warned_no_peak:
+                    self._warned_no_peak = True
+                    logger.info(
+                        "devicetime: device kind %r has no entry in the "
+                        "peak tables (profiling/flops_profiler) — "
+                        "devicetime/mfu_measured and the roofline verdict "
+                        "are not reported", kind)
+            else:
+                ridge = (peak * 1e12) / (hbm * 1e9) if hbm > 0 else 0.0
+                if step_time:
+                    mfu_measured = _mfu(info["flops_per_step"], step_time,
+                                        n_chips=info["n_chips"],
+                                        peak_tflops_per_chip=peak)
+                    reg.gauge("devicetime/mfu_measured").set(mfu_measured,
+                                                             step=step)
         verdicts = roofline_verdicts(intensity, ridge or 0.0)
 
         hot = traceparse.top_ops(analysis, int(self.cfg.top_k))
@@ -328,13 +343,6 @@ class DeviceTimeObservatory:
                              f"x{r['count']:<5} {r['category']} "
                              f"({r.get('share_of_busy', 0.0):.1%} of busy)")
         logger.info("%s", "\n".join(lines))
-
-    def _device_kind(self) -> str:
-        try:
-            import jax
-            return getattr(jax.devices()[0], "device_kind", "")
-        except Exception:  # noqa: BLE001
-            return ""
 
 
 def build_devicetime(tcfg, telemetry=None, goodput=None) -> \

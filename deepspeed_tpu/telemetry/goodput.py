@@ -391,9 +391,12 @@ class GoodputAccountant:
     def mfu(self) -> Optional[float]:
         """Model FLOPs utilisation of the measured (productive+replay)
         steps, through the shared flops_profiler helper — one source of
-        truth with bench.py."""
+        truth with bench.py. ``None`` (gauge absent) until FLOPs and a
+        step time exist, and always on a device with no peak-table
+        entry."""
         dt = self.mean_step_time()
-        if self._flops_per_step is None or dt is None or dt <= 0:
+        if (self._flops_per_step is None or self._peak_tflops is None
+                or dt is None or dt <= 0):
             return None
         from deepspeed_tpu.profiling.flops_profiler import mfu as _mfu
         return _mfu(self._flops_per_step, dt, n_chips=self._n_chips,

@@ -31,8 +31,8 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.config.config import ConfigError, ServingConfig
 from deepspeed_tpu.models import make_gpt
-from deepspeed_tpu.ops.transformer.chunked_prefill import (
-    chunked_prefill_attention, chunked_prefill_ok)
+from deepspeed_tpu.ops.transformer.chunked_prefill import \
+    chunked_prefill_attention
 from deepspeed_tpu.serving import ServeEngine
 from deepspeed_tpu.serving.kv_cache import _quant_tokens
 from deepspeed_tpu.telemetry import (InMemorySink, MetricsRegistry,
@@ -131,11 +131,6 @@ class TestChunkedPrefillKernel:
         assert np.isfinite(got).all()
         np.testing.assert_allclose(got[0, 0], k[0, 0, 0] * 0 + v[0, 0, 0],
                                    atol=2e-5)
-
-    def test_geometry_gate(self):
-        assert chunked_prefill_ok(128, 8)
-        assert not chunked_prefill_ok(64, 8)     # lane-tiling miss
-        assert not chunked_prefill_ok(128, 6)    # sublane-tiling miss
 
 
 # ---------------------------------------------------------------------------
@@ -280,6 +275,23 @@ class TestChunkedOffContract:
         cfg = ServingConfig.from_dict({"chunked_prefill": {}})
         assert cfg.chunked_prefill is True
         assert ServingConfig.from_dict({}).chunked_prefill is False
+
+    def test_untileable_geometry_on_tpu_raises(self, gpt_setup, monkeypatch):
+        """A path the user asked for is never swapped for another: on a
+        TPU, a geometry the compiled kernel cannot tile (tiny GPT:
+        head_dim 16) is a ConfigError at engine construction — for chunked
+        admission and for decode_attention='kernel' alike — while 'auto'
+        stays a documented selection and resolves to gather."""
+        import deepspeed_tpu.serving.engine as serving_engine
+        model, cfg, params = gpt_setup
+        monkeypatch.setattr(serving_engine, "on_tpu", lambda: True)
+        with pytest.raises(ConfigError, match="chunked_prefill"):
+            _serve(model, params, chunked_prefill=True,
+                   chunked_token_budget=8)
+        with pytest.raises(ConfigError, match="decode_attention='kernel'"):
+            _serve(model, params, decode_attention="kernel")
+        assert _serve(model, params,
+                      decode_attention="auto")._attn_impl == "gather"
 
 
 class TestProbeChunkedPrefillCLI:

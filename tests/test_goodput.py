@@ -205,9 +205,12 @@ class TestMfuHelper:
         assert fp.peak_tflops("TPU v6 lite") == 918.0
         # fp16 rides the bf16 MXU path
         assert fp.peak_tflops("TPU v4", "float16") == 275.0
-        # unknown kind: conservative default, fp32 at half
-        assert fp.peak_tflops("", "bfloat16") == fp.DEFAULT_PEAK_TFLOPS
-        assert fp.peak_tflops(None, "fp32") == fp.DEFAULT_PEAK_TFLOPS / 2
+        # unknown kind: no peak at all (never another chip's)
+        assert fp.peak_tflops("", "bfloat16") is None
+        assert fp.peak_tflops(None, "fp32") is None
+        assert fp.peak_tflops("cpu") is None
+        assert fp.peak_hbm_gbps("cpu") is None
+        assert fp.peak_hbm_gbps("TPU v5 lite") == 819.0
 
     def test_mfu_math_and_degenerate_inputs(self):
         # 100 TFLOP over 1 s on 1 chip with 200 TFLOP/s peak = 50%
@@ -219,9 +222,12 @@ class TestMfuHelper:
         # device-kind lookup path
         assert fp.mfu(275e12, 1.0, n_chips=1, device_kind="TPU v4") == \
             pytest.approx(1.0)
-        assert fp.mfu(None, 1.0) == 0.0
-        assert fp.mfu(0.0, 1.0) == 0.0
-        assert fp.mfu(1e12, 0.0) == 0.0
+        assert fp.mfu(None, 1.0, device_kind="TPU v4") == 0.0
+        assert fp.mfu(0.0, 1.0, device_kind="TPU v4") == 0.0
+        assert fp.mfu(1e12, 0.0, device_kind="TPU v4") == 0.0
+        # no peak to divide by -> no figure, whatever the inputs
+        assert fp.mfu(1e12, 1.0) is None
+        assert fp.mfu(1e12, 1.0, device_kind="cpu") is None
 
     def test_profiler_method_uses_last_profile(self):
         prof = fp.FlopsProfiler()
@@ -262,7 +268,11 @@ def _tel_cfg(tmp_path, goodput=True, sinks=("memory",)):
 
 class TestEngineGoodput:
     def test_fused_loop_categories_manifest_and_mfu(self, eight_devices,
-                                                    tmp_path):
+                                                    tmp_path, monkeypatch):
+        # give the test mesh's device kind a peak so engine/mfu is emitted
+        # (the next test pins that without one the gauge is absent)
+        monkeypatch.setitem(fp.TPU_PEAK_TFLOPS, jax.devices()[0].device_kind,
+                            {"bfloat16": 1.0, "float32": 0.5})
         engine = _engine(_tel_cfg(tmp_path) | {"steps_per_print": 2})
         rng = np.random.default_rng(0)
         batches = random_batches(rng, gas=1, batch_size=16)
@@ -292,6 +302,22 @@ class TestEngineGoodput:
             assert mem.values("engine/mfu")[-1] == pytest.approx(want)
         assert mem.values("goodput/steps_committed")[-1] == 5
         assert not g.wants_flops             # analysed exactly once
+
+    def test_no_mfu_gauge_on_a_device_without_a_peak(self, eight_devices,
+                                                     tmp_path):
+        """The CPU mesh's device kind is not in the peak table: the engine
+        still records the step's FLOPs, but engine/mfu is absent — not a
+        number computed against some other chip's peak."""
+        engine = _engine(_tel_cfg(tmp_path))
+        rng = np.random.default_rng(0)
+        batches = random_batches(rng, gas=1, batch_size=16)
+        for _ in range(3):
+            engine.train_batch(batches)
+        g = engine.goodput
+        assert not g.wants_flops and g._peak_tflops is None
+        assert g.mfu() is None
+        mem = engine.telemetry.registry.sinks[0]
+        assert mem.values("engine/mfu") == []
 
     def test_reference_loop_marks(self, eight_devices, tmp_path):
         from simple_model import random_batch

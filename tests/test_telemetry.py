@@ -422,9 +422,6 @@ class TestPipelineTelemetry:
         from deepspeed_tpu.config.config import DeepSpeedTPUConfig
         from deepspeed_tpu.models.gpt import GPTConfig
         from deepspeed_tpu.parallel.pipe import PipelineEngine, gpt_pipe_model
-        from deepspeed_tpu.utils.jax_compat import NATIVE_SHARD_MAP
-        if not NATIVE_SHARD_MAP:
-            pytest.skip("stages > 1 needs a jax with native shard_map")
 
         cfg = GPTConfig(vocab_size=128, max_seq_len=32, hidden_size=32,
                         num_layers=4, num_heads=2, dropout_rate=0.0,

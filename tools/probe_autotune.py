@@ -77,6 +77,8 @@ def run_probe():
 def main(argv=None) -> int:
     selftest = "--selftest" in (argv or sys.argv[1:])
     engine, result, measured, loser, steps_before = run_probe()
+    import jax
+    print(f"platform: {jax.devices()[0].platform} (CPU correctness drive)")
 
     from deepspeed_tpu.autotuning import render_result_table
     print(render_result_table(result))

@@ -48,7 +48,9 @@ def run(name, micro_bs, gas, steps=8, windows=2):
 
 
 def main():
-    print("platform:", jax.devices()[0].platform, flush=True)
+    from deepspeed_tpu.utils.compile_cache import configure_compile_cache
+    print("platform:", jax.devices()[0].platform, "compile cache:",
+          configure_compile_cache(), flush=True)
     run("mb16 gas8  (bench)", 16, 8)
     run("mb16 gas16       ", 16, 16, steps=4)
     run("mb24 gas8        ", 24, 8)

@@ -45,7 +45,9 @@ def run(name, dropout_rate, fast, steps=8, windows=2):
 
 
 def main():
-    print("platform:", jax.devices()[0].platform, flush=True)
+    from deepspeed_tpu.utils.compile_cache import configure_compile_cache
+    print("platform:", jax.devices()[0].platform, "compile cache:",
+          configure_compile_cache(), flush=True)
     off = run("dropout off       ", 0.0, False)
     slow = run("dropout threefry  ", 0.1, False)
     fast = run("dropout hash      ", 0.1, True)

@@ -183,6 +183,8 @@ def run_probe():
 def main(argv=None) -> int:
     selftest = "--selftest" in (argv or sys.argv[1:])
     result = run_probe()
+    import jax
+    print(f"platform: {jax.devices()[0].platform} (CPU correctness drive)")
     print(f"{'path':<28} {'seconds':>10}")
     print("-" * 40)
     print(f"{'in-process reshard only':<28} "

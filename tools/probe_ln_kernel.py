@@ -71,6 +71,8 @@ def main(n=8192, d=768, f=2304, act=None, layers=12):
 
 
 if __name__ == "__main__":
-    print("platform:", jax.devices()[0].platform, flush=True)
+    from deepspeed_tpu.utils.compile_cache import configure_compile_cache
+    print("platform:", jax.devices()[0].platform, "compile cache:",
+          configure_compile_cache(), flush=True)
     main(act=None)
     main(f=3072, act="gelu")

@@ -51,7 +51,9 @@ def timed(s, impl, dropout, steps=10, warmup=2):
 
 
 def main():
-    print("platform:", jax.devices()[0].platform, flush=True)
+    from deepspeed_tpu.utils.compile_cache import configure_compile_cache
+    print("platform:", jax.devices()[0].platform, "compile cache:",
+          configure_compile_cache(), flush=True)
     for dropout in (False, True):
         for s in (640, 768, 896, 1152):
             xla = timed(s, "xla", dropout)

@@ -65,6 +65,7 @@ def main(argv=None) -> int:
 
     from deepspeed_tpu.models import make_gpt
 
+    print(f"platform: {jax.devices()[0].platform} (CPU correctness drive)")
     model, cfg = make_gpt("tiny", dropout_rate=0.0, max_seq_len=64,
                           dtype=jnp.float32)
     params = model.init({"params": jax.random.PRNGKey(0),

@@ -21,7 +21,9 @@ def timed(fn, q, steps=8, warmup=2):
     return (time.perf_counter() - t0) / steps * 1e3
 
 def main():
-    print("platform:", jax.devices()[0].platform, flush=True)
+    from deepspeed_tpu.utils.compile_cache import configure_compile_cache
+    print("platform:", jax.devices()[0].platform, "compile cache:",
+          configure_compile_cache(), flush=True)
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.bfloat16) * 0.1
 

@@ -55,7 +55,9 @@ def main(b=16, s=512, h=12, d=64, layers=12):
         out, _ = jax.lax.scan(body, x_bshd, None, length=layers)
         return jnp.sum(out.astype(jnp.float32))
 
-    print("platform:", jax.devices()[0].platform, flush=True)
+    from deepspeed_tpu.utils.compile_cache import configure_compile_cache
+    print("platform:", jax.devices()[0].platform, "compile cache:",
+          configure_compile_cache(), flush=True)
     bench("fwd   native   ", flash, x_bhsd)
     bench("fwd   transpose", flash_t, x_bshd)
     bench("f+b   native   ", jax.grad(flash), x_bhsd)

@@ -14,7 +14,10 @@ sys.path.insert(0, "/root/repo")
 def main():
     import deepspeed_tpu
     from deepspeed_tpu.models import make_gpt
+    from deepspeed_tpu.utils.compile_cache import configure_compile_cache
 
+    print("platform:", jax.devices()[0].platform, "compile cache:",
+          configure_compile_cache(), flush=True)
     model, cfg = make_gpt("gpt2", dropout_rate=0.0, remat=False,
                           max_seq_len=512)
     rng = np.random.default_rng(0)
@@ -38,7 +41,7 @@ def main():
     for _ in range(2):
         loss = engine.train_batch(batches)
     _ = float(loss)
-    with jax.profiler.trace("/root/repo/profiles/gpt2_r5"):
+    with jax.profiler.trace("/root/repo/chiprun_out/profiles/gpt2_r5"):
         for _ in range(2):
             loss = engine.train_batch(batches)
         _ = float(loss)
