@@ -91,6 +91,7 @@ from deepspeed_tpu.comm.quantize import (dequantize_blockwise,
 from deepspeed_tpu.parallel.mesh import (DATA_AXIS, DCN_AXIS,
                                          axes_size as mesh_axes_size)
 from jax import shard_map
+from deepspeed_tpu.telemetry.tracer import device_scope
 from deepspeed_tpu.utils.logging import log_dist
 
 _MB = 1 << 20
@@ -636,8 +637,9 @@ class GradSyncPlan:
             key = jax.random.fold_in(sub_, slice_id[0])
             with overlap_mod.install_ici_hook(hook):
                 loss, grads = grad_fn(cp, batch_, key, scale_)
-            mb = self.microstep_buckets_overlap(grads)
-            fb_synced = self.fallback_sync(self.fallback_leaves(grads))
+            with device_scope("grad_sync"):
+                mb = self.microstep_buckets_overlap(grads)
+                fb_synced = self.fallback_sync(self.fallback_leaves(grads))
             loss = jax.lax.pmean(loss, DCN_AXIS)
             return tuple(b[None] for b in mb), fb_synced, loss
 
@@ -686,22 +688,26 @@ class GradSyncPlan:
                 batch=batch_k, batch_spec=batch_spec, grad_fn=grad_fn,
                 microbatched=microbatched)
             losses.append(loss_k)
-            fb_total = (list(fb_k) if fb_total is None
-                        else [a + b for a, b in zip(fb_total, fb_k)])
-            if inflight is not None:
-                # Consume the previous microstep's reduce — by now its
-                # wire time has been hidden behind this microstep's
-                # fwd/bwd. The accumulator holds ONE total plus ONE
-                # in-flight buffer (double-buffered), never more.
-                total = (list(inflight) if total is None
-                         else [t + f for t, f in zip(total, inflight)])
-            inflight, parts = self._dcn_sync_overlap(stacked_k)
+            with device_scope("accumulate"):
+                fb_total = (list(fb_k) if fb_total is None
+                            else [a + b for a, b in zip(fb_total, fb_k)])
+                if inflight is not None:
+                    # Consume the previous microstep's reduce — by now its
+                    # wire time has been hidden behind this microstep's
+                    # fwd/bwd. The accumulator holds ONE total plus ONE
+                    # in-flight buffer (double-buffered), never more.
+                    total = (list(inflight) if total is None
+                             else [t + f for t, f in zip(total, inflight)])
+            with device_scope("grad_sync"):
+                inflight, parts = self._dcn_sync_overlap(stacked_k)
             if parts is not None:
                 err_acc = parts if err_acc is None else err_acc + parts
         if inflight is not None:
-            total = (list(inflight) if total is None
-                     else [t + f for t, f in zip(total, inflight)])
-        grads = self._unbucket_overlap(total or [], fb_total or [])
+            with device_scope("accumulate"):
+                total = (list(inflight) if total is None
+                         else [t + f for t, f in zip(total, inflight)])
+        with device_scope("grad_sync"):
+            grads = self._unbucket_overlap(total or [], fb_total or [])
         loss = jnp.mean(jnp.stack(losses))
         qerr = (self._qerr_from_parts(err_acc)
                 if err_acc is not None else None)
@@ -793,15 +799,18 @@ class GradSyncPlan:
                 else:
                     batch, k = batches_, key
                 loss, grads = grad_fn(cp, batch, k, scale_)
-                mb = self.microstep_buckets(grads)
-                buckets = tuple(b + m.astype(b.dtype)
-                                for b, m in zip(buckets, mb))
-                gf = self.fallback_leaves(grads)
-                fb = [jax.lax.with_sharding_constraint(
-                        a + g.astype(a.dtype), s)
-                      for a, g, s in zip(fb, gf, fallback_inner)]
+                with device_scope("grad_sync"):
+                    mb = self.microstep_buckets(grads)
+                with device_scope("accumulate"):
+                    buckets = tuple(b + m.astype(b.dtype)
+                                    for b, m in zip(buckets, mb))
+                    gf = self.fallback_leaves(grads)
+                    fb = [jax.lax.with_sharding_constraint(
+                            a + g.astype(a.dtype), s)
+                          for a, g, s in zip(fb, gf, fallback_inner)]
                 losses.append(loss)
-            fb_synced = self.fallback_sync(fb)
+            with device_scope("grad_sync"):
+                fb_synced = self.fallback_sync(fb)
             loss = jax.lax.pmean(jnp.mean(jnp.stack(losses)), DCN_AXIS)
             return tuple(b[None] for b in buckets), fb_synced, loss
 
@@ -849,8 +858,9 @@ class GradSyncPlan:
         :meth:`run_manual_gas`. Returns ``(grads_tree, qerr)``; ``qerr``
         is :meth:`dcn_sync`'s per-bucket error array (None unless
         ``measure_quant_error``)."""
-        buckets, qerr = self.dcn_sync(stacked)
-        return self.unbucket(buckets, synced_fallback), qerr
+        with device_scope("grad_sync"):
+            buckets, qerr = self.dcn_sync(stacked)
+            return self.unbucket(buckets, synced_fallback), qerr
 
     def _bucket_dcn_bytes(self, elems: int) -> int:
         """Modeled DCN wire bytes for one bucket of ``elems`` elements

@@ -34,6 +34,7 @@ from deepspeed_tpu.inference.quantization import (dequantize_params,
                                                   quantize_params,
                                                   quantized_nbytes)
 from deepspeed_tpu.models.partition import build_specs
+from deepspeed_tpu.telemetry.tracer import device_scope
 from deepspeed_tpu.utils.logging import log_dist
 
 # Smallest prompt bucket: prompts shorter than this share one compiled
@@ -53,6 +54,7 @@ def bucket_length(t: int, floor: int = MIN_PROMPT_BUCKET,
     return max(b, t)
 
 
+@device_scope("sample")
 def sample_logits(logits, rng, temperature: float, top_k: int):
     """Greedy (``temperature == 0``) or temperature/top-k sampling over
     ``[B, V]`` fp32 logits — shared by ``generate()`` and the serving

@@ -132,6 +132,7 @@ def fused_adam_leaf(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
+        name="fused_adam",
         interpret=interpret,
     )(scalars, pf, gf, mf, vf)
     return tuple(o.reshape(-1)[:n].reshape(shape) for o in outs)

@@ -292,6 +292,7 @@ def _sparse_forward(qf, kf, vf, kv_mask, kv_idx, kv_cnt, block, causal,
             jax.ShapeDtypeStruct((bh, s, d), qf.dtype),
             jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32),
         ],
+        name="sparse_attn_fwd",
         interpret=interpret,
         compiler_params=_vmem_params(
             2 * s * d * esz + 2 * block * d * esz + block * LANES * 4
@@ -335,6 +336,7 @@ def _sparse_backward(qf, kf, vf, kv_mask, do, out, lse, kv_idx, kv_cnt,
                                    lambda b, i, idx, cnt: (b, i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), qf.dtype),
+        name="sparse_attn_bwd_dq",
         interpret=interpret,
         compiler_params=_vmem_params(
             2 * s * d * esz + 4 * block * d * esz + 2 * block * LANES * 4
@@ -374,6 +376,7 @@ def _sparse_backward(qf, kf, vf, kv_mask, do, out, lse, kv_idx, kv_cnt,
             jax.ShapeDtypeStruct((bh, s, d), kf.dtype),
             jax.ShapeDtypeStruct((bh, s, d), vf.dtype),
         ],
+        name="sparse_attn_bwd_dkv",
         interpret=interpret,
         compiler_params=_vmem_params(
             2 * s * d * esz + 2 * s * LANES * 4 + 4 * block * d * esz

@@ -214,6 +214,7 @@ def _flash_forward(q, k, v, kv_mask, causal, scale, block_q, block_k,
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, LANES), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
         compiler_params=_vmem_params(
             (2 * sk * d + 2 * block_q * d) * q.dtype.itemsize
@@ -396,6 +397,7 @@ def _flash_backward(res, g, causal, scale, block_q, block_k, interpret,
             out_specs=pl.BlockSpec((1, block_q, d),
                                    lambda b, i, s: (b, i, 0))),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        name="flash_bwd_dq",
         interpret=interpret,
         compiler_params=_vmem_params(
             (2 * sk * d + 3 * block_q * d) * q.dtype.itemsize
@@ -431,6 +433,7 @@ def _flash_backward(res, g, causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
         compiler_params=_vmem_params(
             (2 * sq * d + 4 * block_k * d) * q.dtype.itemsize

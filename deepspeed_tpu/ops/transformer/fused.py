@@ -149,6 +149,7 @@ def _run_fwd(x, gamma, beta, w, bias, eps, activation, block_rows,
         ],
         out_specs=pl.BlockSpec((bn, f), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, f), x.dtype),
+        name="fused_ln_fwd",
         interpret=interpret,
         compiler_params=_vmem_params(
             d * f * w.dtype.itemsize + bn * d * x.dtype.itemsize
@@ -187,6 +188,7 @@ def _run_bwd(x, gamma, beta, w, bias, dy, eps, activation, block_rows,
             jax.ShapeDtypeStruct((1, d), jnp.float32),
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
+        name="fused_ln_bwd",
         interpret=interpret,
         compiler_params=_vmem_params(
             2 * d * f * 4 + 2 * bn * (d + f) * 4 + 2 * (d + f) * 4),

@@ -203,6 +203,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                 pltpu.VMEM((s, d), jnp.float32),       # fp32 accumulator
             ]),
         out_shape=jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
+        name="paged_attention",
         interpret=interpret,
     )(block_table.astype(jnp.int32), pos.astype(jnp.int32), *inputs)
     return out.reshape(b, s, h, d)

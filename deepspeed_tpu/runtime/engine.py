@@ -51,6 +51,7 @@ from deepspeed_tpu.runtime.precision import (LossScaleState, PrecisionPolicy,
 from deepspeed_tpu.runtime.utils import (clip_grad_by_global_norm, global_norm,
                                          has_inf_or_nan)
 from deepspeed_tpu.runtime.zero.partition import ZeroPartitioner
+from deepspeed_tpu.telemetry.tracer import device_scope
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 
@@ -650,6 +651,9 @@ class TPUEngine:
         self._micro_in_window = 0
         self._pending_micro = []
         self._last_loss = None
+        # The gradient norm the last fused train_batch() returned (None
+        # after a forward(): get_global_grad_norm then reads grad_acc).
+        self._fused_grad_norm = None
         self.global_steps = 0
         self.micro_steps = 0
         self.losses = collections.deque(maxlen=100)
@@ -859,8 +863,9 @@ class TPUEngine:
                 grad_fn = jax.value_and_grad(scaled_loss_fn, has_aux=True)
                 (_, (loss, _)), grads = grad_fn(compute_params, batch, sub,
                                                 scale)
-                acc = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(a.dtype), acc, grads)
+                with device_scope("accumulate"):
+                    acc = jax.tree_util.tree_map(
+                        lambda a, g: a + g.astype(a.dtype), acc, grads)
                 return (acc, rng), loss
 
             zeros = jax.tree_util.tree_map(
@@ -923,6 +928,7 @@ class TPUEngine:
         else:
             self._offload_micro_scan = jax.jit(micro_scan)
 
+        @device_scope("cast_params")
         def cast_tree(tree):
             dt = (precision.dtype if precision.mixed else jnp.float32)
             return jax.tree_util.tree_map(lambda a: a.astype(dt), tree)
@@ -1071,10 +1077,10 @@ class TPUEngine:
         plan = self.param_gather_plan
         precision = self.precision
 
-        if plan is None:
-            return lambda params: (precision.cast_params(params), None)
-
+        @device_scope("cast_params")
         def fn(params):
+            if plan is None:
+                return precision.cast_params(params), None
             full, qerr = plan.gather(params)
             return precision.cast_params(full), qerr
 
@@ -1117,6 +1123,7 @@ class TPUEngine:
         if fused:
             from deepspeed_tpu.ops.adam.fused_update import fused_adam_apply
 
+        @device_scope("optimizer")
         def apply_step(state: TrainState, lr):
             scale = state.loss_scale.scale if fp16 else jnp.float32(1.0)
             inv = 1.0 / scale
@@ -1193,9 +1200,12 @@ class TPUEngine:
             scale = state.loss_scale.scale if fp16 else jnp.float32(1.0)
             grad_fn = jax.value_and_grad(scaled_loss_fn, has_aux=True)
             (_, (loss, aux)), grads = grad_fn(compute_params, batch, sub, scale)
-            grads = jax.tree_util.tree_map(
-                lambda a, g: a + g.astype(a.dtype), state.grad_acc, grads)
-            grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
+            with device_scope("accumulate"):
+                grads = jax.tree_util.tree_map(
+                    lambda a, g: a + g.astype(a.dtype), state.grad_acc,
+                    grads)
+                grads = jax.lax.with_sharding_constraint(grads,
+                                                         grad_shardings)
             return state._replace(micro_step=state.micro_step + 1,
                                   grad_acc=grads, rng=rng), loss, aux
 
@@ -1414,8 +1424,9 @@ class TPUEngine:
 
                 (_, loss), grads = jax.value_and_grad(
                     scaled, has_aux=True)(compute_params)
-                acc = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(a.dtype), acc, grads)
+                with device_scope("accumulate"):
+                    acc = jax.tree_util.tree_map(
+                        lambda a, g: a + g.astype(a.dtype), acc, grads)
                 return (acc, key), loss
 
             (acc, _), losses = jax.lax.scan(body, (grad_acc, sub), batches)
@@ -1488,7 +1499,8 @@ class TPUEngine:
         fwd_bwd = self._local_grad_forward_backward(comp_axis, dense_axis)
 
         def phase_a(params, grad_acc, m, we, se, step, sub, scale, batches):
-            compute_params = precision.cast_params(params)
+            with device_scope("cast_params"):
+                compute_params = precision.cast_params(params)
             rank = jax.lax.axis_index(comp_axis)
             if dense_axis is not None:
                 rank = (rank * jax.lax.axis_size(dense_axis)
@@ -1510,7 +1522,8 @@ class TPUEngine:
                             g.astype(comm_dt), dense_axis).astype(g.dtype)
                     return jax.lax.pmean(g, dense_axis)
 
-                grads = jax.tree_util.tree_map(dense_reduce, grads)
+                with device_scope("grad_sync"):
+                    grads = jax.tree_util.tree_map(dense_reduce, grads)
             norm = jnp.float32(0.0)
             if cfg.gradient_clipping > 0.0:
                 # Global-norm clip BEFORE the optimizer's own collective
@@ -1535,8 +1548,9 @@ class TPUEngine:
                 overflow = jax.lax.pmax(local_of, all_manual) > 0
             else:
                 overflow = jnp.zeros((), jnp.bool_)
-            m_new, g_dense, we_new, se_new = optimizer.sync_phase(
-                grads, m, we, se, step)
+            with device_scope("grad_sync"):
+                m_new, g_dense, we_new, se_new = optimizer.sync_phase(
+                    grads, m, we, se, step)
             loss_mean = jax.lax.pmean(loss, red_axes)
             return loss_mean, m_new, g_dense, we_new, se_new, overflow, norm
 
@@ -1597,17 +1611,19 @@ class TPUEngine:
                     opt.server_error, opt.step, sub, scale, batches)
             # GSPMD-auto apply: ZeRO-1 places m/v sharded (opt_specs); the
             # resulting gather/slice collectives ride the ICI data axis.
-            new_params, new_opt = optimizer.finish_step(
-                state.params, opt, m_new, g_dense, we_new, se_new, lr)
-            new_params = _tree_where(overflow, state.params, new_params)
-            new_opt = _tree_where(overflow, opt, new_opt)
-            new_params = jax.lax.with_sharding_constraint(
-                new_params, param_shardings)
-            new_opt = jax.lax.with_sharding_constraint(new_opt, opt_shardings)
-            new_ls = scaler.update(state.loss_scale, overflow)
-            zero_acc = jax.lax.with_sharding_constraint(
-                jax.tree_util.tree_map(jnp.zeros_like, state.grad_acc),
-                grad_shardings)
+            with device_scope("optimizer"):
+                new_params, new_opt = optimizer.finish_step(
+                    state.params, opt, m_new, g_dense, we_new, se_new, lr)
+                new_params = _tree_where(overflow, state.params, new_params)
+                new_opt = _tree_where(overflow, opt, new_opt)
+                new_params = jax.lax.with_sharding_constraint(
+                    new_params, param_shardings)
+                new_opt = jax.lax.with_sharding_constraint(new_opt,
+                                                           opt_shardings)
+                new_ls = scaler.update(state.loss_scale, overflow)
+                zero_acc = jax.lax.with_sharding_constraint(
+                    jax.tree_util.tree_map(jnp.zeros_like, state.grad_acc),
+                    grad_shardings)
             state = state._replace(
                 step=state.step + jnp.where(overflow, 0, 1),
                 micro_step=state.micro_step + gas,
@@ -1729,6 +1745,7 @@ class TPUEngine:
                      else contextlib.nullcontext())
         with tel.span("forward", step=self.global_steps), oom_guard:
             self.state, loss, _ = self._micro_step(self.state, batch)
+        self._fused_grad_norm = None    # the accumulators hold grads again
         if g is not None:
             # Same classification as _goodput_step_mark: micro-steps
             # re-run after a rollback rewind (the upcoming committed step
@@ -2149,7 +2166,8 @@ class TPUEngine:
                      if self.memory is not None
                      else contextlib.nullcontext())
         try:
-            with oom_guard:
+            with oom_guard, self.telemetry.span("train_batch",
+                                                step=self.global_steps):
                 return self._train_batch_inner(batches)
         finally:
             if gr is not None:
@@ -2157,13 +2175,14 @@ class TPUEngine:
 
     def _train_batch_inner(self, batches) -> jax.Array:
         tel = self.telemetry
+        step = self.global_steps        # the identifier this step's spans share
         g = self.goodput
         if g is not None:
             g.mark_gap()
         self.tput_timer.start()
         if self.wall_clock_breakdown:
             self.timers("dataloader").start()
-        with tel.span("dataloader", step=self.global_steps):
+        with tel.span("dataloader", step=step):
             batches = self.put_batch(
                 self._inject_pld(self._stash_moq_probe(batches)),
                 leading_gas_dim=True)
@@ -2179,46 +2198,62 @@ class TPUEngine:
             # deadlocked-collective shape a real hang takes.
             fp.hang()
         if self._train_step is None:  # offloaded optimizer tier
-            with tel.span("train_step", step=self.global_steps) as sp:
+            with tel.span("train_step", step=step) as sp:
                 loss = self._offload_train_batch(batches)
-            self.global_steps += 1
-            self.micro_steps += self.gradient_accumulation_steps
-            if self.lr_scheduler is not None:
-                self.lr_scheduler.step()
-            self.tput_timer.stop()
-            self._last_loss = loss
-            self._goodput_step_mark(status)
-            if self.memory is not None:
-                # Offload tier: attribute the device-side micro-scan
-                # executable (the host optimizer step has no HBM story).
-                self.memory.maybe_attribute(self, batches, None, status)
-            if (self.fleet is not None and sp.duration
-                    and self._fleet_note_inner_span
-                    and tel.tracer.sync_spans):
-                self.fleet.note_step_time(sp.duration)
-            # Feed the UNSCALED grad norm (norm_h is pre-unscale; coef is
-            # the same factor get_global_grad_norm applies) so the offload
-            # tier gets the same grad-norm anomaly coverage as the device
-            # tiers. The tiny host-side multiply is built only when a
-            # detector is listening.
-            norm = None
-            if self.guardrails is not None:
-                norm_h, coef = self._offload_last_norm
-                norm = norm_h * coef
-            rolled_back = self._guardrails_step_hook(
-                loss, getattr(self, "_offload_last_overflow", None), norm)
-            if self.config.check_numerics and not rolled_back:
-                self._check_numerics(loss, overflow=False)
-            self._post_step_hooks(loss)
-            self._emit_step_telemetry()
-            self._resilience_step_hook()
+            with tel.span("step_hooks", step=step):
+                self._offload_step_hooks(batches, loss, status, sp)
             return loss
         lr = self._current_lr()
         self._maybe_profile(self._train_step, self.state, batches, lr,
                             params=self.state.params)
-        with tel.span("train_step", step=self.global_steps) as sp:
+        with tel.span("train_step", step=step) as sp:
             out = self._train_step(self.state, batches, lr)
+        with tel.span("step_hooks", step=step):
+            return self._step_hooks(batches, lr, out, status, sp)
+
+    def _offload_step_hooks(self, batches, loss, status, sp) -> None:
+        """What follows the offloaded step's dispatch (the ``step_hooks``
+        span)."""
+        tel = self.telemetry
+        self.global_steps += 1
+        self.micro_steps += self.gradient_accumulation_steps
+        if self.lr_scheduler is not None:
+            self.lr_scheduler.step()
+        self.tput_timer.stop()
+        self._last_loss = loss
+        self._goodput_step_mark(status)
+        if self.memory is not None:
+            # Offload tier: attribute the device-side micro-scan
+            # executable (the host optimizer step has no HBM story).
+            self.memory.maybe_attribute(self, batches, None, status)
+        if (self.fleet is not None and sp.duration
+                and self._fleet_note_inner_span
+                and tel.tracer.sync_spans):
+            self.fleet.note_step_time(sp.duration)
+        # Feed the UNSCALED grad norm (norm_h is pre-unscale; coef is
+        # the same factor get_global_grad_norm applies) so the offload
+        # tier gets the same grad-norm anomaly coverage as the device
+        # tiers. The tiny host-side multiply is built only when a
+        # detector is listening.
+        norm = None
+        if self.guardrails is not None:
+            norm_h, coef = self._offload_last_norm
+            norm = norm_h * coef
+        rolled_back = self._guardrails_step_hook(
+            loss, getattr(self, "_offload_last_overflow", None), norm)
+        if self.config.check_numerics and not rolled_back:
+            self._check_numerics(loss, overflow=False)
+        self._post_step_hooks(loss)
+        self._emit_step_telemetry()
+        self._resilience_step_hook()
+
+    def _step_hooks(self, batches, lr, out, status, sp) -> jax.Array:
+        """What follows the fused step's dispatch (the ``step_hooks``
+        span): the new state, scheduler, guardrails, numerics and the
+        per-step telemetry. Returns the step's loss."""
+        tel = self.telemetry
         self.state, loss, overflow, norm = out[:4]
+        self._fused_grad_norm = norm    # a device scalar: nothing is fetched
         self.global_steps += 1
         step_aux = out[4] if len(out) > 4 else {}
         if self.numerics is not None:
@@ -2320,6 +2355,11 @@ class TPUEngine:
             if isinstance(last, tuple):
                 return float(last[0]) * last[1]
             return float(last)
+        if self._fused_grad_norm is not None:
+            # A fused train_batch() zeroes the accumulators inside the
+            # step: the norm the step itself returned (unscaled, before
+            # clipping) is the one to report.
+            return float(self._fused_grad_norm)
         # One cached jitted fn for the life of the process: a fresh
         # jax.jit(global_norm) per call built a new wrapper each time,
         # re-tracing (and re-compiling) on every invocation. The detector

@@ -43,6 +43,7 @@ from deepspeed_tpu.serving.kv_cache import (BlockPool, ChunkedLayerCache,
                                             init_paged_pools, pack_prefill)
 from deepspeed_tpu.serving.scheduler import (PrefixCache, Scheduler,
                                              Sequence)
+from deepspeed_tpu.telemetry.tracer import device_scope
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.platform import on_tpu
 
@@ -268,10 +269,15 @@ class ServeEngine:
         # program touched per row (window width x steps) — the modeled
         # HBM-traffic evidence behind the capped fallback
         # (tools/probe_serving_fastpath.py); ``full_positions`` is the
-        # uncapped counterfactual.
+        # uncapped counterfactual. ``read_positions``: what the decode
+        # programs read whatever is live (table rows x columns x block
+        # size, every dispatch); ``live_positions``: of those, the
+        # positions of active rows that hold KV. Their ratio is the
+        # useful share of the KV read.
         self.stats = {"decode_steps": 0, "occupancy_sum": 0.0,
                       "slot_assignments": {}, "kernel_steps": 0,
                       "gathered_positions": 0, "full_positions": 0,
+                      "live_positions": 0, "read_positions": 0,
                       "spec_rounds": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "spec_new_tokens": 0}
         log_dist(
@@ -296,6 +302,15 @@ class ServeEngine:
         also refuse the request outright — the returned rid then maps to
         a terminal ``results`` record with status ``shed``."""
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
+        with self.telemetry.span("submit", prompt_len=len(prompt)) as sp:
+            rid = self._submit(prompt, max_new_tokens, eos_token_id,
+                               deadline_ms)
+            sp.set_metadata(rid=rid)
+        return rid
+
+    def _submit(self, prompt: List[int], max_new_tokens: int,
+                eos_token_id: Optional[int],
+                deadline_ms: Optional[float]) -> int:
         if not prompt:
             raise ValueError("empty prompt")
         if max_new_tokens < 1:
@@ -373,6 +388,15 @@ class ServeEngine:
         """One engine iteration: admit+prefill (bounded), then advance the
         whole decode batch one token. Returns a step report
         (``finished``/``prefilled`` request ids, ``active`` count...)."""
+        with self.telemetry.span(
+                "serve_step", step=self._step_count,
+                queued=self.sched.queue_depth,
+                active=len(self.sched.running),
+                blocks_used=self.pool.used_blocks,
+                blocks_total=self.pool.capacity):
+            return self._step()
+
+    def _step(self) -> Dict[str, Any]:
         info: Dict[str, Any] = {"step": self._step_count, "prefilled": [],
                                 "finished": [], "active": 0}
         # Engine serving-time partition (telemetry/requests.py): the
@@ -395,7 +419,10 @@ class ServeEngine:
 
         # -- admission + prefill (the in-flight batching half) ----------
         for _ in range(self.scfg.max_prefills_per_step):
-            seq = self.sched.try_admit(self._bucket_of, self._step_count)
+            with self.telemetry.span("admit", step=self._step_count,
+                                     queued=self.sched.queue_depth):
+                seq = self.sched.try_admit(self._bucket_of,
+                                           self._step_count)
             if seq is None:
                 break
             if self._chunked:
@@ -621,8 +648,7 @@ class ServeEngine:
         if bucket not in self._prefill_jit:
             self._prefill_jit[bucket] = jax.jit(functools.partial(
                 self._prefill_impl, bucket=bucket))
-        with self.telemetry.span("prefill", rid=seq.request.rid,
-                                 bucket=bucket, prompt_len=t):
+        with self._prefill_span(seq, bucket, t):
             tok, _logits, ks, vs = self._prefill_jit[bucket](
                 self.engine.params, dev_ids, length, rng)
             if self._measure_kv:
@@ -661,13 +687,24 @@ class ServeEngine:
             self._tail_prefill_jit[tb] = jax.jit(functools.partial(
                 self._prefill_tail_impl, tail_bucket=tb),
                 donate_argnums=(1,))
-        with self.telemetry.span("prefill", rid=seq.request.rid,
-                                 bucket=tb, prompt_len=t, shared_len=sl):
+        with self._prefill_span(seq, tb, t, shared_len=sl):
             tok, self._pools = self._tail_prefill_jit[tb](
                 self.engine.params, self._pools, dev_ids, dev_bt, start,
                 length, rng)
             first = int(tok)                     # host fetch = first token
         self._record_first_token(seq, first)
+
+    def _prefill_span(self, seq: Sequence, bucket: int, prompt_len: int,
+                      **ids):
+        """The ``prefill`` span of one request: dispatch, pack and the
+        first token's fetch, with how long the request queued (admitted -
+        arrival, this engine's clock)."""
+        req = seq.request
+        wait_ms = ((req.admitted_time - req.arrival) * 1e3
+                   if req.admitted_time is not None else 0.0)
+        return self.telemetry.span(
+            "prefill", rid=req.rid, bucket=bucket, prompt_len=prompt_len,
+            step=self._step_count, queue_wait_ms=wait_ms, **ids)
 
     def _record_first_token(self, seq: Sequence, first: int) -> None:
         """Append the prefill's sampled token and record TTFT — on the
@@ -718,8 +755,7 @@ class ServeEngine:
                 self._tail_prefill_jit[tb] = jax.jit(functools.partial(
                     self._prefill_tail_impl, tail_bucket=tb),
                     donate_argnums=(1,))
-            with self.telemetry.span("prefill", rid=seq.request.rid,
-                                     bucket=tb, prompt_len=t, replay=1):
+            with self._prefill_span(seq, tb, t, replay=1):
                 _tok, self._pools = self._tail_prefill_jit[tb](
                     self.engine.params, self._pools, dev_ids, dev_bt,
                     start, length, rng)
@@ -734,8 +770,7 @@ class ServeEngine:
         if bucket not in self._prefill_jit:
             self._prefill_jit[bucket] = jax.jit(functools.partial(
                 self._prefill_impl, bucket=bucket))
-        with self.telemetry.span("prefill", rid=seq.request.rid,
-                                 bucket=bucket, prompt_len=t, replay=1):
+        with self._prefill_span(seq, bucket, t, replay=1):
             _tok, _logits, ks, vs = self._prefill_jit[bucket](
                 self.engine.params, dev_ids, length, rng)
             blocks = jnp.asarray(seq.block_table, jnp.int32)
@@ -753,12 +788,11 @@ class ServeEngine:
         while t0 < total:
             c = min(self._chunk_budget, total - t0)
             rows = [(seq.slot, replay[t0 + i], t0 + i) for i in range(c)]
-            with self.telemetry.span("prefill", rid=seq.request.rid,
-                                     bucket=seq.bucket, prompt_len=total,
-                                     replay=1):
+            with self._prefill_span(seq, seq.bucket, total, replay=1):
                 self._mixed_dispatch([seq], rows, 1)
             t0 += c
 
+    @device_scope("prefill")
     def _prefill_tail_impl(self, params, pools, ids, bt, start, length,
                            rng, *, tail_bucket: int):
         # The tail writes [start, start + tail_bucket) — block-aligned
@@ -780,6 +814,7 @@ class ServeEngine:
                             self.scfg.temperature, self.scfg.top_k)[0]
         return tok, tuple(c.pools for c in out["cache"])
 
+    @device_scope("prefill")
     def _prefill_impl(self, params, ids, length, rng, *, bucket: int):
         from deepspeed_tpu.models.gpt import init_kv_cache
 
@@ -916,25 +951,39 @@ class ServeEngine:
         self.stats["full_positions"] += mb * self.block_size
         if impl == "kernel":
             self.stats["kernel_steps"] += 1
+        # once this dispatch has written, row r holds pos[r] + chunk
+        ids = self._count_positions(
+            len(active), live=int(pos.sum()) + len(active) * chunk,
+            read=bt.shape[0] * wb * self.block_size)
         bt, pos, toks = jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(toks)
         self.engine.recompile_detector.check(name, toks, pos, bt)
-        return bt, pos, toks, key, impl
+        return bt, pos, toks, key, impl, ids
+
+    def _count_positions(self, active: int, live: int,
+                         read: int) -> Dict[str, int]:
+        """Add one decode dispatch to the running totals of positions read
+        and of those that are live, and return what its span carries."""
+        self.stats["live_positions"] += live
+        self.stats["read_positions"] += read
+        return {"step": self._step_count, "active": active,
+                "live_positions": live, "read_positions": read}
 
     def _decode(self, active: List[Sequence]):
-        bt, pos, toks, key, impl = self._dispatch_batch(
+        bt, pos, toks, key, impl, ids = self._dispatch_batch(
             active, 1, "serving.decode_step")
         rng = jax.random.fold_in(self._base_key, 2 * self._step_count)
         if key not in self._decode_jits:
             self._decode_jits[key] = jax.jit(
                 functools.partial(self._decode_impl, attn_impl=impl),
                 donate_argnums=(1,))
-        with self.telemetry.span("decode_step", active=len(active)):
+        with self.telemetry.span("decode_step", **ids):
             tok_dev, logits, self._pools = self._decode_jits[key](
                 self.engine.params, self._pools, bt, pos, toks, rng)
             tok_host = np.asarray(tok_dev)       # host fetch: finish checks
         logits_host = np.asarray(logits) if self.capture_logits else None
         return [int(tok_host[s.slot]) for s in active], logits_host
 
+    @device_scope("decode")
     def _decode_impl(self, params, pools, bt, pos, toks, rng, *,
                      attn_impl: str = "gather"):
         cache = tuple(
@@ -1029,6 +1078,11 @@ class ServeEngine:
             bt[seq.slot, :len(seq.block_table)] = seq.block_table
         for r, (sl, tk, p) in enumerate(rows):
             slots[r], toks[r], pos[r] = sl, tk, p
+        # every token of the budget reads its row of the table; a real
+        # token at position p sees p + 1 keys
+        ids = self._count_positions(
+            n_active, live=int(pos.sum()) + len(rows),
+            read=self._chunk_budget * mb * self.block_size)
         bt, pos, toks, slots = (jnp.asarray(bt), jnp.asarray(pos),
                                 jnp.asarray(toks), jnp.asarray(slots))
         self.engine.recompile_detector.check("serving.mixed_step", toks,
@@ -1037,14 +1091,14 @@ class ServeEngine:
             self._mixed_jit = jax.jit(self._mixed_impl,
                                       donate_argnums=(1,))
         rng = jax.random.fold_in(self._base_key, 2 * self._step_count)
-        with self.telemetry.span("mixed_step", active=n_active,
-                                 tokens=len(rows)):
+        with self.telemetry.span("mixed_step", tokens=len(rows), **ids):
             tok_dev, self._pools = self._mixed_jit(
                 self.engine.params, self._pools, bt, pos, slots, toks,
                 rng)
             tok_host = np.asarray(tok_dev)       # host fetch: finish checks
         return tok_host
 
+    @device_scope("decode")
     def _mixed_impl(self, params, pools, bt, pos, slots, toks, rng):
         max_pos = self.model_cfg.max_seq_len - 1
         cache = tuple(
@@ -1115,13 +1169,13 @@ class ServeEngine:
                 "decoding — a spec round has no single per-step logits "
                 "row to expose (docs/SERVING.md)")
         k = self._spec_k
-        bt, pos, toks, key, impl = self._dispatch_batch(
+        bt, pos, toks, key, impl, ids = self._dispatch_batch(
             active, k + 1, "serving.spec_step")
         if key not in self._spec_jits:
             self._spec_jits[key] = jax.jit(
                 functools.partial(self._spec_impl, k=k, attn_impl=impl),
                 donate_argnums=(1,))
-        with self.telemetry.span("spec_step", active=len(active), k=k):
+        with self.telemetry.span("spec_step", k=k, **ids):
             chunk_dev, greedy_dev, self._pools = self._spec_jits[key](
                 self.engine.params, self._pools, bt, pos, toks)
             chunk = np.asarray(chunk_dev)        # [B, k+1] verify inputs
@@ -1150,6 +1204,7 @@ class ServeEngine:
         self.stats["spec_new_tokens"] += appended
         return appended
 
+    @device_scope("decode")
     def _spec_impl(self, params, pools, bt, pos, toks, *, k: int,
                    attn_impl: str):
         """Draft scan (k+1 single-token steps — the extra step pre-writes

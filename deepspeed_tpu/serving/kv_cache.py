@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.comm.quantize import quantize_blockwise
+from deepspeed_tpu.telemetry.tracer import device_scope
 
 
 class BlockPool:
@@ -213,6 +214,7 @@ class PagedLayerCache:
         return (self.k, self.v, self.k_scale, self.v_scale)
 
     # -- traced ops -----------------------------------------------------
+    @device_scope("kv_write")
     def _write(self, pool, scale, chunk):
         """Scatter ``chunk`` [B, S, H, D] at per-row positions
         ``pos..pos+S-1`` through the block table."""
@@ -236,6 +238,7 @@ class PagedLayerCache:
             return pool.at[blk, off].set(q), scale.at[blk, off].set(sc)
         return pool.at[blk, off].set(chunk.astype(pool.dtype)), None
 
+    @device_scope("kv_gather")
     def _gather(self, pool, scale):
         """[B, MB, BS, H, D] pool gather -> [B, L, H, D] keys/values."""
         b, mb = self.block_table.shape
@@ -349,6 +352,7 @@ class ChunkedLayerCache:
         return (self.k, self.v, self.k_scale, self.v_scale)
 
     # -- traced ops -----------------------------------------------------
+    @device_scope("kv_write")
     def _write(self, pool, scale, chunk):
         """Scatter ``chunk`` [T, H, D] — one write per ragged token at
         its own ``(slot, pos)``. Pad tokens all collide on the scratch
@@ -382,6 +386,7 @@ class ChunkedLayerCache:
         return new, o[None].astype(q.dtype)
 
 
+@device_scope("pack")
 def pack_prefill(pools: Tuple, blocks: jax.Array,
                  k_stack: jax.Array, v_stack: jax.Array) -> Tuple:
     """Scatter a prefilled contiguous cache into pool blocks (jit this).

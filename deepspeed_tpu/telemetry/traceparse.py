@@ -186,18 +186,51 @@ def percentile(sorted_vals: List[float], q: float) -> float:
     return sorted_vals[lo] + (sorted_vals[hi] - sorted_vals[lo]) * (pos - lo)
 
 
+def _self_durations(spans: List[Dict[str, Any]]) -> List[float]:
+    """Each complete event's duration less what its children cover, a
+    child being an event of the same thread that lies inside it
+    (``train_batch`` encloses ``train_step``, ``serve_step`` encloses
+    ``prefill``): self times add up to the time spans cover, durations
+    count an enclosed span twice."""
+    start = [float(ev.get("ts", 0.0)) for ev in spans]
+    end = [s + float(ev.get("dur", 0.0)) for s, ev in zip(start, spans)]
+    out = [e - s for s, e in zip(start, end)]
+    threads: Dict[Any, List[int]] = {}
+    for i, ev in enumerate(spans):
+        # load_many prefixes a name with its host: two hosts' threads may
+        # share pid and tid, and never nest
+        host = ev.get("name", "").rpartition(":")[0]
+        threads.setdefault((host, ev.get("pid"), ev.get("tid")),
+                           []).append(i)
+    for idx in threads.values():
+        idx.sort(key=lambda i: (start[i], -end[i]))
+        stack: List[int] = []
+        for i in idx:
+            while stack and end[stack[-1]] <= start[i]:
+                stack.pop()
+            if stack:
+                out[stack[-1]] -= min(end[i], end[stack[-1]]) - start[i]
+            stack.append(i)
+    return out
+
+
 def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Per-span-name totals / percentiles, counter last-values, instant
-    counts — the trace_report table's data."""
+    counts — the trace_report table's data. ``share`` is a span's share
+    of SELF time, so the column adds up to 100% although spans nest."""
     spans: Dict[str, List[float]] = {}
+    self_us: Dict[str, float] = {}
     counters: Dict[str, float] = {}
     instants: Dict[str, int] = {}
+    complete = [ev for ev in events if ev.get("ph") == "X"]
+    for ev, own in zip(complete, _self_durations(complete)):
+        name = ev.get("name", "<unnamed>")
+        spans.setdefault(name, []).append(float(ev.get("dur", 0.0)))
+        self_us[name] = self_us.get(name, 0.0) + max(own, 0.0)
     for ev in events:
         ph = ev.get("ph")
         name = ev.get("name", "<unnamed>")
-        if ph == "X":
-            spans.setdefault(name, []).append(float(ev.get("dur", 0.0)))
-        elif ph == "C":
+        if ph == "C":
             args = ev.get("args") or {}
             # last write wins: counters carry running totals
             for k, v in args.items():
@@ -212,13 +245,14 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
             "name": name,
             "count": len(durs),
             "total_ms": total / 1e3,
+            "self_ms": self_us[name] / 1e3,
             "mean_ms": total / len(durs) / 1e3,
             "p50_ms": percentile(durs, 50) / 1e3,
             "p99_ms": percentile(durs, 99) / 1e3,
         })
-    grand = sum(r["total_ms"] for r in rows) or 1.0
+    grand = sum(r["self_ms"] for r in rows) or 1.0
     for r in rows:
-        r["share"] = r["total_ms"] / grand
+        r["share"] = r["self_ms"] / grand
     return {"spans": rows, "counters": counters, "instants": instants}
 
 

@@ -1,6 +1,17 @@
-"""Step tracer — Chrome trace-event spans for the training loop.
+"""Step tracer — the program's one span primitive.
 
-Records named spans (dataloader / forward / backward / optimizer_step /
+``StepTracer.span(name, **ids)`` always opens a
+``jax.profiler.TraceAnnotation("ds.<name>", **ids)``: while a
+``jax.profiler`` session runs, whoever started it, the span lands on the
+host plane of the profiler's own trace, on the same clock as the device
+ops, with ``ids`` as the event's stats. With no session it costs well
+under a microsecond and records nothing. ``device_scope(name)`` is the
+device-side counterpart: a ``jax.named_scope("ds.<name>")`` whose name the
+compiler keeps in every op's metadata (docs/OBSERVABILITY.md, "Spans in
+the profiler's trace").
+
+When the tracer is enabled (a path is configured) it ALSO records named
+spans (dataloader / forward / backward / optimizer_step /
 ckpt_snapshot / ckpt_write / ...) as Chrome trace-event JSON, the format
 Perfetto and ``chrome://tracing`` open directly, plus instant and counter
 events. ``tools/trace_report.py`` renders the same file as a per-span time
@@ -12,9 +23,10 @@ returns, so a host-side wall-clock span around a dispatch measures the
 enabled tracer), the tracer drains the device queue at every span boundary —
 the span then brackets exactly the device work issued inside it, which is
 the T3-style "where does step time go" attribution. The sync barrier is
-gated on the tracer being enabled: a disabled tracer's ``span()`` is a
-reusable no-op context manager that performs **zero** ``block_until_ready``
-calls and no allocation beyond one attribute check.
+gated on the tracer being enabled: a disabled tracer's ``span()`` is the
+profiler annotation alone, which performs **zero** ``block_until_ready``
+calls and records no Chrome event. ``sync_spans`` belongs to the
+Chrome-JSON file only.
 
 Optional ``jax.profiler`` passthrough: give ``jax_profiler_dir`` and the
 tracer starts a profiler session alongside (device-level XLA timeline, for
@@ -28,6 +40,39 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
+# Every span and device scope of the program carries this prefix in the
+# profiler's trace (benchmarks/program_trace.py finds them by it).
+PREFIX = "ds."
+
+# The device scopes the program opens, each with the part of a jitted
+# program it brackets. Forward and backward need none: JAX writes
+# ``jvp(...)`` and ``transpose(jvp(...))`` into the name stack itself.
+DEVICE_SCOPES = {
+    "cast_params": "master weights to the compute dtype (and the zeropp "
+                   "gather), once per optimizer step",
+    "accumulate": "a micro-batch's gradients added into the accumulator",
+    "grad_sync": "an explicit gradient sync (hierarchical, 1-bit)",
+    "optimizer": "unscale, norm, clip, update, overflow select, zeroing",
+    "prefill": "the serving prefill program",
+    "pack": "a prefilled cache scattered into pool blocks",
+    "decode": "the serving decode program (mixed and speculative alike)",
+    "kv_gather": "the pool indexed by the block table, reshape, dequant",
+    "kv_write": "the new K and V scattered into the pool",
+    "sample": "logits to token ids",
+}
+
+
+def device_scope(name: str):
+    """``jax.named_scope("ds.<name>")`` for a name of ``DEVICE_SCOPES``,
+    as a context manager or a function decorator: HLO metadata only, the
+    executable computes the same thing."""
+    if name not in DEVICE_SCOPES:
+        raise KeyError(f"unknown device scope {name!r}; "
+                       f"known: {sorted(DEVICE_SCOPES)}")
+    return jax.named_scope(PREFIX + name)
+
 
 def _device_sync() -> None:
     """Drain the device queue. Routed through ``utils.timer`` so the whole
@@ -37,24 +82,19 @@ def _device_sync() -> None:
     _timer._device_synchronize()
 
 
-class _NullSpan:
-    """Reusable no-op context manager for the disabled tracer."""
+class _ProfilerSpan(jax.profiler.TraceAnnotation):
+    """The annotation-only span of a disabled tracer: an event in the
+    profiler's trace while a session runs, nothing otherwise."""
 
     __slots__ = ()
     duration = 0.0
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0", "duration")
+    """The enabled tracer's span: the profiler annotation plus a Chrome
+    event, with the device drained at both ends under ``sync_spans``."""
+
+    __slots__ = ("_tracer", "name", "args", "_t0", "duration", "_ann")
 
     def __init__(self, tracer: "StepTracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -62,17 +102,26 @@ class _Span:
         self.args = args
         self._t0 = 0.0
         self.duration = 0.0
+        self._ann = jax.profiler.TraceAnnotation(PREFIX + name, **args)
 
     def __enter__(self):
         if self._tracer.sync_spans:
             _device_sync()
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def set_metadata(self, **ids) -> None:
+        """More identifiers for a span that is open (a request's ``rid``
+        exists only once the scheduler has drawn it)."""
+        self.args.update(ids)
+        self._ann.set_metadata(**ids)
 
     def __exit__(self, *exc):
         if self._tracer.sync_spans:
             _device_sync()
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         self.duration = t1 - self._t0
         self._tracer._record(self.name, self._t0, t1, self.args)
         return False
@@ -148,13 +197,16 @@ class StepTracer:
             self._append(ev)
 
     # -- public API -----------------------------------------------------
-    def span(self, name: str, **args):
-        """Context manager timing the enclosed region (no-op when
-        disabled). The returned handle exposes ``.duration`` (seconds)
-        after exit."""
+    def span(self, name: str, **ids):
+        """Context manager round a region of host code: always a
+        ``ds.<name>`` annotation in the profiler's trace, and a Chrome
+        event as well when the tracer is enabled. ``ids`` are host ints,
+        floats or short strings already in hand (never a device value).
+        The handle exposes ``.duration`` in seconds after exit, 0.0 on
+        the annotation-only path."""
         if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, args)
+            return _ProfilerSpan(PREFIX + name, **ids)
+        return _Span(self, name, ids)
 
     def instant(self, name: str, **args) -> None:
         if not self.enabled:

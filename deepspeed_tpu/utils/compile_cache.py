@@ -10,6 +10,16 @@ directory is part of the cache key, so a ``tempfile``, pid or timestamp
 path would never hit. The library entry points (``initialize``,
 ``init_serving``, ``init_inference``) do not touch cache configuration:
 where a user's job caches is the user's decision.
+
+What the cache is keyed by is set in either case: JAX leaves an op's
+metadata (its ``jax.named_scope`` path, its source line) out of the key by
+default, so a cache filled by another version of the program hands back
+executables whose ops bear THAT version's names. A profile then shows
+scopes this program does not have and misses the ones it has (seen on the
+chip: a checkout without the ``ds.*`` scopes loaded executables that had
+them; PERF.md, PR 24). With the metadata in the key a hit is the same
+program, names included; the price is a cold compile after an edit that
+only moves lines in a traced file.
 """
 
 import os
@@ -27,6 +37,7 @@ DEFAULT_CACHE_DIR = os.path.join(
 def configure_compile_cache() -> str:
     """Place the compile cache per the rule above; returns the directory
     in force (for the caller to print)."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get(CACHE_DIR_ENV)
     if from_env:
         return from_env

@@ -33,13 +33,14 @@ def _load_bench(tmp_path, monkeypatch):
     return mod
 
 
-def _run_python(*argv):
+def _run_python(*argv, env=None):
     """``python *argv`` from the repo root on the CPU, with no compile
-    cache directory inherited from the caller's environment."""
-    env = {k: v for k, v in os.environ.items()
-           if k != "JAX_COMPILATION_CACHE_DIR"}
+    cache directory inherited from the caller's environment (``env`` is
+    laid over it)."""
+    base = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
     return subprocess.run([sys.executable, *argv], cwd=REPO,
-                          env={**env, "JAX_PLATFORMS": "cpu"},
+                          env={**base, "JAX_PLATFORMS": "cpu", **(env or {})},
                           capture_output=True, text=True, timeout=120)
 
 
@@ -220,7 +221,44 @@ class TestCompileCachePlacement:
         monkeypatch.setattr(jax.config, "update",
                             lambda *a: updates.append(a))
         assert compile_cache.configure_compile_cache() == str(tmp_path)
-        assert updates == []
+        # where the cache lives is left alone; what it is keyed by is not
+        # (ISSUE 24: a hit must carry this program's scopes)
+        assert updates == [
+            ("jax_compilation_cache_include_metadata_in_key", True)]
+
+
+    def test_a_cache_hit_carries_this_programs_scopes(self, tmp_path):
+        """Two programs that differ in a ``named_scope`` alone: with JAX's
+        default key the second loads the first one's executable, names
+        and all; after ``configure_compile_cache()`` it compiles its own."""
+        snippet = (
+            "import sys, jax, jax.numpy as jnp, jax.monitoring as mon\n"
+            "if sys.argv[1] == 'ours':\n"
+            "    from deepspeed_tpu.utils.compile_cache import "
+            "configure_compile_cache as f\n"
+            "    f()\n"
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
+            " 0.0)\n"
+            "jax.config.update('jax_persistent_cache_min_entry_size_bytes',"
+            " -1)\n"
+            "seen = []\n"
+            "mon.register_event_listener(lambda e, **kw: seen.append("
+            "e.rsplit('/', 1)[-1]))\n"
+            "def step(x):\n"
+            "    return jnp.sin(x) * 2 + 1\n"
+            "def scoped(x):\n"
+            "    with jax.named_scope('ds.optimizer'):\n"
+            "        return jnp.sin(x) * 2 + 1\n"
+            "scoped.__name__ = 'step'\n"
+            "for fn in (step, scoped):\n"
+            "    seen.clear()\n"
+            "    jax.jit(fn)(jnp.ones(8)).block_until_ready()\n"
+            "    print('hit' if 'cache_hits' in seen else 'miss')\n")
+        for keyed, want in (("default", ["miss", "hit"]),
+                            ("ours", ["miss", "miss"])):
+            env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / keyed)}
+            out = _run_python("-c", snippet, keyed, env=env)
+            assert out.stdout.split() == want, (keyed, out.stderr[-2000:])
 
 
 class TestUnknownDeviceHasNoPeak:

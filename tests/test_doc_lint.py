@@ -35,6 +35,11 @@ from deepspeed_tpu.telemetry.requests import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.join(REPO, "deepspeed_tpu")
 DOC = os.path.join(REPO, "docs", "OBSERVABILITY.md")
+SPAN_SECTION = "## Spans in the profiler's trace"
+
+# telemetry.span("name", ...) / tracer.span(\n "name" / self._span("name"
+_SPAN_CALL_RE = re.compile(r"\b_?span\(\s*\"([a-z_]+)\"")
+_KERNEL_NAME_RE = re.compile(r"^\s*name=\"([a-z_]+)\",$", re.MULTILINE)
 
 # .gauge("a/b") / .counter(f"a/{x}") / .histogram('a') / ._counter("a/b")
 _METRIC_CALL_RE = re.compile(
@@ -490,3 +495,46 @@ class TestDocDrift:
             assert f'"{cat}"' in src, (
                 f"tools/goodput_report.py CATEGORIES is missing {cat!r} — "
                 "keep it in sync with telemetry/goodput.py")
+
+
+class TestSpanTable:
+    """The section "Spans in the profiler's trace" against the code: every
+    span name, device scope and kernel name the package emits is in it."""
+
+    @staticmethod
+    def _section():
+        doc = _doc_text()
+        start = doc.index(SPAN_SECTION)
+        return doc[start:doc.index("\n## ", start + 1)]
+
+    def test_every_span_name_is_in_the_table(self):
+        names = set()
+        for path in _iter_py_files():
+            with open(path) as f:
+                names.update(_SPAN_CALL_RE.findall(f.read()))
+        # the scan sees the old call sites and the new ones
+        assert {"dataloader", "train_step", "prefill", "decode_step",
+                "train_batch", "step_hooks", "serve_step", "admit",
+                "submit", "ckpt_write"} <= names
+        section = self._section()
+        missing = sorted(n for n in names if f"`{n}`" not in section)
+        assert not missing, (
+            f"span names emitted but absent from docs/OBSERVABILITY.md "
+            f"({SPAN_SECTION!r}): {missing}")
+
+    def test_every_device_scope_and_kernel_name_is_in_the_section(self):
+        from deepspeed_tpu.telemetry.tracer import DEVICE_SCOPES, PREFIX
+        section = self._section()
+        missing = sorted(n for n in DEVICE_SCOPES
+                         if f"`{PREFIX}{n}`" not in section)
+        assert not missing, missing
+        kernels = set()
+        for path in _iter_py_files():
+            with open(path) as f:
+                src = f.read()
+            if "pallas_call(" in src:
+                kernels.update(_KERNEL_NAME_RE.findall(src))
+        assert len(kernels) == 10, kernels
+        assert not sorted(k for k in kernels if f"`{k}`" not in section)
+        # the section says how to read them and what they cost
+        assert "dump_xplane.py" in section and "sync_spans" in section

@@ -625,8 +625,14 @@ class TestGlobalNormNoRetrace:
         from deepspeed_tpu.runtime.utils import global_norm
 
         engine = _engine({"telemetry": _tel(tmp_path)})
-        engine.train_batch(random_batches(np.random.default_rng(0), gas=1,
-                                          batch_size=16))
+        rng = np.random.default_rng(0)
+        engine.train_batch(random_batches(rng, gas=1, batch_size=16))
+        # After a fused step the engine reports the norm that step
+        # returned (ISSUE 24); the jitted norm of the accumulators, which
+        # this test is about, serves the forward()/backward() path.
+        engine.backward(engine.forward(
+            {k: v[0] for k, v in random_batches(rng, gas=1,
+                                                batch_size=16).items()}))
         traces = {"n": 0}
 
         def counted(tree):

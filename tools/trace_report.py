@@ -67,12 +67,15 @@ def render(summary: Dict[str, Any], sort: str = "total") -> str:
     key = {"total": "total_ms", "mean": "mean_ms", "count": "count"}[sort]
     rows = sorted(summary["spans"], key=lambda r: r[key], reverse=True)
     out = []
-    hdr = (f"{'span':<24} {'count':>7} {'total ms':>12} {'mean ms':>10} "
-           f"{'p50 ms':>10} {'p99 ms':>10} {'share':>7}")
+    # ``share`` is of SELF time (a span less the spans inside it), so the
+    # column adds up though train_batch and serve_step enclose others
+    hdr = (f"{'span':<24} {'count':>7} {'total ms':>12} {'self ms':>12} "
+           f"{'mean ms':>10} {'p50 ms':>10} {'p99 ms':>10} {'share':>7}")
     out.append(hdr)
     out.append("-" * len(hdr))
     for r in rows:
         out.append(f"{r['name']:<24} {r['count']:>7} {r['total_ms']:>12.3f} "
+                   f"{r['self_ms']:>12.3f} "
                    f"{r['mean_ms']:>10.3f} {r['p50_ms']:>10.3f} "
                    f"{r['p99_ms']:>10.3f} {r['share']:>6.1%}")
     if not rows:
