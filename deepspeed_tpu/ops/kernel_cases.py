@@ -165,8 +165,11 @@ def _paged_reference(q, k_pool, v_pool, k_scale, v_scale, table, pos,
     (table-slot order) is visible to query i iff j <= pos + i."""
     from deepspeed_tpu.ops.transformer.attention import xla_attention
 
+    heads = q.shape[2]
+
     def gathered(pool, scale):
-        x = pool.astype(jnp.float32)
+        # unfold the stored [N, BS, H*D] form
+        x = pool.astype(jnp.float32).reshape(*pool.shape[:2], heads, -1)
         if scale is not None:
             x = x * scale[..., None]
         x = x[table]                               # [B, WB, BS, H, D]
@@ -198,6 +201,8 @@ def _paged_args(rng, q_shape, int8: bool):
     else:
         # fp pools carry no scales: None is an empty pytree to jit
         (k, ks), (v, vs) = ((p.astype(jnp.bfloat16), None) for p in pools)
+    # the stored form: heads folded into the lane axis
+    k, v = (p.reshape(n_blocks, bs, heads * d) for p in (k, v))
     return q, k, v, ks, vs, jnp.asarray(table), jnp.asarray(pos)
 
 

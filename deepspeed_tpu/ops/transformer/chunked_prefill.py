@@ -57,8 +57,9 @@ def chunked_prefill_attention(q: jax.Array, k_pool: jax.Array,
     """Attention of a ragged token batch ``q`` [T, H, D] over the paged
     pool through **per-token** block tables.
 
-    ``k_pool``/``v_pool``: [N, BS, H, D] (fp, or int8 with ``k_scale``/
-    ``v_scale`` [N, BS, H] fp32 per-(token, head) scales). ``table``:
+    ``k_pool``/``v_pool``: [N, BS, H*D], the pool as it is stored (fp, or
+    int8 with ``k_scale``/``v_scale`` [N, BS, H] fp32 per-(token, head)
+    scales). ``table``:
     [T, WB] int32 — row ``t`` is the block-table row of the sequence that
     token ``t`` belongs to (the caller gathers ``block_table[slots]``;
     pad tokens carry an all-scratch row). ``pos``: [T] int32 — token

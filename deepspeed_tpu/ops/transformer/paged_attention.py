@@ -148,8 +148,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     """Attention of ``q`` [B, S, H, D] over the paged pool through each
     row's block table.
 
-    ``k_pool``/``v_pool``: [N, BS, H, D] (fp, or int8 with ``k_scale``/
-    ``v_scale`` [N, BS, H] fp32 per-(token, head) scales). ``block_table``:
+    ``k_pool``/``v_pool``: [N, BS, H*D], the pool as it is stored
+    (``serving/kv_cache.py``: heads folded into the lane axis; fp, or
+    int8 with ``k_scale``/``v_scale`` [N, BS, H] fp32 per-(token, head)
+    scales). ``block_table``:
     [B, WB] int32 pool-block ids (the caller may pass a column-sliced
     window — all positions indexed are table-relative). ``pos``: [B]
     int32, the first query's position (queries sit at ``pos..pos+S-1``).
@@ -157,29 +159,28 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     written into the pools (``PagedLayerCache.update_attend`` does both).
     """
     b, s, h, d = q.shape
-    n = k_pool.shape[0]
     wb = block_table.shape[1]
     bs = int(block_size)
-    if k_pool.shape[1] != bs:
-        raise ValueError(f"pool block size {k_pool.shape[1]} != {bs}")
+    if k_pool.shape[1:] != (bs, h * d):
+        raise ValueError(f"pool blocks are {k_pool.shape[1:]}, not the "
+                         f"stored form [block_size, heads * head_dim] = "
+                         f"{(bs, h * d)}")
     scale = softmax_scale if softmax_scale is not None else 1.0 / (d ** 0.5)
     interpret = not on_tpu() if interpret is None else interpret
     int8 = k_scale is not None
 
     kernel = functools.partial(_decode_kernel, scale=float(scale),
                                block_size=bs, num_q=s, int8=int8)
-    # Heads fold into the lane axis ([.., H, D] -> [.., H*D], a free
-    # reshape of trailing contiguous dims — no relayout of the donated,
-    # per-step-rewritten pools): a block is then one head's D columns,
-    # (rows, D) on the last two dims, which is what Mosaic tiles. A
-    # (.., 1, D) block over [.., H, D] puts 1 on the sublane axis and is
-    # refused at lowering.
+    # Heads sit folded in the lane axis ([.., H*D]: the pool is stored
+    # so, and q is a small activation to fold): a block is then one
+    # head's D columns, (rows, D) on the last two dims, which is what
+    # Mosaic tiles. A (.., 1, D) block over [.., H, D] puts 1 on the
+    # sublane axis and is refused at lowering.
     q_spec = pl.BlockSpec((None, s, d), lambda bi, hi, wi, bt, p: (bi, 0, hi))
     kv_spec = pl.BlockSpec((None, bs, d),
                            lambda bi, hi, wi, bt, p: (bt[bi, wi], 0, hi))
     in_specs = [q_spec, kv_spec, kv_spec]
-    inputs = [q.reshape(b, s, h * d), k_pool.reshape(n, bs, h * d),
-              v_pool.reshape(n, bs, h * d)]
+    inputs = [q.reshape(b, s, h * d), k_pool, v_pool]
     if int8:
         # Whole-heads (BS, H) scale blocks straight from the pool
         # layout; the kernel picks its head's column. H extra lanes per

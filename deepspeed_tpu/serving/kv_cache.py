@@ -12,12 +12,27 @@ never retraces as sequences grow, join or leave (the per-request
 
 Layout (per transformer layer, all layers share one block table):
 
-- ``k``/``v`` pool: ``[num_blocks, block_size, heads, head_dim]`` in the
+- ``k``/``v`` pool: ``[num_blocks, block_size, heads * head_dim]`` in the
   model's compute dtype — or **int8** with per-(token, head) fp32 scales
   ``[num_blocks, block_size, heads]`` when ``int8=True``. Quantization is
   the SAME deterministic RTNE blockwise round-trip the DCN gradient path
   uses (:func:`deepspeed_tpu.comm.quantize.quantize_blockwise` with
   ``block_size=head_dim``) — one int8 implementation in the tree.
+
+  Heads are **folded into the lane axis**, and this is the ONE stored
+  form: allocated, carried between programs, scattered into and gathered
+  from as such, by every program and both Pallas kernels. Why: a
+  ``[N, BS, H, 64]`` array has a 64-wide minor axis, which row-major
+  ``(8, 128)`` tiles would pad to 128 lanes; the TPU runtime avoids the
+  padding by handing such an array over in a compact layout with the
+  BLOCK axis minor-most (``{0,3,2,1}``), which no gather or scatter over
+  blocks can use. Every program that took the pool therefore copied the
+  whole of it into row-major on entry and back on exit (70 of a 163 ms
+  decode step and all 70 ms of a pack at gpt2-medium with 4097 blocks;
+  PERF.md section 6, PR 25). ``[N, BS, H * D]`` has a minor axis that is
+  a multiple of 128 at every real model's width, arrives row-major, and
+  is read and written in place. Only the small activations (this step's
+  chunk, the gathered window) are reshaped to and from ``[.., H, D]``.
 - block table: ``[batch_slots, max_blocks_per_seq]`` int32, row ``b``
   listing the pool blocks of the sequence in slot ``b``. **Block 0 is a
   reserved scratch block**: inactive slots point at it, so their (masked,
@@ -125,9 +140,16 @@ def init_paged_pools(cfg, num_blocks: int, block_size: int,
                      int8: bool = False, dtype=None) -> Tuple:
     """Per-layer ``(k, v, k_scale, v_scale)`` pool arrays (scales are None
     in the fp path). Zero-initialised: scratch/unwritten slots dequantize
-    to exact zeros, so masked attention terms stay exactly ``0 * 0``."""
+    to exact zeros, so masked attention terms stay exactly ``0 * 0``.
+
+    K/V pools are ``[num_blocks, block_size, heads * head_dim]`` (the
+    module docstring says why). The int8 SCALE pools stay
+    ``[num_blocks, block_size, heads]``: at 1/32 of the pool's bytes
+    their own layout round trip is small, and the two obvious folds
+    (``[N, BS * H]`` written through a reshape, or by an element scatter)
+    each still compile to pool-sized copies or reshapes."""
     dtype = dtype if dtype is not None else cfg.dtype
-    shape = (num_blocks, block_size, cfg.num_heads, cfg.head_dim)
+    shape = (num_blocks, block_size, cfg.num_heads * cfg.head_dim)
     sshape = (num_blocks, block_size, cfg.num_heads)
     layers = []
     for _ in range(cfg.num_layers):
@@ -235,20 +257,21 @@ class PagedLayerCache:
         off = idx % self.block_size
         if scale is not None:
             q, sc = _quant_tokens(chunk)
-            return pool.at[blk, off].set(q), scale.at[blk, off].set(sc)
-        return pool.at[blk, off].set(chunk.astype(pool.dtype)), None
+            return (pool.at[blk, off].set(q.reshape(b, s, -1)),
+                    scale.at[blk, off].set(sc))
+        return pool.at[blk, off].set(
+            chunk.reshape(b, s, -1).astype(pool.dtype)), None
 
     @device_scope("kv_gather")
-    def _gather(self, pool, scale):
-        """[B, MB, BS, H, D] pool gather -> [B, L, H, D] keys/values."""
-        b, mb = self.block_table.shape
-        g = pool[self.block_table]                # [B, MB, BS, H, D]
-        g = g.reshape(b, self.key_len, *pool.shape[2:])
+    def _gather(self, pool, scale, heads: int):
+        """[B, MB, BS, H*D] pool gather -> [B, L, H, D] keys/values."""
+        b = self.block_table.shape[0]
+        g = pool[self.block_table]                # [B, MB, BS, H*D]
+        g = g.reshape(b, self.key_len, heads, -1)
         if scale is not None:
             # Per-(token, head) dequant — the inverse of _quant_tokens'
             # head_dim-block RTNE (comm/quantize.py round-trip semantics).
-            sc = scale[self.block_table].reshape(b, self.key_len,
-                                                 scale.shape[-1])
+            sc = scale[self.block_table].reshape(b, self.key_len, heads)
             g = g.astype(jnp.float32) * sc[..., None]
         return g.astype(jnp.dtype(self.dtype_name))
 
@@ -266,8 +289,9 @@ class PagedLayerCache:
         new = PagedLayerCache(k, v, ks, vs, self.block_table, self.pos,
                               self.block_size, self.dtype_name,
                               self.attn_impl, self.clamp_writes)
-        kk = new._gather(k, ks)
-        vv = new._gather(v, vs)
+        heads = k_new.shape[2]
+        kk = new._gather(k, ks, heads)
+        vv = new._gather(v, vs, heads)
         qpos = self.pos[:, None] + jnp.arange(s)[None, :]        # [B, S]
         kpos = jnp.arange(self.key_len)
         mask = kpos[None, None, :] <= qpos[:, :, None]           # [B, S, L]
@@ -360,10 +384,13 @@ class ChunkedLayerCache:
         distinct and tables are disjoint)."""
         blk = self.block_table[self.slots, self.pos // self.block_size]
         off = self.pos % self.block_size                         # [T]
+        t = chunk.shape[0]
         if scale is not None:
             q, sc = _quant_tokens(chunk)
-            return pool.at[blk, off].set(q), scale.at[blk, off].set(sc)
-        return pool.at[blk, off].set(chunk.astype(pool.dtype)), None
+            return (pool.at[blk, off].set(q.reshape(t, -1)),
+                    scale.at[blk, off].set(sc))
+        return pool.at[blk, off].set(
+            chunk.reshape(t, -1).astype(pool.dtype)), None
 
     def update_attend(self, q: jax.Array, k_new: jax.Array,
                       v_new: jax.Array,
@@ -400,15 +427,17 @@ def pack_prefill(pools: Tuple, blocks: jax.Array,
     nb = blocks.shape[0]
     out = []
     for i, (k, v, ks, vs) in enumerate(pools):
-        bs = k.shape[1]
-        kb = k_stack[i].reshape(nb, bs, *k.shape[2:])
-        vb = v_stack[i].reshape(nb, bs, *v.shape[2:])
+        rows = (nb, k.shape[1], -1)     # whole blocks, [H, D] folded
         if ks is not None:
-            kq, ksc = _quant_tokens(kb)
-            vq, vsc = _quant_tokens(vb)
-            out.append((k.at[blocks].set(kq), v.at[blocks].set(vq),
-                        ks.at[blocks].set(ksc), vs.at[blocks].set(vsc)))
+            kq, ksc = _quant_tokens(k_stack[i])
+            vq, vsc = _quant_tokens(v_stack[i])
+            out.append((k.at[blocks].set(kq.reshape(rows)),
+                        v.at[blocks].set(vq.reshape(rows)),
+                        ks.at[blocks].set(ksc.reshape(rows)),
+                        vs.at[blocks].set(vsc.reshape(rows))))
         else:
-            out.append((k.at[blocks].set(kb.astype(k.dtype)),
-                        v.at[blocks].set(vb.astype(v.dtype)), None, None))
+            out.append((
+                k.at[blocks].set(k_stack[i].reshape(rows).astype(k.dtype)),
+                v.at[blocks].set(v_stack[i].reshape(rows).astype(v.dtype)),
+                None, None))
     return tuple(out)

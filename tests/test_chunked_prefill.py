@@ -73,6 +73,11 @@ def _pools(rng, nblocks, block_size, h, d, dtype=np.float32):
     return k, v
 
 
+def _stored(pool):
+    """[N, BS, H, D] -> the pool's stored form [N, BS, H*D]."""
+    return jnp.asarray(pool).reshape(*pool.shape[:2], -1)
+
+
 class TestChunkedPrefillKernel:
     @pytest.mark.parametrize("pos", [
         # mixed: decode rows (deep pos) + prefill chunk rows (ragged)
@@ -94,7 +99,7 @@ class TestChunkedPrefillKernel:
                           for _ in range(t)]).astype(np.int32)
         pos = np.asarray(pos, np.int32)
         got = chunked_prefill_attention(
-            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), None, None,
+            jnp.asarray(q), _stored(k), _stored(v), None, None,
             jnp.asarray(table), jnp.asarray(pos), block_size=bs)
         ref = _reference(q, k, v, table, pos, bs, d ** -0.5)
         np.testing.assert_allclose(np.asarray(got), ref, atol=2e-5)
@@ -109,7 +114,7 @@ class TestChunkedPrefillKernel:
                           for _ in range(t)]).astype(np.int32)
         pos = np.asarray([0, 5, 9, 2, 13, 7], np.int32)
         got = chunked_prefill_attention(
-            jnp.asarray(q), kq, vq, ks, vs,
+            jnp.asarray(q), _stored(kq), _stored(vq), ks, vs,
             jnp.asarray(table), jnp.asarray(pos), block_size=bs)
         # int8 reference: dequantize the pools, then exact attention
         kd = np.asarray(kq, np.float32) * np.asarray(ks)[:, :, :, None]
@@ -126,7 +131,7 @@ class TestChunkedPrefillKernel:
         table = np.zeros((2, 2), np.int32)
         pos = np.zeros((2,), np.int32)
         got = np.asarray(chunked_prefill_attention(
-            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), None, None,
+            jnp.asarray(q), _stored(k), _stored(v), None, None,
             jnp.asarray(table), jnp.asarray(pos), block_size=bs))
         assert np.isfinite(got).all()
         np.testing.assert_allclose(got[0, 0], k[0, 0, 0] * 0 + v[0, 0, 0],

@@ -31,6 +31,7 @@ from deepspeed_tpu.models import make_gpt
 from deepspeed_tpu.models.gpt import init_kv_cache
 from deepspeed_tpu.serving import (BlockPool, PagedLayerCache, ServeEngine,
                                    init_paged_pools, pack_prefill)
+from deepspeed_tpu.serving.kv_cache import _quant_tokens
 from deepspeed_tpu.telemetry import (InMemorySink, MetricsRegistry,
                                      RecompileDetector, StepTracer,
                                      Telemetry)
@@ -131,8 +132,10 @@ class TestPagedCache:
         bt[0, :2] = [3, 7]
         lc = PagedLayerCache(*pools[0], jnp.asarray(bt),
                              jnp.asarray([8], jnp.int32), 4, "float32")
-        got_k = np.asarray(lc._gather(lc.k, lc.k_scale))[0, :8]
-        got_v = np.asarray(lc._gather(lc.v, lc.v_scale))[0, :8]
+        got_k = np.asarray(lc._gather(lc.k, lc.k_scale,
+                                      cfg.num_heads))[0, :8]
+        got_v = np.asarray(lc._gather(lc.v, lc.v_scale,
+                                      cfg.num_heads))[0, :8]
         np.testing.assert_array_equal(got_k, np.asarray(k_stack[0]))
         np.testing.assert_array_equal(got_v, np.asarray(v_stack[0]))
 
@@ -148,7 +151,8 @@ class TestPagedCache:
         bt[0, :2] = [3, 7]
         lc = PagedLayerCache(*pools[0], jnp.asarray(bt),
                              jnp.asarray([8], jnp.int32), 4, "float32")
-        got = np.asarray(lc._gather(lc.k, lc.k_scale))[0, :8]
+        got = np.asarray(lc._gather(lc.k, lc.k_scale,
+                                    cfg.num_heads))[0, :8]
         want = np.asarray(k_stack[0])
         bound = np.abs(want).max(axis=-1, keepdims=True) / 127.0 + 1e-7
         assert (np.abs(got - want) <= bound).all()
@@ -170,9 +174,89 @@ class TestPagedCache:
         np.testing.assert_array_equal(kk[1, 6], np.asarray(k_new[1, 0]))
         m = np.asarray(mask)[:, 0, 0]                 # [B, L]
         assert m[0].sum() == 3 and m[1].sum() == 7    # kpos <= pos
-        # row 0's write landed in block 1 offset 2 of the pool
+        # row 0's write landed in block 1 offset 2 of the pool (heads
+        # folded into the stored row)
         np.testing.assert_array_equal(np.asarray(new.k[1, 2]),
-                                      np.asarray(k_new[0, 0]))
+                                      np.asarray(k_new[0, 0]).reshape(-1))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["fp", "int8"])
+class TestPoolForm:
+    """The ONE stored form of the pool (kv_cache.py's module docstring):
+    ``[num_blocks, block_size, heads * head_dim]``, heads folded into the
+    lane axis, written and read as such by every program. What the model
+    sees is unchanged: ``[B, L, H, D]``, element for element the
+    contiguous cache (int8: its per-(token, head) RTNE round trip)."""
+
+    NB, BS = 16, 4
+
+    @staticmethod
+    def _stored_values(x, int8):
+        """What a pool hands back for ``x`` [..., H, D]."""
+        if not int8:
+            return np.asarray(x)
+        q, s = _quant_tokens(x)
+        return np.asarray(q, np.float32) * np.asarray(s)[..., None]
+
+    def test_pool_shapes(self, gpt_setup, int8):
+        _, cfg, _ = gpt_setup
+        pools = init_paged_pools(cfg, self.NB, self.BS, int8=int8,
+                                 dtype=jnp.float32)
+        assert len(pools) == cfg.num_layers
+        for k, v, ks, vs in pools:
+            assert k.shape == v.shape == (
+                self.NB, self.BS, cfg.num_heads * cfg.head_dim)
+            assert k.dtype == v.dtype == (jnp.int8 if int8 else jnp.float32)
+            if int8:
+                assert ks.shape == vs.shape == (self.NB, self.BS,
+                                                cfg.num_heads)
+            else:
+                assert ks is None and vs is None
+
+    def test_write_then_gather_equals_contiguous(self, gpt_setup, int8):
+        """Two rows written token by token through scrambled block tables
+        read back as the contiguous ``[B, L, H, D]`` cache."""
+        _, cfg, _ = gpt_setup
+        rng = np.random.default_rng(5)
+        b, length = 2, 10
+        kv = jnp.asarray(rng.normal(size=(2, b, length, cfg.num_heads,
+                                          cfg.head_dim)), jnp.float32)
+        bt = jnp.asarray([[9, 2, 11, 0], [4, 13, 1, 0]], jnp.int32)
+        pools = init_paged_pools(cfg, self.NB, self.BS, int8=int8,
+                                 dtype=jnp.float32)[0]
+        for t in range(0, length, 2):           # chunks of two tokens
+            lc = PagedLayerCache(*pools, bt, jnp.full((b,), t, jnp.int32),
+                                 self.BS, "float32")
+            lc, kk, vv, _ = lc.update(kv[0][:, t:t + 2], kv[1][:, t:t + 2])
+            pools = lc.pools
+        assert lc.k.shape == (self.NB, self.BS,
+                              cfg.num_heads * cfg.head_dim)
+        assert kk.shape == (b, lc.key_len, cfg.num_heads, cfg.head_dim)
+        np.testing.assert_array_equal(np.asarray(kk)[:, :length],
+                                      self._stored_values(kv[0], int8))
+        np.testing.assert_array_equal(np.asarray(vv)[:, :length],
+                                      self._stored_values(kv[1], int8))
+
+    def test_pack_then_gather_returns_the_stack(self, gpt_setup, int8):
+        _, cfg, _ = gpt_setup
+        rng = np.random.default_rng(6)
+        k_stack, v_stack = (jnp.asarray(rng.normal(
+            size=(cfg.num_layers, 2 * self.BS, cfg.num_heads,
+                  cfg.head_dim)), jnp.float32) for _ in range(2))
+        pools = pack_prefill(
+            init_paged_pools(cfg, self.NB, self.BS, int8=int8,
+                             dtype=jnp.float32),
+            jnp.asarray([7, 3], jnp.int32), k_stack, v_stack)
+        bt = jnp.asarray([[7, 3, 0]], jnp.int32)
+        for i, layer in enumerate(pools):
+            lc = PagedLayerCache(*layer, bt, jnp.asarray([8], jnp.int32),
+                                 self.BS, "float32")
+            for pool, scale, stack in ((lc.k, lc.k_scale, k_stack),
+                                       (lc.v, lc.v_scale, v_stack)):
+                got = np.asarray(lc._gather(pool, scale, cfg.num_heads))
+                np.testing.assert_array_equal(
+                    got[0, :2 * self.BS],
+                    self._stored_values(stack[i], int8))
 
 
 # ---------------------------------------------------------------------------

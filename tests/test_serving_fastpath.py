@@ -110,15 +110,17 @@ class TestPagedKernelParity:
         bt[1, :2] = [5, 1]
         # row 2 stays all-zeros: an inactive slot pointing at scratch
         pos = jnp.asarray([9, 5, 0], jnp.int32)   # 9, 5: partial blocks
+        ks = vs = None
         if int8:
-            kq, ks = _quant_tokens(k)
-            vq, vs = _quant_tokens(v)
-            return kq, vq, ks, vs, jnp.asarray(bt), pos
-        return k, v, None, None, jnp.asarray(bt), pos
+            k, ks = _quant_tokens(k)
+            v, vs = _quant_tokens(v)
+        # the stored form: heads folded into the lane axis
+        k, v = (p.reshape(self.N, self.BS, self.H * self.D) for p in (k, v))
+        return k, v, ks, vs, jnp.asarray(bt), pos
 
     def _reference(self, q, k, v, ks, vs, bt, pos):
         lc = PagedLayerCache(k, v, ks, vs, bt, pos, self.BS, "float32")
-        kk, vv = lc._gather(k, ks), lc._gather(v, vs)
+        kk, vv = lc._gather(k, ks, self.H), lc._gather(v, vs, self.H)
         s = q.shape[1]
         qpos = pos[:, None] + jnp.arange(s)[None, :]
         kpos = jnp.arange(lc.key_len)

@@ -7,9 +7,15 @@ entry of ``ops/kernel_cases.py`` with ``interpret=False`` (~1 s each) —
 the test that would have caught two serving kernels that were never
 compilable. It says nothing about speed, and nothing about numerics on
 the chip (``chip_smoke.py`` does that).
+
+The compiled text also shows what the compiler does to a program's
+ARGUMENTS: the last test holds the serving programs to reading and
+writing the paged KV pool in the layout it arrives in.
 """
 
 import functools
+import math
+import re
 
 import jax
 import numpy as np
@@ -83,3 +89,92 @@ def test_flash_attention_compiles_on_a_four_chip_mesh(axes, spec, v5e_devices,
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3   # fwd, dq, dkv
+
+
+# The benchmark's serving cell (gpt2m-serve-chat): 4097 blocks x 16
+# positions, 16 heads x 64, 64 slots x 64 table columns.
+POOL_BLOCKS, POOL_BLOCK, SLOTS, TABLE = 4097, 16, 64, 64
+# `%name = dtype[dims]{minor_to_major:tiles} opcode(`, anywhere in a module
+HLO_RESULT = re.compile(
+    r"= \w+\[([\d,]+)\]\{([\d,]+)[^}]*\} ([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("program", ["decode", "pack"])
+def test_serving_programs_take_the_pool_as_it_is_stored(program, int8,
+                                                        v5e_sharding):
+    """A pool stored ``[N, BS, H, 64]`` reaches a TPU program block-minor
+    (``{0,3,2,1}``), and every program that scatters into or gathers from
+    it then copies the WHOLE pool to row-major on entry and back on exit
+    (``serving/kv_cache.py``'s module docstring has the why and what it
+    cost on the chip). Stored ``[N, BS, H * D]`` it arrives row-major and
+    is updated in place. So: no ``copy`` of a K/V pool's size anywhere in
+    the compiled module, and every K/V pool parameter row-major. The int8
+    SCALE pools ``[N, BS, H]`` are exempt: they do arrive block-minor and
+    are copied, at 1/32 of the bytes (``init_paged_pools`` says why they
+    stay)."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.gpt import make_gpt
+    from deepspeed_tpu.serving.kv_cache import (PagedLayerCache,
+                                                init_paged_pools,
+                                                pack_prefill)
+
+    # gpt2-medium's width, two of its 24 layers: each layer's pools meet
+    # the same scatter, gather and donation
+    layers = 2
+    model, cfg = make_gpt("gpt2-medium", num_layers=layers, dropout_rate=0.0)
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
+                                           sharding=v5e_sharding), tree)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_sharding)
+
+    pools = on_chip(jax.eval_shape(lambda: init_paged_pools(
+        cfg, POOL_BLOCKS, POOL_BLOCK, int8=int8, dtype=jnp.bfloat16)))
+
+    if program == "decode":         # the body of ServeEngine._decode_impl
+        params = on_chip(jax.eval_shape(
+            lambda rng: model.init(
+                rng, {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"],
+            jax.random.PRNGKey(0)), jnp.bfloat16)
+
+        def decode(params, pools, bt, pos, toks):
+            cache = tuple(PagedLayerCache(*pools[i], bt, pos, POOL_BLOCK,
+                                          "bfloat16") for i in range(layers))
+            out = model.apply(
+                {"params": params},
+                {"input_ids": toks[:, None], "position_ids": pos[:, None]},
+                deterministic=True, cache=cache, pos=None)
+            return out["logits"][:, -1], tuple(c.pools for c in out["cache"])
+
+        lowered = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, pools, ints(SLOTS, TABLE), ints(SLOTS), ints(SLOTS))
+    else:                           # a 256-token prompt bucket
+        stack = jax.ShapeDtypeStruct(
+            (layers, 256, cfg.num_heads, cfg.head_dim), jnp.bfloat16,
+            sharding=v5e_sharding)
+        lowered = jax.jit(pack_prefill, donate_argnums=(0,)).lower(
+            pools, ints(256 // POOL_BLOCK), stack, stack)
+
+    text = lowered.compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    pool_size = POOL_BLOCKS * POOL_BLOCK * cfg.num_heads * cfg.head_dim
+
+    def results(hlo, opcode):
+        """Layouts of ``opcode``'s results of a K/V pool's size."""
+        return [[int(i) for i in layout.split(",")]
+                for dims, layout, op in HLO_RESULT.findall(hlo)
+                if op == opcode
+                and math.prod(map(int, dims.split(","))) == pool_size]
+
+    pool_params = results(entry, "parameter")
+    assert len(pool_params) == 2 * layers            # the regex still reads
+    assert all(layout == sorted(layout, reverse=True)
+               for layout in pool_params), pool_params
+    copies = results(text, "copy")          # fused computations included
+    assert not copies, f"{len(copies)} whole-pool copies: {copies}"
