@@ -9,6 +9,12 @@ weights from a seed):
   micro batch 16 x seq 512 per chip on a fixed seeded batch. On a
   multi-device host the same global batch is also trained under ZeRO-3 and
   on ONE device, and the loss trajectories must agree at bf16 tolerance.
+- dropless trainer: the ``glm4_moe_lite`` block at its tiny preset (latent
+  attention, the dropless expert layer holding 4 of 8 experts, a shared
+  expert, an MTP module) through the same entry points on one device; its
+  first two ``train_batch()`` losses on one batch are held to the
+  benchmark's plain float32 reference and one reference Adam step, by the
+  benchmark's own comparison.
 - server: ``deepspeed_tpu.init_serving()`` with the default serving
   config, a mixed trace of ``submit()``s, ``run_until_complete()`` — twice,
   compared with itself.
@@ -31,6 +37,7 @@ Last line of stdout on success:
 """
 
 import argparse
+import dataclasses
 import functools
 import importlib.metadata
 import json
@@ -43,7 +50,7 @@ import jax
 import numpy as np
 
 import deepspeed_tpu
-from deepspeed_tpu.models import make_gpt
+from deepspeed_tpu.models import make_glm4_moe_lite, make_gpt
 from deepspeed_tpu.ops.kernel_cases import kernel_cases
 from deepspeed_tpu.parallel.mesh import build_mesh
 from deepspeed_tpu.profiling.flops_profiler import TPU_PEAK_TFLOPS
@@ -215,6 +222,53 @@ def trainer_phase(rep, model, cfg, params, rehearsal):
     rep.details["trainer"] = records
 
 
+def dropless_trainer_phase(rep, rehearsal):
+    """Two ``train_batch()`` calls of the tiny ``glm4_moe_lite`` block on
+    one batch, bf16 under ZeRO-2 on one device, against the benchmark's
+    plain reference: the first loss at the seeded weights, the second
+    after one reference Adam step (``drivers/train_steps.compare``)."""
+    from benchmarks.harness import load_module
+    from benchmarks.reference import glm4_moe_lite as reference
+    from benchmarks.reference.optimizers import adam
+
+    lr, gas = 1e-3, 2
+    model, cfg = make_glm4_moe_lite(n_held_experts=4, first_held_expert=2)
+    seq = 32 if rehearsal else 128
+    ids = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (gas, 2, seq), dtype=np.int32)
+    params = jax.device_get(model.init(
+        {"params": jax.random.PRNGKey(0)}, {"input_ids": ids[0]})["params"])
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, params=params,
+        mesh=build_mesh(data=1, devices=jax.devices()[:1]),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "gradient_accumulation_steps": gas,
+                "optimizer": {"type": "Adam", "params": {"lr": lr}},
+                "zero_optimization": {"stage": 2},
+                "bf16": {"enabled": True}})
+    losses = [float(engine.train_batch({"input_ids": ids}))
+              for _ in range(2)]
+
+    kw = reference.settings({
+        **dataclasses.asdict(cfg),
+        "assumed": {"mtp_loss_weight": cfg.mtp_loss_weight}})
+
+    @jax.jit
+    def reference_losses(p):
+        mean = lambda q: sum(reference.loss(q, {"input_ids": micro}, **kw)
+                             for micro in ids) / gas
+        loss_0, grads = jax.value_and_grad(mean)(p)
+        return loss_0, mean(adam.first_step(p, grads, lr=lr))
+
+    loss_0, loss_1 = map(float, reference_losses(params))
+    why_not = load_module("drivers", "train_steps").compare(
+        losses, loss_0, loss_1)
+    rep.check(not why_not, "dropless trainer: two train_batch() losses "
+              f"match the reference and one reference Adam step {why_not}")
+    rep.details["dropless_trainer"] = {
+        "losses": losses, "reference": [loss_0, loss_1]}
+
+
 # ---------------------------------------------------------------------------
 # server
 # ---------------------------------------------------------------------------
@@ -324,6 +378,7 @@ def main(argv=None) -> int:
 
     t_start = time.perf_counter()
     rep.run_phase("trainer", trainer_phase, model, cfg, params, rehearsal)
+    rep.run_phase("dropless trainer", dropless_trainer_phase, rehearsal)
     rep.run_phase("server", server_phase, model, cfg, params, rehearsal)
     rep.run_phase("kernels", kernels_phase, rehearsal)
 

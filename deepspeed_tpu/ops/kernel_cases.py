@@ -239,6 +239,7 @@ def kernel_cases() -> List[KernelCase]:
     return [
         _flash_case(64, jnp.float32), _flash_case(128, jnp.float32),
         _flash_case(64, jnp.bfloat16),       # the trainer's dtype
+        _flash_case(256, jnp.bfloat16),      # latent attention's head size
         _sparse_case(64, masked=False), _sparse_case(128, masked=False),
         _sparse_case(128, masked=True),      # masks need block % 128 == 0
         _ln_matmul_case(None), _ln_matmul_case("gelu"),

@@ -51,7 +51,7 @@ from deepspeed_tpu.runtime.precision import (LossScaleState, PrecisionPolicy,
 from deepspeed_tpu.runtime.utils import (clip_grad_by_global_norm, global_norm,
                                          has_inf_or_nan)
 from deepspeed_tpu.runtime.zero.partition import ZeroPartitioner
-from deepspeed_tpu.telemetry.tracer import device_scope
+from deepspeed_tpu.telemetry.tracer import device_scope, profiler_session_live
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 
@@ -651,6 +651,9 @@ class TPUEngine:
         self._micro_in_window = 0
         self._pending_micro = []
         self._last_loss = None
+        # (the step's span id, device scalars) of the last two fused
+        # steps that returned counters; read by _trace_step_counters.
+        self._step_counters = collections.deque(maxlen=2)
         # The gradient norm the last fused train_batch() returned (None
         # after a forward(): get_global_grad_norm then reads grad_acc).
         self._fused_grad_norm = None
@@ -1226,15 +1229,20 @@ class TPUEngine:
 
             def body(st, batch):
                 st, loss, m_aux = micro_step_inner(st, batch, compute_params)
-                # MoE: thread the model's in-program moe_* stats out of
-                # the scan (trace-time key check — a moe-less model, or
-                # moe_monitor None, stacks nothing and the emitted
-                # program is bit-identical to the pre-moe one).
-                moe = ({k: m_aux[k] for k in moe_keys if k in m_aux}
-                       if moe_keys and isinstance(m_aux, dict) else {})
-                return st, (loss, moe)
+                # The model's per-micro-batch scalars leave the scan as
+                # ONE dict: what it returns under "step_counters", and
+                # its moe_* stats while the moe monitor listens
+                # (trace-time key checks: a model with neither stacks
+                # nothing and the emitted program is bit-identical to
+                # the one before either existed).
+                counters = {}
+                if isinstance(m_aux, dict):
+                    counters = {**m_aux.get("step_counters", {}),
+                                **{k: m_aux[k] for k in moe_keys
+                                   if k in m_aux}}
+                return st, (loss, counters)
 
-            state, (losses, moe_stacked) = jax.lax.scan(body, state, batches)
+            state, (losses, counters) = jax.lax.scan(body, state, batches)
             out = apply_step(state, lr)
             state, overflow, norm = out[0], out[1], out[2]
             step_aux = {}
@@ -1242,9 +1250,9 @@ class TPUEngine:
                 step_aux["groups"] = out[3]
                 if pqerr is not None:
                     step_aux["param_qerr"] = pqerr
-            if moe_stacked:
-                step_aux["moe"] = {k: jnp.mean(v.astype(jnp.float32))
-                                   for k, v in moe_stacked.items()}
+            if counters:
+                step_aux["counters"] = {k: jnp.mean(v.astype(jnp.float32))
+                                        for k, v in counters.items()}
             if step_aux:
                 return state, jnp.mean(losses), overflow, norm, step_aux
             return state, jnp.mean(losses), overflow, norm
@@ -2208,8 +2216,27 @@ class TPUEngine:
                             params=self.state.params)
         with tel.span("train_step", step=step) as sp:
             out = self._train_step(self.state, batches, lr)
+        self._trace_step_counters()
         with tel.span("step_hooks", step=step):
             return self._step_hooks(batches, lr, out, status, sp)
+
+    def _trace_step_counters(self) -> None:
+        """The counters of earlier steps as the stats of a
+        ``ds.step_counters`` span each (``of_step``: the ``step`` of that
+        step's own spans), while a profiler session records: only those
+        the device has already finished (as a rule the step before
+        last), so a traced step waits for nothing an untraced one does
+        not, and an untraced one fetches nothing."""
+        if not self._step_counters or not profiler_session_live():
+            return
+        while self._step_counters and all(
+                v.is_ready() for v in self._step_counters[0][1].values()):
+            of_step, counters = self._step_counters.popleft()
+            with self.telemetry.span(
+                    "step_counters", of_step=of_step,
+                    **{k: float(v)
+                       for k, v in jax.device_get(counters).items()}):
+                pass
 
     def _offload_step_hooks(self, batches, loss, status, sp) -> None:
         """What follows the offloaded step's dispatch (the ``step_hooks``
@@ -2260,14 +2287,19 @@ class TPUEngine:
             # A reference hand-off of the in-program stats aux — the
             # device->host transfer happens at the flush boundary only.
             self.numerics.note_step(
-                {k: v for k, v in step_aux.items() if k != "moe"},
+                {k: v for k, v in step_aux.items() if k != "counters"},
                 self.global_steps)
-        if self.moe_monitor is not None and "moe" in step_aux:
-            # Same reference hand-off for the model's moe_* stats; the
-            # monitor pays its one device_get at the flush boundary.
-            self.moe_monitor.note_step(
-                step_aux["moe"], self.global_steps,
-                gas=self.gradient_accumulation_steps)
+        if "counters" in step_aux:
+            # References only. The moe monitor pays its one device_get
+            # of its moe_* stats at the flush boundary;
+            # _trace_step_counters fetches them all later, and only for
+            # a trace.
+            if self.moe_monitor is not None:
+                self.moe_monitor.note_step(
+                    step_aux["counters"], self.global_steps,
+                    gas=self.gradient_accumulation_steps)
+            self._step_counters.append((self.global_steps - 1,
+                                        step_aux["counters"]))
         self.micro_steps += self.gradient_accumulation_steps
         if self.lr_scheduler is not None:
             self.lr_scheduler.step()

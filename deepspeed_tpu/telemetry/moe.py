@@ -3,7 +3,8 @@
 The model computes the per-step MoE statistics in-program (moe/layer.py
 ``_dispatch_stats`` — load-balance loss, capacity overflow fraction,
 expert utilization, modeled dispatch wire bytes) and the engine's train
-step threads them out through its aux output, exactly the numerics
+step threads them out of the GAS scan with the model's other per-step
+counters (one dict, ``step_aux["counters"]``), exactly the numerics
 observatory's economy: ``note_step`` stores device-array REFERENCES (no
 sync on the step path) and ``flush`` — the telemetry cadence boundary,
 ``steps_per_print`` — pays ONE ``device_get`` for the whole dict.
@@ -71,8 +72,8 @@ class MoEMonitor:
         vals = self._fetch()
         reg = self.telemetry.registry
         for key, v in vals.items():
-            if not key.startswith("moe_"):
-                continue
+            if key not in MOE_AUX_KEYS:
+                continue        # another layer's counters (the trace's)
             if key == "moe_dispatch_bytes_ici":
                 # The model reports per-microstep modeled wire bytes
                 # (averaged over the GAS scan of a constant); the gauge

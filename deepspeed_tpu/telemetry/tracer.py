@@ -61,6 +61,15 @@ DEVICE_SCOPES = {
     "kv_gather": "the pool indexed by the block table, reshape, dequant",
     "kv_write": "the new K and V scattered into the pool",
     "sample": "logits to token ids",
+    "mla": "latent attention: both low-rank paths, their inner norms, RoPE, "
+           "the attention itself, the output projection",
+    "moe_route": "the dropless layer's router: scores, top-k, weights",
+    "moe_dispatch": "assignments sorted by held expert, group sizes, the "
+                    "token rows gathered into the sorted buffer",
+    "moe_experts": "the held experts' grouped matmuls and their SwiGLU",
+    "moe_shared": "the shared expert",
+    "moe_combine": "the buffer's rows back to their tokens, weighted sum",
+    "mtp": "the multi-token-prediction module (its block and head included)",
 }
 
 
@@ -72,6 +81,13 @@ def device_scope(name: str):
         raise KeyError(f"unknown device scope {name!r}; "
                        f"known: {sorted(DEVICE_SCOPES)}")
     return jax.named_scope(PREFIX + name)
+
+
+def profiler_session_live() -> bool:
+    """Whether a ``jax.profiler`` session is recording: exactly when a
+    span's stats reach a trace. What is worth fetching only for a trace
+    (``TPUEngine._trace_step_counters``) asks this first."""
+    return jax.profiler.TraceAnnotation.is_enabled()
 
 
 def _device_sync() -> None:

@@ -601,6 +601,35 @@ class TestMoEOffContract:
                 engine.state, batches, jnp.float32(1e-3)).as_text()
         assert texts["off"] != texts["on"]
 
+    @pytest.mark.parametrize("listening", [False, True])
+    def test_the_moe_stats_take_the_one_counters_path(
+            self, eight_devices, tmp_path, listening):
+        """One dict leaves the GAS scan (``step_aux["counters"]``): the
+        model's moe_* stats ride it while the monitor listens, and both
+        the monitor and the trace's hand-off get the same references;
+        with no monitor nothing is stacked and nothing is kept."""
+        from deepspeed_tpu.telemetry.moe import MOE_AUX_KEYS
+        mesh = build_mesh(data=4, expert=2)
+        config = {"train_micro_batch_size_per_gpu": 2,
+                  "gradient_accumulation_steps": 1,
+                  "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+                  "zero_optimization": {"stage": 1}}
+        if listening:
+            config.update(
+                moe={"enabled": True, "num_experts": 4, "k": 1,
+                     "dispatch": "scatter"},
+                telemetry={"enabled": True, "dir": str(tmp_path)})
+        engine, batches = _moe_gpt_engine(mesh, config)
+        engine.train_batch(batches)
+        if not listening:
+            assert engine.moe_monitor is None
+            assert not engine._step_counters
+            return
+        (step, counters), = engine._step_counters
+        assert step == 0 and set(counters) == set(MOE_AUX_KEYS)
+        assert engine.moe_monitor._pending == counters
+        assert engine.moe_monitor.last_step == 1
+
 
 class TestProbeMoECLI:
     @pytest.mark.parametrize("probe", ["probe_moe.py"])

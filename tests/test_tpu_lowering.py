@@ -178,3 +178,39 @@ def test_serving_programs_take_the_pool_as_it_is_stored(program, int8,
                for layout in pool_params), pool_params
     copies = results(text, "copy")          # fused computations included
     assert not copies, f"{len(copies)} whole-pool copies: {copies}"
+
+
+def test_the_dropless_expert_layer_at_the_cells_size(v5e_sharding):
+    """``glm47flash-train-s4096``'s expert layer, forward and backward, at
+    its real size (4096 tokens, 8 of 64 experts held, 4 a token): the
+    held experts' three matmuls and their six transposes each reach the
+    chip as XLA's grouped-matmul kernel (named ``ragged-dot-*``, which
+    ``benchmarks/moe_trace.py`` reads by), not as a dense product per
+    expert over the whole buffer; and no row travels by a scatter: the
+    only scatters are the inverse permutation (int32) and the router's
+    ``take_along_axis`` transpose (64 scores a token)."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe.dropless import DroplessMoE, DroplessMoEConfig
+
+    layer = DroplessMoE(DroplessMoEConfig(
+        hidden_size=2048, expert_intermediate=1536, n_routed_experts=64,
+        n_held_experts=8, experts_per_token=4, shared_intermediate=1536,
+        routed_scaling_factor=1.8))
+    on_chip = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                                 sharding=v5e_sharding)
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape), jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048)))["params"]))
+
+    def loss(p, x):
+        y, counters = layer.apply({"params": p}, x)
+        return y.astype(jnp.float32).sum(), counters
+
+    hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+                  ).lower(params, on_chip((1, 4096, 2048))).compile(
+                  ).as_text()
+    calls = re.findall(r"%(ragged-dot-[a-z]+)[.\d]* = ", hlo)
+    assert calls.count("ragged-dot-none") == 9, sorted(set(calls))
+    scattered = re.findall(r"= (\w+)\[([\d,]*)\][^=]* scatter\(", hlo)
+    assert sorted(scattered) == [("f32", "262144"), ("s32", "16384")], \
+        scattered
