@@ -1000,9 +1000,10 @@ class ServingConfig:
     (0.0 = greedy, byte-reproducible).
 
     Decode fast path (docs/SERVING.md "Decode fast path" — all three
-    off by default, PR-8 bit-identical): ``decode_attention``
-    gather|auto|kernel selects the Pallas paged decode-attention kernel
-    (with the max-active-length-capped gather as its fallback);
+    off by default): ``decode_attention`` gather|auto|kernel: "gather"
+    decodes over the flat list of the batch's live blocks, "auto" and
+    "kernel" select the Pallas paged decode-attention kernel (with the
+    max-active-length-capped gather as "auto"'s fallback);
     ``prefix_cache`` turns on COW prompt-head block reuse;
     ``speculative`` configures draft-model speculative decoding
     (greedy-identical by construction — requires ``temperature == 0``).

@@ -299,8 +299,8 @@ SERVING_TOP_K = "top_k"
 SERVING_TOP_K_DEFAULT = 0
 SERVING_SEED = "seed"
 SERVING_SEED_DEFAULT = 0
-# decode fast path (docs/SERVING.md "Decode fast path"): "gather" keeps
-# the PR-8 full-window gather program bit-identical; "auto" runs the
+# decode fast path (docs/SERVING.md "Decode fast path"): "gather" is
+# ONE decode program over the flat list of live blocks; "auto" runs the
 # Pallas paged decode-attention kernel where the geometry tiles and the
 # max-active-length-capped gather elsewhere; "kernel" forces the kernel
 # (Pallas interpreter off-TPU — the parity/bench path).

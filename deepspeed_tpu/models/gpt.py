@@ -237,16 +237,16 @@ class GPTBlock(nn.Module):
                 o = attention(q, ck, cv, causal=False, mask=dec_mask,
                               deterministic=True, impl="xla",
                               softmax_scale=cfg.attention_scale)
+            elif (getattr(kv_cache, "live", None) is not None
+                    and attn_mask is None):
+                # Serving's default decode: over the live blocks' list.
+                kv_cache, o = kv_cache.attend_live(
+                    q, k, v, softmax_scale=cfg.attention_scale)
             elif (getattr(kv_cache, "attn_impl", "gather")
                     in ("kernel", "chunked") and attn_mask is None):
                 # Paged decode fast path: the Pallas kernel streams K/V
-                # blocks from the pool through the block table (int8
-                # pools dequantized in-kernel) — the gathered [B, L, H,
-                # D] copy is never materialized. Same visibility
-                # semantics as the gather branch below (parity-tested).
-                # "chunked" is the ragged mixed-batch form: one flat
-                # token batch with per-token tables and positions
-                # (ChunkedLayerCache; ops/transformer/chunked_prefill.py).
+                # blocks through the block table, no gathered copy made
+                # ("chunked": the ragged mixed batch). Visibility as below.
                 kv_cache, o = kv_cache.update_attend(
                     q, k, v, softmax_scale=cfg.attention_scale)
             else:

@@ -17,8 +17,10 @@ in steady state**:
   dispatches per step boundary, never a retrace of the decode program.
 - ``decode_step`` (ONE program, ever): the whole slot batch advances one
   token through the paged cache — fixed batch width, fixed block-table
-  shape, per-row positions. Sequences join/leave by editing host-side
-  numpy inputs, which XLA never sees as a new signature.
+  shape, per-row positions, and a fixed-length list of the batch's live
+  KV blocks of which the program walks only the chunks that hold
+  anything (a traced trip count). Sequences join/leave by editing
+  host-side numpy inputs, which XLA never sees as a new signature.
 - scheduling between steps is pure host python (microseconds).
 
 SLO telemetry rides the established contract: metrics through the
@@ -40,7 +42,8 @@ from deepspeed_tpu.inference.engine import (InferenceEngine, bucket_length,
                                             sample_logits)
 from deepspeed_tpu.serving.kv_cache import (BlockPool, ChunkedLayerCache,
                                             PagedLayerCache,
-                                            init_paged_pools, pack_prefill)
+                                            init_paged_pools,
+                                            live_block_list, pack_prefill)
 from deepspeed_tpu.serving.scheduler import (PrefixCache, Scheduler,
                                              Sequence)
 from deepspeed_tpu.telemetry.tracer import device_scope
@@ -99,6 +102,15 @@ class ServeEngine:
     loop for a dedicated serving process.
     """
 
+    # The live-block list of the default decode (kv_cache.live_block_list,
+    # PagedLayerCache.attend_live): a row's blocks are listed in runs of
+    # LIVE_RUN_BLOCKS, the last one padded (larger: more padding read at
+    # the end of every row); the program takes LIVE_CHUNK_RUNS runs a loop
+    # iteration (larger: fewer iterations, a longer fold in each). PERF.md
+    # section 6 (PR 27) has what the chip said of 4 x 8 to 16 x 4.
+    LIVE_RUN_BLOCKS = 16
+    LIVE_CHUNK_RUNS = 2
+
     def __init__(self, engine: InferenceEngine, config=None,
                  telemetry=None, capture_logits: bool = False,
                  measure_kv_quant_error: bool = False,
@@ -146,10 +158,11 @@ class ServeEngine:
 
         self._prefill_jit: Dict[int, Any] = {}
         # -- decode fast path (docs/SERVING.md "Decode fast path") ------
-        # "gather" (default) keeps the PR-8 program byte-for-byte: one
-        # decode program over the FULL table window, no window slicing,
-        # no kernel. "auto"/"kernel" turn on window capping (the decode
-        # key axis covers only the max active length, ceiled to a
+        # "gather" (default): ONE decode program that attends over the
+        # flat list of the batch's live blocks (_dispatch_live); its
+        # speculative verify chunk, several queries a row, gathers the
+        # full table window. "auto"/"kernel" turn on window capping (the
+        # decode key axis covers only the max active length, ceiled to a
         # power-of-two block count — O(log max_blocks) compiled variants
         # instead of one) and, where the geometry tiles (or always,
         # under "kernel" — the Pallas interpreter covers CPU), the paged
@@ -178,7 +191,16 @@ class ServeEngine:
         log_dist(f"serving: decode_attention={mode!r} resolved to "
                  f"{self._attn_impl!r} ({geometry}, platform "
                  f"{jax.devices()[0].platform})", ranks=[0])
-        self._decode_jits: Dict[Any, Any] = {}    # window bucket -> jit
+        # None -> the default decode; a window bucket -> its capped program
+        self._decode_jits: Dict[Any, Any] = {}
+        # Length of the live-block list, in chunks: every slot at the
+        # table's width, in whole runs (a block shared through the prefix
+        # cache is listed once per row that reads it, so the pool's block
+        # count is no bound). Fixed, so the decode program has one
+        # signature.
+        runs = (self.scfg.max_batch_size
+                * -(-self.max_blocks // self.LIVE_RUN_BLOCKS))
+        self._live_chunks = -(-runs // self.LIVE_CHUNK_RUNS)
         self._tail_prefill_jit: Dict[int, Any] = {}
         # -- speculative decoding ---------------------------------------
         self._spec_k = 0
@@ -269,15 +291,19 @@ class ServeEngine:
         # program touched per row (window width x steps) — the modeled
         # HBM-traffic evidence behind the capped fallback
         # (tools/probe_serving_fastpath.py); ``full_positions`` is the
-        # uncapped counterfactual. ``read_positions``: what the decode
-        # programs read whatever is live (table rows x columns x block
-        # size, every dispatch); ``live_positions``: of those, the
-        # positions of active rows that hold KV. Their ratio is the
-        # useful share of the KV read.
+        # uncapped counterfactual; both count the windowed dispatches
+        # only. ``read_positions``: what the decode programs read (the
+        # default decode: chunks walked x chunk x block size; a windowed
+        # program: table rows x columns x block size whatever is live);
+        # ``live_positions``: of those, the positions of active rows that
+        # hold KV. Their ratio is the useful share of the KV read.
+        # ``live_blocks``/``chunks``: entries of the default decode's
+        # live lists and the chunks it walked.
         self.stats = {"decode_steps": 0, "occupancy_sum": 0.0,
                       "slot_assignments": {}, "kernel_steps": 0,
                       "gathered_positions": 0, "full_positions": 0,
                       "live_positions": 0, "read_positions": 0,
+                      "live_blocks": 0, "chunks": 0,
                       "spec_rounds": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "spec_new_tokens": 0}
         log_dist(
@@ -558,6 +584,12 @@ class ServeEngine:
         b = -(-b // self.block_size) * self.block_size   # whole blocks
         return min(max(b, -(-t // self.block_size) * self.block_size),
                    self.bucket_cap)
+
+    @property
+    def live_chunk_positions(self) -> int:
+        """Key positions the default decode reads a loop iteration."""
+        return (self.LIVE_CHUNK_RUNS * self.LIVE_RUN_BLOCKS
+                * self.block_size)
 
     @property
     def mean_occupancy(self) -> float:
@@ -933,7 +965,8 @@ class ServeEngine:
 
     def _dispatch_batch(self, active: List[Sequence], chunk: int,
                         scope: str):
-        """Shared decode/spec dispatch prep: batch matrices, window
+        """Dispatch prep shared by the windowed programs (the fast
+        path's decode, every speculative round): batch matrices, window
         slicing under the fast path, the detector scope (per window
         bucket when capped), the jit-cache key, the resolved attention
         impl, and the gathered-positions evidence — ONE accounting for
@@ -959,18 +992,45 @@ class ServeEngine:
         self.engine.recompile_detector.check(name, toks, pos, bt)
         return bt, pos, toks, key, impl, ids
 
-    def _count_positions(self, active: int, live: int,
-                         read: int) -> Dict[str, int]:
+    def _dispatch_live(self, active: List[Sequence]):
+        """Dispatch prep of the default decode: the batch matrices and,
+        beside them, the flat list of the batch's live blocks
+        (kv_cache.live_block_list) that the program attends over. One
+        detector scope and one signature whatever is live."""
+        self._fault_hook()
+        bt, pos, toks = self._batch_inputs(active)
+        live, n_live, n_chunks = live_block_list(
+            ((s.slot, s.block_table, s.pos) for s in active),
+            self.block_size, self.LIVE_RUN_BLOCKS, self.LIVE_CHUNK_RUNS,
+            self._live_chunks)
+        # once this dispatch has written, row r holds pos[r] + 1
+        ids = self._count_positions(
+            len(active), live=int(pos.sum()) + len(active),
+            read=n_chunks * self.live_chunk_positions,
+            live_blocks=n_live, chunks=n_chunks)
+        args = tuple(jnp.asarray(a) for a in (
+            bt, pos, toks, live, np.int32(n_chunks)))
+        self.engine.recompile_detector.check("serving.decode_step", *args)
+        return args, ids
+
+    def _count_positions(self, active: int, live: int, read: int,
+                         **more: int) -> Dict[str, int]:
         """Add one decode dispatch to the running totals of positions read
-        and of those that are live, and return what its span carries."""
-        self.stats["live_positions"] += live
-        self.stats["read_positions"] += read
-        return {"step": self._step_count, "active": active,
-                "live_positions": live, "read_positions": read}
+        and of those that are live (and of whatever else it counts), and
+        return what its span carries."""
+        counts = dict(live_positions=live, read_positions=read, **more)
+        for name, n in counts.items():
+            self.stats[name] += n
+        return {"step": self._step_count, "active": active, **counts}
 
     def _decode(self, active: List[Sequence]):
-        bt, pos, toks, key, impl, ids = self._dispatch_batch(
-            active, 1, "serving.decode_step")
+        if self._fast_path:
+            *args, key, impl, ids = self._dispatch_batch(
+                active, 1, "serving.decode_step")
+        else:
+            args, ids = self._dispatch_live(active)
+            key, impl = None, "gather"
+        bt, pos, toks, *live = args
         rng = jax.random.fold_in(self._base_key, 2 * self._step_count)
         if key not in self._decode_jits:
             self._decode_jits[key] = jax.jit(
@@ -978,17 +1038,18 @@ class ServeEngine:
                 donate_argnums=(1,))
         with self.telemetry.span("decode_step", **ids):
             tok_dev, logits, self._pools = self._decode_jits[key](
-                self.engine.params, self._pools, bt, pos, toks, rng)
+                self.engine.params, self._pools, bt, pos, toks, rng, *live)
             tok_host = np.asarray(tok_dev)       # host fetch: finish checks
         logits_host = np.asarray(logits) if self.capture_logits else None
         return [int(tok_host[s.slot]) for s in active], logits_host
 
     @device_scope("decode")
-    def _decode_impl(self, params, pools, bt, pos, toks, rng, *,
-                     attn_impl: str = "gather"):
+    def _decode_impl(self, params, pools, bt, pos, toks, rng, live=None,
+                     n_chunks=None, *, attn_impl: str = "gather"):
         cache = tuple(
             PagedLayerCache(*pools[i], bt, pos, self.block_size,
-                            self._dtype_name, attn_impl)
+                            self._dtype_name, attn_impl, live=live,
+                            n_chunks=n_chunks)
             for i in range(self.model_cfg.num_layers))
         out = self.module.apply(
             {"params": self.engine._materialized(params)},
@@ -1327,7 +1388,7 @@ class ServeEngine:
         if pre > ctr.total:
             ctr.inc(pre - ctr.total, step=step)
         # -- fast-path attribution (only when the piece is on: the tag
-        # set a disabled engine emits is byte-identical to PR 8's) ------
+        # set a disabled engine emits stays what it was) ----------------
         if self._fast_path and n_active:
             reg.gauge("serving/decode_attn_kernel").set(
                 1.0 if self._attn_impl == "kernel" else 0.0, step=step)

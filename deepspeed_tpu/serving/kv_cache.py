@@ -39,14 +39,27 @@ Layout (per transformer layer, all layers share one block table):
   discarded) decode writes land somewhere harmless and the program needs
   no branch on slot liveness.
 
+- live list (the default decode, :meth:`PagedLayerCache.attend_live`):
+  ``[chunks, G, R + 2]`` int32 built on the host each step by
+  :func:`live_block_list` — the blocks every active row reads, a row
+  after a row, in runs of R blocks of ONE row (the last run of a row
+  padded), and with each run the slot that owns it and the position of
+  its first key; G runs make a chunk — plus the number of chunks that
+  hold anything. The decode program walks that many chunks and no more,
+  so what it reads follows what is live, not the ``slots x max_blocks``
+  the table reserves; the trip count is data, so it is still ONE
+  compiled program.
+
 Host-side block accounting (:class:`BlockPool`) is plain python — a free
 list is microseconds per step and never touches the device.
 """
 
-from typing import Dict, List, Optional, Tuple
+import functools
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deepspeed_tpu.comm.quantize import quantize_blockwise
 from deepspeed_tpu.telemetry.tracer import device_scope
@@ -136,6 +149,51 @@ class BlockPool:
                 self._free_set.add(b)
 
 
+def live_block_list(rows: Iterable[Tuple[int, List[int], int]],
+                    block_size: int, run: int, group: int,
+                    chunks: int) -> Tuple[np.ndarray, int, int]:
+    """The batch's live KV blocks as one flat list, for
+    :meth:`PagedLayerCache.attend_live`.
+
+    ``rows``: ``(slot, block_table, pos)`` of each active sequence, ``pos``
+    being the position this step writes. Every block of a row up to and
+    including the one that holds ``pos`` is listed, a row after a row, in
+    RUNS of ``run`` blocks; a row's last run is padded with the scratch
+    block, so a run holds blocks of ONE row. ``group`` runs, whoever's,
+    make a chunk, which is what the program takes a loop iteration.
+
+    Returns ``(live, n_blocks, n_chunks)``: ``live`` is ``[chunks, group,
+    run + 2]`` int32, a run a line: its pool blocks, then the slot that
+    owns it (-1: nobody's, padding), then the position of its first key
+    in that row; ``n_blocks`` the blocks listed and ``n_chunks`` the
+    leading chunks that hold any. Pad blocks inside a run need no mark:
+    their keys lie past ``pos``.
+
+    ``chunks`` is fixed per engine (every slot at the table's width), so
+    the program that takes the list has one signature."""
+    live = np.zeros((chunks * group, run + 2), np.int32)
+    live[:, run] = -1
+    n_blocks = n_runs = 0
+    for slot, table, pos in rows:
+        nb = pos // block_size + 1
+        nr = -(-nb // run)
+        if len(table) < nb or n_runs + nr > len(live):
+            raise ValueError(
+                f"slot {slot} writes position {pos} with {len(table)} "
+                f"blocks of {block_size}; the list holds {len(live)} runs "
+                f"of {run}")
+        blocks = np.zeros((nr * run,), np.int32)
+        blocks[:nb] = table[:nb]
+        mine = live[n_runs:n_runs + nr]
+        mine[:, :run] = blocks.reshape(nr, run)
+        mine[:, run] = slot
+        mine[:, run + 1] = np.arange(nr) * run * block_size
+        n_blocks += nb
+        n_runs += nr
+    return (live.reshape(chunks, group, run + 2), n_blocks,
+            -(-n_runs // group))
+
+
 def init_paged_pools(cfg, num_blocks: int, block_size: int,
                      int8: bool = False, dtype=None) -> Tuple:
     """Per-layer ``(k, v, k_scale, v_scale)`` pool arrays (scales are None
@@ -187,7 +245,9 @@ class PagedLayerCache:
                  k_scale: Optional[jax.Array], v_scale: Optional[jax.Array],
                  block_table: jax.Array, pos: jax.Array,
                  block_size: int, dtype_name: str = "bfloat16",
-                 attn_impl: str = "gather", clamp_writes: bool = False):
+                 attn_impl: str = "gather", clamp_writes: bool = False,
+                 live: Optional[jax.Array] = None,
+                 n_chunks: Optional[jax.Array] = None):
         self.k = k
         self.v = v
         self.k_scale = k_scale
@@ -197,8 +257,8 @@ class PagedLayerCache:
         self.block_size = int(block_size)
         self.dtype_name = dtype_name
         # Static (aux) knobs of the serving fast path (docs/SERVING.md):
-        # ``attn_impl`` — "gather" (the materializing fallback, and the
-        # bit-identical-to-PR-8 default) or "kernel" (the Pallas paged
+        # ``attn_impl`` — "gather" (the materializing path: the table
+        # window through :meth:`update`) or "kernel" (the Pallas paged
         # decode-attention kernel; the model's paged branch reads it).
         # ``clamp_writes`` — route out-of-window writes to the scratch
         # block instead of relying on in-bounds positions; the
@@ -208,18 +268,29 @@ class PagedLayerCache:
         # decode path never overshoots and must not pay the extra ops.
         self.attn_impl = str(attn_impl)
         self.clamp_writes = bool(clamp_writes)
+        # The batch's live blocks (:func:`live_block_list`) and how many
+        # chunks of them hold anything: given, the model's paged branch
+        # takes :meth:`attend_live` and reads nothing else of the pool.
+        self.live = live                    # [chunks, G, R + 2] int32 or None
+        self.n_chunks = n_chunks            # [] int32
 
     # -- pytree ---------------------------------------------------------
     def tree_flatten(self):
         return ((self.k, self.v, self.k_scale, self.v_scale,
-                 self.block_table, self.pos),
+                 self.block_table, self.pos, self.live, self.n_chunks),
                 (self.block_size, self.dtype_name, self.attn_impl,
                  self.clamp_writes))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, block_size=aux[0], dtype_name=aux[1],
-                   attn_impl=aux[2], clamp_writes=aux[3])
+        return cls(*children[:6], *aux, *children[6:])
+
+    def _written(self, k, v, ks, vs) -> "PagedLayerCache":
+        """This view over the pools a write returned."""
+        return PagedLayerCache(k, v, ks, vs, self.block_table, self.pos,
+                               self.block_size, self.dtype_name,
+                               self.attn_impl, self.clamp_writes,
+                               self.live, self.n_chunks)
 
     # -- properties -----------------------------------------------------
     @property
@@ -286,9 +357,7 @@ class PagedLayerCache:
         b, s = k_new.shape[:2]
         k, ks = self._write(self.k, self.k_scale, k_new)
         v, vs = self._write(self.v, self.v_scale, v_new)
-        new = PagedLayerCache(k, v, ks, vs, self.block_table, self.pos,
-                              self.block_size, self.dtype_name,
-                              self.attn_impl, self.clamp_writes)
+        new = self._written(k, v, ks, vs)
         heads = k_new.shape[2]
         kk = new._gather(k, ks, heads)
         vv = new._gather(v, vs, heads)
@@ -312,13 +381,108 @@ class PagedLayerCache:
 
         k, ks = self._write(self.k, self.k_scale, k_new)
         v, vs = self._write(self.v, self.v_scale, v_new)
-        new = PagedLayerCache(k, v, ks, vs, self.block_table, self.pos,
-                              self.block_size, self.dtype_name,
-                              self.attn_impl, self.clamp_writes)
+        new = self._written(k, v, ks, vs)
         o = paged_decode_attention(q, k, v, ks, vs, self.block_table,
                                    self.pos, block_size=self.block_size,
                                    softmax_scale=softmax_scale)
         return new, o.astype(q.dtype)
+
+    def attend_live(self, q: jax.Array, k_new: jax.Array, v_new: jax.Array,
+                    softmax_scale: Optional[float] = None):
+        """The default decode (one query a row): write this step's key
+        and value as :meth:`update` does, then attend over ``self.live``,
+        the flat list of the batch's live blocks, ``n_chunks`` chunks of
+        it and no further. Returns ``(new_cache, o [B, 1, H, D])``.
+
+        A chunk is G runs of R blocks, each run ONE row's
+        (:func:`live_block_list`). Gather the chunk's blocks from the K
+        and the V pool, score each run against ITS row's query, mask
+        ``kpos <= pos[row]``, and take each run's partial softmax (max,
+        sum, weighted values) by itself; then fold the runs, one after
+        another, into their rows' running accumulators, the split-K
+        combine of flash decoding. Visibility is exactly :meth:`update`'s;
+        only the order of summation differs. That order is the row's own:
+        a run's partial depends on nothing but the run, and a row's runs
+        are folded in their order whichever chunk they fall in, so a row's
+        output is the same bits in any slot and among any neighbours. Rows
+        with no live block come out zero.
+
+        Everything stays in the pool's stored form, heads folded into the
+        lane axis: a head's 64 lanes are summed by a ``[H * D, H]``
+        indicator matmul and its probabilities spread back over them by
+        the transpose, so no ``[.., H, D]`` reshape of keys or values is
+        made. int8 pools dequantize by their per-(token, head) scales,
+        which factor out of both products."""
+        k, ks = self._write(self.k, self.k_scale, k_new)
+        v, vs = self._write(self.v, self.v_scale, v_new)
+        new = self._written(k, v, ks, vs)
+        b, _, h, d = q.shape
+        g, r = self.live.shape[1], self.live.shape[2] - 2
+        t = r * self.block_size                  # key positions of a run
+        dt = jnp.dtype(self.dtype_name)
+        scale = softmax_scale if softmax_scale is not None else d ** -0.5
+        # float32 operands of a TPU matmul are rounded to bfloat16 unless
+        # told otherwise; the one-hot and indicator factors are exact in
+        # any precision, the other side must not be rounded.
+        exact = functools.partial(jnp.einsum,
+                                  precision=jax.lax.Precision.HIGHEST,
+                                  preferred_element_type=jnp.float32)
+        head_of_lane = (jnp.arange(h * d)[:, None] // d
+                        == jnp.arange(h)[None, :])               # [HD, H]
+        sum_lanes, spread_lanes = (head_of_lane.astype(jnp.float32),
+                                   head_of_lane.astype(dt))
+        qf = q.reshape(b, h * d)
+        posf = self.pos.astype(jnp.float32)
+        low = jnp.finfo(jnp.float32).min
+
+        def lanes(x):                            # [B, H] -> [B, HD]
+            return jnp.repeat(x, d, axis=1)
+
+        def chunk(i, carry):
+            runs = self.live[i]                                  # [G, R + 2]
+            blocks, slots, start = runs[:, :r], runs[:, r], runs[:, r + 1]
+            with device_scope("kv_gather"):
+                kb, vb = (pool[blocks.reshape(-1)].reshape(g, t, h * d)
+                          for pool in (k, v))                # [G, T, HD]
+                if ks is not None:
+                    ksb, vsb = (pool[blocks.reshape(-1)].reshape(g, t, h)
+                                for pool in (ks, vs))        # [G, T, H]
+            owner = slots[:, None] == jnp.arange(b)[None, :]     # [G, B]
+            own = owner.astype(jnp.float32)
+            qr = exact("gb,bk->gk", owner.astype(qf.dtype), qf)  # [G, HD]
+            s = exact("gtk,kh->gth", kb.astype(jnp.float32) * qr[:, None],
+                      sum_lanes) * scale                     # [G, T, H]
+            if ks is not None:
+                s = s * ksb
+            seen = (start[:, None] + jnp.arange(t)[None, :]
+                    <= exact("gb,b->g", own, posf)[:, None])[..., None]
+            # a run's partial, by itself: a real run's first key is seen
+            m_run = jnp.where(seen, s, low).max(axis=1)          # [G, H]
+            p = jnp.where(seen, jnp.exp(s - m_run[:, None]), 0.0)
+            l_run = p.sum(axis=1)                                # [G, H]
+            pv = p * vsb if vs is not None else p
+            spread = exact("gth,kh->gtk", pv.astype(dt), spread_lanes)
+            acc_run = (spread * vb.astype(jnp.float32)).sum(axis=1)
+            # fold the runs in their order, each into its own row
+            m, l, acc = carry                    # [B, H], [B, H], [B, HD]
+            for j in range(g):
+                mine = owner[j][:, None]                         # [B, 1]
+                m_new = jnp.maximum(m, m_run[j])
+                keep, take = jnp.exp(m - m_new), jnp.exp(m_run[j] - m_new)
+                l = jnp.where(mine, l * keep + take * l_run[j], l)
+                acc = jnp.where(mine, acc * lanes(keep)
+                                + lanes(take) * acc_run[j], acc)
+                m = jnp.where(mine, m_new, m)
+            return m, l, acc
+
+        _, l, acc = jax.lax.fori_loop(
+            0, self.n_chunks, chunk,
+            (jnp.full((b, h), low, jnp.float32),
+             jnp.zeros((b, h), jnp.float32),
+             jnp.zeros((b, h * d), jnp.float32)))
+        l = lanes(l)
+        o = jnp.where(l > 0, acc / jnp.where(l > 0, l, 1.0), 0.0)
+        return new, o.reshape(b, 1, h, d).astype(q.dtype)
 
 
 @jax.tree_util.register_pytree_node_class

@@ -58,7 +58,7 @@ DEVICE_SCOPES = {
     "prefill": "the serving prefill program",
     "pack": "a prefilled cache scattered into pool blocks",
     "decode": "the serving decode program (mixed and speculative alike)",
-    "kv_gather": "the pool indexed by the block table, reshape, dequant",
+    "kv_gather": "the pool indexed by live blocks or the table, dequant",
     "kv_write": "the new K and V scattered into the pool",
     "sample": "logits to token ids",
     "mla": "latent attention: both low-rank paths, their inner norms, RoPE, "

@@ -854,7 +854,11 @@ _HANG_SCRIPT = textwrap.dedent("""
             "guardrails": {"enabled": True,
                            "rollback": {"enabled": False},
                            "watchdog": {"enabled": True,
-                                        "step_timeout_seconds": 1.0,
+                                        # far under the 120 s hang, and
+                                        # over a step with its checkpoint
+                                        # write on a host busy with five
+                                        # other test workers (1 s was not)
+                                        "step_timeout_seconds": 4.0,
                                         "poll_interval_seconds": 0.05,
                                         "crashdump_dir": dump_dir}},
         },

@@ -333,9 +333,15 @@ def test_serve_spans_nest_and_carry_their_identifiers(serve_capture):
         assert parent_name(trace, s) == "serve_step"
         assert s.stats["step"] == trace.spans[s.parent].stats["step"]
         assert 0 < s.stats["active"] <= 4
-        assert 0 < s.stats["live_positions"] < s.stats["read_positions"]
-        # 4 slots x 12 blocks x 4 positions, whatever is live
-        assert s.stats["read_positions"] == 4 * 12 * 4
+        assert 0 < s.stats["live_positions"] <= s.stats["read_positions"]
+        # what the program reads follows what is live: whole chunks of
+        # the list of live blocks, 4 positions a block; and of a live
+        # block at most its last 3 are not live
+        assert s.stats["read_positions"] == (
+            s.stats["chunks"] * srv.live_chunk_positions) > 0
+        assert (s.stats["live_positions"]
+                > 4 * (s.stats["live_blocks"] - s.stats["active"]))
+        assert 4 * s.stats["live_blocks"] <= s.stats["read_positions"]
     assert {parent_name(trace, s) for s in named(trace, "admit")} == \
         {"serve_step"}
     submits = named(trace, "submit")
@@ -370,8 +376,9 @@ def test_the_useful_share_of_the_kv_read_is_the_ratio_of_the_totals(
     got = load_module("layer_metrics", "serve.kv_read_useful_share").read(
         run, {}, None)
     assert got == 100.0 * live / read
-    # the other two factors are what the engine counted all along
-    assert srv.stats["gathered_positions"] * 4 == srv.stats["read_positions"]
+    # and what was read is the chunks the decode programs walked
+    assert srv.stats["chunks"] * srv.live_chunk_positions == \
+        srv.stats["read_positions"]
     # on a CPU there is no device plane: the device readers say nothing,
     # the span readers read the host plane
     for name in ("serve.kv_gather_share", "serve.prefill_device_share"):
@@ -390,7 +397,10 @@ def test_the_serving_programs_name_their_parts(gpt_setup):
     text = decode.lower(
         srv.engine.params, srv._pools, jnp.zeros((nb, mb), jnp.int32),
         jnp.zeros((nb,), jnp.int32), jnp.zeros((nb,), jnp.int32),
-        jax.random.PRNGKey(0)).compile().as_text()
+        jax.random.PRNGKey(0),
+        jnp.zeros((srv._live_chunks, srv.LIVE_CHUNK_RUNS,
+                   srv.LIVE_RUN_BLOCKS + 2), jnp.int32),
+        jnp.int32(1)).compile().as_text()
     for scope in ("decode", "kv_gather", "kv_write", "sample"):
         assert PREFIX + scope in text, scope
     assert f"{PREFIX}decode/" in text and f"/{PREFIX}kv_gather/" in text

@@ -18,12 +18,13 @@ The acceptance gates:
   cache off);
 - speculative decode is token-identical to greedy by construction and
   emits its accept-rate evidence;
-- fast path fully off ⇒ the decode program's lowering is bit-identical
-  to the pre-fast-path (PR 8) program, reconstructed here from the same
-  public pieces (jaxpr pin), and no fast-path tags are emitted.
+- fast path fully off ⇒ the decode program attends over the flat list
+  of the batch's live blocks (``PagedLayerCache.attend_live``): equal to
+  ``update()`` + dense masked attention on raw pools, token-identical to
+  ``generate()`` through the engine, ONE compiled program whose lowering
+  holds no tensor over the reserved window, and no fast-path tags.
 """
 
-import functools
 import os
 import subprocess
 import sys
@@ -40,7 +41,8 @@ from deepspeed_tpu.ops.transformer.attention import xla_attention
 from deepspeed_tpu.ops.transformer.paged_attention import (
     paged_decode_attention, paged_decode_ok)
 from deepspeed_tpu.serving import PagedLayerCache, ServeEngine
-from deepspeed_tpu.serving.kv_cache import _quant_tokens, init_paged_pools
+from deepspeed_tpu.serving.kv_cache import (_quant_tokens, init_paged_pools,
+                                            live_block_list)
 from deepspeed_tpu.telemetry import (InMemorySink, MetricsRegistry,
                                      RecompileDetector, StepTracer,
                                      Telemetry)
@@ -184,6 +186,208 @@ class TestPagedKernelParity:
 
 
 # ---------------------------------------------------------------------------
+# The default decode: attention over the flat list of live blocks
+# ---------------------------------------------------------------------------
+
+class TestLiveBlockDecode:
+    """``attend_live`` on raw pools against ``update()`` + dense masked
+    attention, and through the engine against ``generate()``."""
+
+    B, H, D, BS, N, MB = 6, 4, 16, 4, 40, 8
+    # (slot, blocks, pos): one block only; ending exactly on a block
+    # boundary (pos 11 is the last position of its third block); at the
+    # table's full width (the last position of ``max_model_len``); two
+    # rows reading the same two blocks (a shared prompt head) and then
+    # their own. Slots 1 and 4 are dead: scratch table rows, no entry.
+    ROWS = [(0, [9], 2),
+            (2, [3, 7, 2], 11),
+            (3, [11, 12, 13, 14, 15, 16, 17, 18], 31),
+            (5, [20, 21, 30], 8)]
+    SHARED = (2, [20, 21, 31, 32], 13)
+
+    def _pools(self, kind, rng):
+        shape = (self.N, self.BS, self.H, self.D)
+        dtype = jnp.bfloat16 if kind == "bfloat16" else jnp.float32
+        k = jnp.asarray(rng.normal(size=shape), dtype)
+        v = jnp.asarray(rng.normal(size=shape), dtype)
+        ks = vs = None
+        if kind == "int8":
+            k, ks = _quant_tokens(k)
+            v, vs = _quant_tokens(v)
+        k, v = (p.reshape(self.N, self.BS, self.H * self.D) for p in (k, v))
+        return k, v, ks, vs, dtype
+
+    def _inputs(self, rows, rng, dtype):
+        bt = np.zeros((self.B, self.MB), np.int32)
+        pos = np.zeros((self.B,), np.int32)
+        for slot, blocks, p in rows:
+            bt[slot, :len(blocks)] = blocks
+            pos[slot] = p
+        q, knew, vnew = (jnp.asarray(
+            rng.normal(size=(self.B, 1, self.H, self.D)), dtype)
+            for _ in range(3))
+        return jnp.asarray(bt), jnp.asarray(pos), q, knew, vnew
+
+    def _attend_live(self, pools, name, rows, chunk, bt, pos, q, knew, vnew):
+        run, group = chunk
+        live, _, n_chunks = live_block_list(
+            rows, self.BS, run, group,
+            -(-self.B * -(-self.MB // run) // group))
+        flat = PagedLayerCache(*pools, bt, pos, self.BS, name,
+                               live=jnp.asarray(live),
+                               n_chunks=jnp.int32(n_chunks))
+        new, got = jax.jit(PagedLayerCache.attend_live)(flat, q, knew, vnew)
+        return new, np.asarray(got, np.float32), n_chunks
+
+    # (blocks a run, runs a chunk). Runs of 8: every row in one, all in
+    # one chunk; of 3: 1 + 1 + 3 + 1 runs in 3 chunks of two; of 2: 1 + 4 +
+    # 2 + 2 (the shared rows among them) in 3 chunks of four
+    @pytest.mark.parametrize("chunk,chunks,shared", [
+        ((8, 4), 1, False), ((3, 2), 3, False), ((2, 4), 3, True)],
+        ids=["1-chunk", "3-chunks", "3-chunks-shared-blocks"])
+    @pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+    def test_matches_update_plus_dense_attention(self, kind, chunk, chunks,
+                                                 shared):
+        rng = np.random.default_rng(5)
+        *pools, dtype = self._pools(kind, rng)
+        rows = self.ROWS
+        if shared:
+            rows = [r for r in rows if r[0] != 2] + [self.SHARED]
+        bt, pos, q, knew, vnew = self._inputs(rows, rng, dtype)
+        name = jnp.dtype(dtype).name
+        slow = PagedLayerCache(*pools, bt, pos, self.BS, name)
+        new_s, kk, vv, mask = slow.update(knew, vnew)
+        want = np.asarray(xla_attention(q, kk, vv, causal=False, mask=mask),
+                          np.float32)
+        new_f, got, n_chunks = self._attend_live(pools, name, rows, chunk,
+                                                 bt, pos, q, knew, vnew)
+        assert n_chunks == chunks
+        alive = [r[0] for r in rows]
+        dead = [b for b in range(self.B) if b not in alive]
+        # float32: only the order of summation differs. bfloat16 rounds
+        # the probabilities before they meet the values on both sides,
+        # at different scalings (2**-9 of outputs of order one); int8
+        # pools are dequantized exactly here and through a bfloat16-free
+        # float32 copy there.
+        tol = 2e-2 if kind == "bfloat16" else 5e-6
+        np.testing.assert_allclose(got[alive], want[alive], atol=tol,
+                                   rtol=tol)
+        assert not got[dead].any()            # no live block: zeros
+        # the write is update()'s (a jitted scale may differ in an ulp)
+        for a, b in zip(new_f.pools, new_s.pools):
+            if a is not None:
+                np.testing.assert_allclose(
+                    np.asarray(a, np.float32), np.asarray(b, np.float32),
+                    rtol=1e-6, atol=0)
+
+    @pytest.mark.parametrize("kind", ["bfloat16", "int8"])
+    def test_a_rows_output_is_the_same_bits_whoever_its_neighbours_are(
+            self, kind):
+        """A run holds one row's blocks, its partial depends on the run
+        alone, and a row's runs are folded in their order: the same
+        sequence alone in the batch, in another slot (its runs then start
+        a chunk instead of ending one), or behind rows of other lengths
+        gives the same bits. (Blocks packed with no regard to rows, one
+        running maximum a row and chunk, did not: on the chip a second pass
+        of one trace, its requests in other slots, gave other greedy tokens
+        in bfloat16.)"""
+        rng = np.random.default_rng(6)
+        *pools, dtype = self._pools(kind, rng)
+        name = jnp.dtype(dtype).name
+        slot, blocks, p = self.ROWS[2]                  # the longest row
+        bt, pos, q, knew, vnew = self._inputs(self.ROWS, rng, dtype)
+        _, among, _ = self._attend_live(pools, name, self.ROWS, (3, 2), bt,
+                                        pos, q, knew, vnew)
+        # alone, in another slot, with the same query, key and value
+        other = 1
+        move = lambda a: jnp.zeros_like(a).at[other].set(a[slot])
+        bt1 = jnp.zeros_like(bt).at[other].set(bt[slot])
+        _, alone, _ = self._attend_live(
+            pools, name, [(other, blocks, p)], (3, 2), bt1, move(pos),
+            move(q), move(knew), move(vnew))
+        np.testing.assert_array_equal(alone[other], among[slot])
+        assert among[slot].any()
+
+    def test_the_list_holds_each_rows_blocks_up_to_its_write(self):
+        live, n_blocks, n_chunks = live_block_list(self.ROWS, self.BS, 3, 4,
+                                                   5)
+        assert live.shape == (5, 4, 5) and live.dtype == np.int32
+        assert n_blocks == 1 + 3 + 8 + 3 == sum(p // self.BS + 1
+                                                for _, _, p in self.ROWS)
+        assert n_chunks == 2                    # 1 + 1 + 3 + 1 runs, by four
+        # a run a line: three blocks, the owning slot, the first position
+        assert live.reshape(-1, 5)[:8].tolist() == [
+            [9, 0, 0, 0, 0],
+            [3, 7, 2, 2, 0],
+            [11, 12, 13, 3, 0], [14, 15, 16, 3, 12], [17, 18, 0, 3, 24],
+            [20, 21, 30, 5, 0],
+            [0, 0, 0, -1, 0], [0, 0, 0, -1, 0]]    # nobody's: padding
+        assert (live.reshape(-1, 5)[8:] == [0, 0, 0, -1, 0]).all()
+        # a table shorter than the write position is a fault, not a pad
+        with pytest.raises(ValueError):
+            live_block_list([(0, [9], 4)], self.BS, 3, 4, 5)
+        with pytest.raises(ValueError):                  # nor a list too short
+            live_block_list(self.ROWS, self.BS, 3, 4, 1)
+
+    @pytest.mark.parametrize("over", [
+        {}, {"int8_kv_cache": True}, {"prefix_cache": True}],
+        ids=["plain", "int8-pool", "prefix-cache"])
+    @pytest.mark.parametrize("run,group", [(2, 2), (16, 2)],
+                             ids=["many-chunks", "one-chunk"])
+    def test_engine_matches_generate_and_reads_what_is_live(
+            self, gpt_setup, monkeypatch, run, group, over):
+        """Greedy outputs of a mixed trace, token for token those of
+        ``generate()``, with the list walked in several chunks (two runs
+        of two blocks a chunk) and in one (the engine's own sizes); and on
+        every dispatch what is read covers what is live."""
+        model, cfg, params = gpt_setup
+        monkeypatch.setattr(ServeEngine, "LIVE_RUN_BLOCKS", run)
+        monkeypatch.setattr(ServeEngine, "LIVE_CHUNK_RUNS", group)
+        srv = _serve(model, params, **over)
+        rng = np.random.default_rng(7)
+        head = rng.integers(0, cfg.vocab_size, (8,)).tolist()
+        prompts = [head + rng.integers(0, cfg.vocab_size, (t,)).tolist()
+                   for t, _ in TRACE]
+        rids = [srv.submit(p, n) for p, (_, n) in zip(prompts[:3], TRACE)]
+        dispatches = 0
+        while not srv.idle():
+            if srv._step_count == 2:     # two join a running batch
+                rids += [srv.submit(p, n) for p, (_, n)
+                         in zip(prompts[3:], TRACE[3:])]
+            before = dict(srv.stats)
+            srv.step()
+            read, live, blocks, chunks = (
+                srv.stats[key] - before[key] for key in (
+                    "read_positions", "live_positions", "live_blocks",
+                    "chunks"))
+            if read:
+                dispatches += 1
+                assert read >= live > 0
+                assert read == chunks * run * group * srv.block_size
+                assert chunks >= -(-blocks // (run * group))
+        assert dispatches == srv.stats["decode_steps"] > 5
+        assert run == 16 or srv.stats["chunks"] > 2 * dispatches
+        if "int8_kv_cache" in over:
+            # a quantized cache may flip a near-tie against generate();
+            # the oracle is the same pool through update() and the window
+            ref = _serve(model, params, decode_attention="auto", **over)
+            want = [ref.submit(p, n) for p, (_, n) in zip(prompts, TRACE)]
+            ref.run_until_complete()
+            want = [ref.results[r]["tokens"] for r in want]
+        else:
+            eng = deepspeed_tpu.init_inference(model, params=params,
+                                               dtype=jnp.float32)
+            want = [np.asarray(eng.generate(
+                np.asarray([p], np.int32), max_new_tokens=n))[0].tolist()
+                for p, (_, n) in zip(prompts, TRACE)]
+        assert [srv.results[r]["tokens"] for r in rids] == want
+        det = srv.engine.recompile_detector
+        assert det.compiles("serving.decode_step") == 1
+        assert det.retraces("serving.decode_step") == 0
+        assert len(srv._decode_jits) == 1
+
+
+# ---------------------------------------------------------------------------
 # Engine-level token identity + window capping
 # ---------------------------------------------------------------------------
 
@@ -208,12 +412,10 @@ class TestFastPathTokenIdentity:
         the modeled gathered positions drop well below the full-window
         program's on the same trace."""
         model, cfg, params = gpt_setup
-        srv_off = _serve(model, params)
-        _run_trace(srv_off, cfg)
         srv = _serve(model, params, decode_attention="auto")
         _run_trace(srv, cfg)
-        assert srv.stats["full_positions"] == \
-            srv_off.stats["gathered_positions"]
+        assert srv.stats["full_positions"] == (
+            srv.stats["decode_steps"] * srv.max_blocks * srv.block_size)
         assert srv.stats["gathered_positions"] < \
             0.7 * srv.stats["full_positions"]
         # each window bucket is its own expected-first-compile scope —
@@ -389,20 +591,32 @@ class TestSpeculative:
 
 class TestOffContract:
     def test_decode_lowering_pinned_to_pr8_program(self, gpt_setup):
-        """Jaxpr pin: with the fast path fully off, the engine's decode
-        program lowers bit-identically to the pre-fast-path (PR 8)
-        decode impl, reconstructed here from the same public pieces —
-        full-window gather, no window slicing, no kernel, no clamps."""
+        """The pin, turned round: PR 8's decode program gathered, reshaped
+        and attended over ``slots x max_blocks x block_size`` positions of
+        keys whatever was live, and this test held the default engine to
+        that program. The default decode now reads the list of live
+        blocks, so its lowered text must hold NO operand or result over
+        that window: not the gathered keys or values, not their scores or
+        mask. PR 8's program, rebuilt here from the same public pieces
+        (the cache without a list), shows that the search finds them."""
+        import re
+
         from deepspeed_tpu.inference.engine import sample_logits
 
         model, cfg, params = gpt_setup
         srv = _serve(model, params)
-        nb, mb = srv.scfg.max_batch_size, srv.max_blocks
+        srv.submit([1, 2, 3, 4, 5], 3)
+        srv.run_until_complete()
+        (decode,) = srv._decode_jits.values()     # what the engine runs
+        nb, mb, bs = srv.scfg.max_batch_size, srv.max_blocks, srv.block_size
+        shape = (srv._live_chunks, srv.LIVE_CHUNK_RUNS,
+                 srv.LIVE_RUN_BLOCKS + 2)
         bt = jnp.zeros((nb, mb), jnp.int32)
         pos = jnp.zeros((nb,), jnp.int32)
         toks = jnp.zeros((nb,), jnp.int32)
         rng = jax.random.fold_in(srv._base_key, 0)
         args = (srv.engine.params, srv._pools, bt, pos, toks, rng)
+        live = (jnp.zeros(shape, jnp.int32), jnp.int32(1))
 
         def pr8_decode_impl(params, pools, bt, pos, toks, rng):
             cache = tuple(
@@ -418,19 +632,27 @@ class TestOffContract:
                                 srv.scfg.top_k)
             return tok, logits, tuple(c.pools for c in out["cache"])
 
-        import re
+        window = mb * bs                    # key positions a row reserves
+        over_window = {nb * window * cfg.num_heads * cfg.head_dim,  # K, V
+                       nb * window * cfg.num_heads,                 # scores
+                       nb * window}                                 # mask
 
-        def canon(text):
-            # the module carries the python function's name — the only
-            # legitimate difference between the two lowerings
-            return re.sub(r"module @\S+", "module @m", text)
+        def tensors_over_window(text):
+            found = set()
+            for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]", text):
+                dims = [int(d) for d in dims.rstrip("x").split("x")]
+                if window in dims and int(np.prod(dims)) in over_window:
+                    found.add(tuple(dims))
+            return found
 
-        ours = jax.jit(functools.partial(srv._decode_impl,
-                                         attn_impl="gather"),
-                       donate_argnums=(1,)).lower(*args).as_text()
         pr8 = jax.jit(pr8_decode_impl,
                       donate_argnums=(1,)).lower(*args).as_text()
-        assert canon(ours) == canon(pr8)
+        assert len(tensors_over_window(pr8)) >= 3       # the search reads
+        ours = decode.lower(*args, *live).as_text()
+        assert not tensors_over_window(ours)
+        # and it takes the list: a run a line, with its slot and start
+        assert "tensor<%dx%dx%dxi32>" % shape in ours
+        assert "stablehlo.while" in ours and "stablehlo.while" not in pr8
 
     def test_off_emits_no_fastpath_tags(self, gpt_setup):
         """A fully-off engine's emitted tag set is byte-identical to the

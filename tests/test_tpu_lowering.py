@@ -9,8 +9,9 @@ compilable. It says nothing about speed, and nothing about numerics on
 the chip (``chip_smoke.py`` does that).
 
 The compiled text also shows what the compiler does to a program's
-ARGUMENTS: the last test holds the serving programs to reading and
-writing the paged KV pool in the layout it arrives in.
+ARGUMENTS: the serving programs are held to reading and writing the paged
+KV pool in the layout it arrives in, and the default decode to reading
+the list of live blocks and nothing of the reserved window's size.
 """
 
 import functools
@@ -99,6 +100,71 @@ HLO_RESULT = re.compile(
     r"= \w+\[([\d,]+)\]\{([\d,]+)[^}]*\} ([\w\-]+)\(")
 
 
+def _lower_serving_program(program, int8, sharding, layers=2):
+    """The engine's decode program (the body of ``ServeEngine._decode_impl``
+    over the list of live blocks) or its pack program, lowered for the
+    described chip at gpt2-medium's width with ``layers`` of its 24 layers:
+    each layer's pools meet the same scatter, gather and donation."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.gpt import make_gpt
+    from deepspeed_tpu.serving.engine import ServeEngine
+    from deepspeed_tpu.serving.kv_cache import (PagedLayerCache,
+                                                init_paged_pools,
+                                                pack_prefill)
+
+    model, cfg = make_gpt("gpt2-medium", num_layers=layers, dropout_rate=0.0)
+    run, group = ServeEngine.LIVE_RUN_BLOCKS, ServeEngine.LIVE_CHUNK_RUNS
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
+                                           sharding=sharding), tree)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    pools = on_chip(jax.eval_shape(lambda: init_paged_pools(
+        cfg, POOL_BLOCKS, POOL_BLOCK, int8=int8, dtype=jnp.bfloat16)))
+
+    if program == "decode":
+        params = on_chip(jax.eval_shape(
+            lambda rng: model.init(
+                rng, {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"],
+            jax.random.PRNGKey(0)), jnp.bfloat16)
+
+        def decode(params, pools, bt, pos, toks, live, n_chunks):
+            cache = tuple(PagedLayerCache(*pools[i], bt, pos, POOL_BLOCK,
+                                          "bfloat16", live=live,
+                                          n_chunks=n_chunks)
+                          for i in range(layers))
+            out = model.apply(
+                {"params": params},
+                {"input_ids": toks[:, None], "position_ids": pos[:, None]},
+                deterministic=True, cache=cache, pos=None)
+            return out["logits"][:, -1], tuple(c.pools for c in out["cache"])
+
+        lowered = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, pools, ints(SLOTS, TABLE), ints(SLOTS), ints(SLOTS),
+            ints(SLOTS * (TABLE // run) // group, group, run + 2), ints())
+    else:                           # a 256-token prompt bucket
+        stack = jax.ShapeDtypeStruct(
+            (layers, 256, cfg.num_heads, cfg.head_dim), jnp.bfloat16,
+            sharding=sharding)
+        lowered = jax.jit(pack_prefill, donate_argnums=(0,)).lower(
+            pools, ints(256 // POOL_BLOCK), stack, stack)
+    return lowered.compile().as_text(), cfg
+
+
+def _results(hlo, size, opcodes=None):
+    """``(opcode, layout)`` of the results in ``hlo`` with ``size``
+    elements, of ``opcodes`` alone if given."""
+    return [(op, [int(i) for i in layout.split(",")])
+            for dims, layout, op in HLO_RESULT.findall(hlo)
+            if (opcodes is None or op in opcodes)
+            and math.prod(map(int, dims.split(","))) == size]
+
+
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("program", ["decode", "pack"])
 def test_serving_programs_take_the_pool_as_it_is_stored(program, int8,
@@ -113,71 +179,52 @@ def test_serving_programs_take_the_pool_as_it_is_stored(program, int8,
     SCALE pools ``[N, BS, H]`` are exempt: they do arrive block-minor and
     are copied, at 1/32 of the bytes (``init_paged_pools`` says why they
     stay)."""
-    import jax.numpy as jnp
-
-    from deepspeed_tpu.models.gpt import make_gpt
-    from deepspeed_tpu.serving.kv_cache import (PagedLayerCache,
-                                                init_paged_pools,
-                                                pack_prefill)
-
-    # gpt2-medium's width, two of its 24 layers: each layer's pools meet
-    # the same scatter, gather and donation
     layers = 2
-    model, cfg = make_gpt("gpt2-medium", num_layers=layers, dropout_rate=0.0)
-
-    def on_chip(tree, dtype=None):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
-                                           sharding=v5e_sharding), tree)
-
-    def ints(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_sharding)
-
-    pools = on_chip(jax.eval_shape(lambda: init_paged_pools(
-        cfg, POOL_BLOCKS, POOL_BLOCK, int8=int8, dtype=jnp.bfloat16)))
-
-    if program == "decode":         # the body of ServeEngine._decode_impl
-        params = on_chip(jax.eval_shape(
-            lambda rng: model.init(
-                rng, {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"],
-            jax.random.PRNGKey(0)), jnp.bfloat16)
-
-        def decode(params, pools, bt, pos, toks):
-            cache = tuple(PagedLayerCache(*pools[i], bt, pos, POOL_BLOCK,
-                                          "bfloat16") for i in range(layers))
-            out = model.apply(
-                {"params": params},
-                {"input_ids": toks[:, None], "position_ids": pos[:, None]},
-                deterministic=True, cache=cache, pos=None)
-            return out["logits"][:, -1], tuple(c.pools for c in out["cache"])
-
-        lowered = jax.jit(decode, donate_argnums=(1,)).lower(
-            params, pools, ints(SLOTS, TABLE), ints(SLOTS), ints(SLOTS))
-    else:                           # a 256-token prompt bucket
-        stack = jax.ShapeDtypeStruct(
-            (layers, 256, cfg.num_heads, cfg.head_dim), jnp.bfloat16,
-            sharding=v5e_sharding)
-        lowered = jax.jit(pack_prefill, donate_argnums=(0,)).lower(
-            pools, ints(256 // POOL_BLOCK), stack, stack)
-
-    text = lowered.compile().as_text()
+    text, cfg = _lower_serving_program(program, int8, v5e_sharding, layers)
     entry = text[text.index("\nENTRY "):]
     entry = entry[:entry.index("\n}")]
     pool_size = POOL_BLOCKS * POOL_BLOCK * cfg.num_heads * cfg.head_dim
-
-    def results(hlo, opcode):
-        """Layouts of ``opcode``'s results of a K/V pool's size."""
-        return [[int(i) for i in layout.split(",")]
-                for dims, layout, op in HLO_RESULT.findall(hlo)
-                if op == opcode
-                and math.prod(map(int, dims.split(","))) == pool_size]
-
-    pool_params = results(entry, "parameter")
+    pool_params = _results(entry, pool_size, ("parameter",))
     assert len(pool_params) == 2 * layers            # the regex still reads
     assert all(layout == sorted(layout, reverse=True)
-               for layout in pool_params), pool_params
-    copies = results(text, "copy")          # fused computations included
+               for _, layout in pool_params), pool_params
+    copies = _results(text, pool_size, ("copy",))   # fused ones included
     assert not copies, f"{len(copies)} whole-pool copies: {copies}"
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_the_default_decode_reads_the_live_blocks_and_no_window(
+        int8, v5e_sharding):
+    """What PR 8's decode program did whatever was live: gather ``slots x
+    max_blocks`` blocks of keys and of values (64 x 64 x 16 positions x
+    1024 lanes each), reshape them to ``[.., H, D]`` and attend over 1024
+    masked keys a row: nine tenths of a 90 ms step at a ninth live
+    (PERF.md section 6, PR 27). The default decode walks the list of live
+    blocks in a loop with a traced trip count, a chunk at a time. So, in
+    the text compiled for the chip: one ``while`` per layer; NOTHING of
+    the window's size (no result of any op has the element count of the
+    keys gathered over 64 x 1024 positions); and of the pool's size no
+    copy, reshape, transpose or gather, only the pool itself, written in
+    place."""
+    layers = 2
+    text, cfg = _lower_serving_program("decode", int8, v5e_sharding, layers)
+    assert len(re.findall(r" while\(", text)) == layers
+    window = SLOTS * TABLE * POOL_BLOCK * cfg.num_heads * cfg.head_dim
+    found = _results(text, window)
+    assert not found, f"results of the window's size: {found[:4]}"
+    # a chunk of the list IS gathered: the search reads such results
+    from deepspeed_tpu.serving.engine import ServeEngine
+    chunk = (ServeEngine.LIVE_CHUNK_RUNS * ServeEngine.LIVE_RUN_BLOCKS
+             * POOL_BLOCK * cfg.num_heads * cfg.head_dim)
+    assert len(_results(text, chunk, ("fusion",))) >= 2 * layers
+    pool_size = POOL_BLOCKS * POOL_BLOCK * cfg.num_heads * cfg.head_dim
+    moved = _results(text, pool_size,
+                     ("copy", "reshape", "transpose", "gather"))
+    assert not moved, moved
+    # fused gathers bear the name of what they fuse
+    assert not [line for line in text.splitlines()
+                if "/gather\"" in line and f"[{POOL_BLOCKS}," in
+                line.split(" fusion(")[0]]
 
 
 def test_the_dropless_expert_layer_at_the_cells_size(v5e_sharding):

@@ -4,8 +4,9 @@ Three claims of docs/SERVING.md "Decode fast path", measured on a tiny
 GPT over the CPU backend (Pallas interpreter for the kernel):
 
 1. **Token identity** — the same mixed request trace produces
-   byte-identical outputs with the fast path fully off (PR-8 gather
-   program), with the paged decode-attention kernel forced, with the
+   byte-identical outputs with the fast path fully off (the default
+   decode over the batch's live blocks), with the paged
+   decode-attention kernel forced, with the
    prefix cache on, and with speculative decoding on. Every fast-path
    piece is a pure-performance lever.
 2. **Prefix reuse works** — a shared-prompt-head workload drives
@@ -15,7 +16,7 @@ GPT over the CPU backend (Pallas interpreter for the kernel):
    ``decode_attention: auto`` (no TPU -> capped gather), the decode
    program's key window covers the max ACTIVE length instead of the full
    ``max_blocks`` table: the modeled gathered-positions total drops
-   measurably on the same trace.
+   measurably below the full window's on the same trace.
 
 Run: JAX_PLATFORMS=cpu python tools/probe_serving_fastpath.py [--selftest]
 (tier-1 via tests/test_serving_fastpath.py)
@@ -90,12 +91,12 @@ def main(argv=None) -> int:
         rows.append((name, srv))
     print("token identity: every configuration matches the off path "
           f"({len(TRACE)} requests)")
-    print(f"{'config':24s} {'kernel steps':>12s} {'gathered pos':>12s} "
-          f"{'spec acc/prop':>14s}")
+    print(f"{'config':24s} {'kernel steps':>12s} {'read pos':>12s} "
+          f"{'live pos':>12s} {'spec acc/prop':>14s}")
     for name, srv in rows:
         st = srv.stats
         print(f"{name:24s} {st['kernel_steps']:12d} "
-              f"{st['gathered_positions']:12d} "
+              f"{st['read_positions']:12d} {st['live_positions']:12d} "
               f"{st['spec_accepted']:6d}/{st['spec_proposed']:<6d}")
 
     # -- 2. prefix reuse + refcount leak check --------------------------
@@ -117,13 +118,12 @@ def main(argv=None) -> int:
           f"drains to 0 after clear")
 
     # -- 3. capped fallback gathers measurably less ---------------------
-    off = base_srv.stats
     capped = dict(rows)["auto (capped gather)"].stats
-    assert capped["full_positions"] == off["gathered_positions"], \
+    assert capped["decode_steps"] == base_srv.stats["decode_steps"], \
         "traces not comparable"
-    ratio = capped["gathered_positions"] / max(1, off["gathered_positions"])
+    ratio = capped["gathered_positions"] / max(1, capped["full_positions"])
     print(f"capped fallback: {capped['gathered_positions']} vs "
-          f"{off['gathered_positions']} gathered key positions "
+          f"{capped['full_positions']} gathered key positions a row "
           f"({ratio:.2f}x)")
     assert ratio < 0.7, (
         f"capped gather should cut gathered positions well below the "
