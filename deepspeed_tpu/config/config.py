@@ -1001,9 +1001,9 @@ class ServingConfig:
 
     Decode fast path (docs/SERVING.md "Decode fast path" — all three
     off by default): ``decode_attention`` gather|auto|kernel: "gather"
-    decodes over the flat list of the batch's live blocks, "auto" and
-    "kernel" select the Pallas paged decode-attention kernel (with the
-    max-active-length-capped gather as "auto"'s fallback);
+    decodes over the flat list of the batch's live blocks, "kernel"
+    selects the Pallas paged decode-attention kernel, "auto" the kernel
+    on a TPU where it tiles and else the "gather" decode;
     ``prefix_cache`` turns on COW prompt-head block reuse;
     ``speculative`` configures draft-model speculative decoding
     (greedy-identical by construction — requires ``temperature == 0``).

@@ -301,8 +301,8 @@ SERVING_SEED = "seed"
 SERVING_SEED_DEFAULT = 0
 # decode fast path (docs/SERVING.md "Decode fast path"): "gather" is
 # ONE decode program over the flat list of live blocks; "auto" runs the
-# Pallas paged decode-attention kernel where the geometry tiles and the
-# max-active-length-capped gather elsewhere; "kernel" forces the kernel
+# Pallas paged decode-attention kernel on a TPU where the geometry tiles
+# and that same "gather" program elsewhere; "kernel" forces the kernel
 # (Pallas interpreter off-TPU — the parity/bench path).
 SERVING_DECODE_ATTENTION = "decode_attention"
 SERVING_DECODE_ATTENTION_DEFAULT = "gather"

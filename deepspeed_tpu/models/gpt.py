@@ -28,7 +28,6 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.ops.transformer.attention import attention
 from deepspeed_tpu.ops.xent import fused_cross_entropy
-from deepspeed_tpu.utils.platform import on_tpu
 
 
 from deepspeed_tpu.ops.dropout import dropout_module as _dropout_mod
@@ -54,16 +53,6 @@ class GPTConfig:
     fused_ce_fp32_logits: bool = False
     # None -> 1/sqrt(head_dim); GPT-Neo trains UNSCALED attention (1.0)
     attention_scale: Any = None
-    # MXU tiling lever (PROFILE.md r3): pad the wte vocab dim to a multiple
-    # (128 pads GPT-2's 50257 -> 50304) so the tied head matmul tiles
-    # exactly; pad logits are masked to -1e9 in the CE, so the loss is
-    # numerically identical to the unpadded model and pad rows stay at
-    # init. 0 = off. Applies to the tied-embedding head (lm_head stays
-    # unpadded when untied).
-    vocab_pad_multiple: int = 0
-    # Embedding-table gradient via one-hot MXU matmul instead of XLA's
-    # serialized TPU scatter-add (ops/embedding.py; PROFILE.md r3 lever).
-    embed_grad_matmul: bool = False
     # Row-sparse cross-rank embedding-grad exchange (config
     # `sparse_gradients: true` — reference engine.py:1530-1586):
     # (mesh, axes) — what deepspeed_tpu.initialize() bakes in (the
@@ -74,16 +63,6 @@ class GPTConfig:
     # threefry bernoulli — the reference's fused-dropout economy
     # (csrc/transformer/dropout_kernels.cu); measured A/B in PROFILE.md.
     fast_dropout: bool = True
-    # Fused LayerNorm+projection Pallas kernel at the two pre-LN sites
-    # (LN1+QKV and LN2+fc1+GELU) — the reference's fused-block economy
-    # (csrc/transformer/ds_transformer_cuda.cpp:147). OFF by default:
-    # measured end-to-end LOSS on v5e despite winning isolated micro A/Bs
-    # (r5, tools/probe_fused_r5.py: qkv-only 0.93x, mlp-only 0.95x,
-    # both 0.90x of baseline — the pallas_call is an XLA fusion barrier,
-    # and the surrounding transposes/adds XLA previously fused into the
-    # matmuls become standalone HBM passes; PROFILE.md r5). Values:
-    # True/"auto" = both sites, "qkv"/"mlp" = one site, False = unfused.
-    fused_ln: Any = False
     # Block-sparse attention config dict (the DeepSpeed `sparse_attention`
     # block: mode/block/num_local_blocks/...). When set, training attention
     # routes through ops.sparse_attention (long-sequence O(s·√s) path);
@@ -119,13 +98,6 @@ class GPTConfig:
         return self.hidden_size // self.num_heads
 
     @property
-    def padded_vocab(self) -> int:
-        m = self.vocab_pad_multiple
-        if m <= 1:
-            return self.vocab_size
-        return (self.vocab_size + m - 1) // m * m
-
-    @property
     def num_params(self) -> int:
         d, l, v = self.hidden_size, self.num_layers, self.vocab_size
         per_layer = 12 * d * d + 13 * d
@@ -141,34 +113,6 @@ GPT_CONFIGS: Dict[str, GPTConfig] = {
     "gpt2-large": GPTConfig(hidden_size=1280, num_layers=36, num_heads=20),
     "gpt2-xl": GPTConfig(hidden_size=1600, num_layers=48, num_heads=25),
 }
-
-
-def _use_fused_ln(cfg, x) -> frozenset:
-    """Dispatch for the fused LN+projection path (GPTConfig.fused_ln):
-    returns the set of fused sites ("qkv", "mlp"). "auto" = both on TPU
-    when shapes tile; True forces both (Pallas interpret off-TPU — parity
-    tests); "qkv"/"mlp" select one site; False = unfused flax modules.
-
-    Mode validation comes FIRST — a typo must always raise, never silently
-    train unfused just because shapes happen not to tile. Each site is then
-    shape-gated independently: an untileable mlp dim no longer disables a
-    requested (and tileable) qkv fusion, and vice versa."""
-    mode = getattr(cfg, "fused_ln", False)
-    if mode is False or mode is None:
-        return frozenset()
-    if mode is not True and mode not in ("auto", "qkv", "mlp"):
-        raise ValueError(f"unknown fused_ln value {mode!r}: expected False, "
-                         "True, 'auto', 'qkv', or 'mlp'")
-    if mode == "auto" and not on_tpu():
-        return frozenset()
-    from deepspeed_tpu.ops.transformer.fused import ln_matmul_ok
-
-    n = x.shape[0] * x.shape[1]
-    want = ("qkv", "mlp") if mode in (True, "auto") else (mode,)
-    out_dim = {"qkv": 3 * cfg.hidden_size,
-               "mlp": cfg.mlp_ratio * cfg.hidden_size}
-    return frozenset(s for s in want
-                     if ln_matmul_ok(n, cfg.hidden_size, out_dim[s]))
 
 
 class GPTBlock(nn.Module):
@@ -198,21 +142,9 @@ class GPTBlock(nn.Module):
         cfg = self.cfg
         d = cfg.hidden_size
         dt = cfg.dtype
-        fused = _use_fused_ln(cfg, x)
-
-        if fused:
-            from deepspeed_tpu.ops.transformer.fused import (DenseParams,
-                                                             LNParams,
-                                                             ln_matmul)
-        if "qkv" in fused:
-            scale1, lnb1 = LNParams(d, name="ln_1")()
-            wk, wb = DenseParams(d, 3 * d, name="c_attn")()
-            qkv = ln_matmul(x, scale1, lnb1, wk.astype(dt), wb.astype(dt),
-                            eps=cfg.layer_norm_epsilon)
-        else:
-            h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon,
-                             dtype=jnp.float32, name="ln_1")(x).astype(dt)
-            qkv = nn.Dense(3 * d, dtype=dt, name="c_attn")(h)
+        h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon,
+                         dtype=jnp.float32, name="ln_1")(x).astype(dt)
+        qkv = nn.Dense(3 * d, dtype=dt, name="c_attn")(h)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         b, s = q.shape[0], q.shape[1]
         shape = (b, s, cfg.num_heads, cfg.head_dim)
@@ -289,39 +221,32 @@ class GPTBlock(nn.Module):
         x = x + o
 
         aux = None
-        if "mlp" in fused and not self.moe:
-            scale2, lnb2 = LNParams(d, name="ln_2")()
-            wf, bf2 = DenseParams(d, cfg.mlp_ratio * d, name="c_fc")()
-            h = ln_matmul(x, scale2, lnb2, wf.astype(dt), bf2.astype(dt),
-                          eps=cfg.layer_norm_epsilon, activation="gelu")
-            h = nn.Dense(d, dtype=dt, name="mlp_proj")(h)
-        else:
-            h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon,
-                             dtype=jnp.float32, name="ln_2")(x).astype(dt)
-            if self.moe:
-                from deepspeed_tpu.moe import MoE, MoEConfig
+        h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon,
+                         dtype=jnp.float32, name="ln_2")(x).astype(dt)
+        if self.moe:
+            from deepspeed_tpu.moe import MoE, MoEConfig
 
-                moe_out = MoE(MoEConfig(
-                    hidden_size=d, num_experts=cfg.moe_experts, k=cfg.moe_k,
-                    capacity_factor=cfg.moe_capacity_factor,
-                    eval_capacity_factor=cfg.moe_eval_capacity_factor,
-                    min_capacity=cfg.moe_min_capacity,
-                    router_jitter=cfg.moe_router_jitter,
-                    dispatch=cfg.moe_dispatch, mesh=cfg.moe_mesh,
-                    stats=cfg.moe_stats,
-                    expert_intermediate=cfg.mlp_ratio * d, dtype=dt),
-                    name="moe")(h, deterministic=deterministic)
-                if cfg.moe_stats:
-                    # Bundle (aux, stats) so the block's return arity
-                    # stays fixed; GPT unpacks the pair.
-                    h, aux_loss, moe_stats = moe_out
-                    aux = (aux_loss, moe_stats)
-                else:
-                    h, aux = moe_out
+            moe_out = MoE(MoEConfig(
+                hidden_size=d, num_experts=cfg.moe_experts, k=cfg.moe_k,
+                capacity_factor=cfg.moe_capacity_factor,
+                eval_capacity_factor=cfg.moe_eval_capacity_factor,
+                min_capacity=cfg.moe_min_capacity,
+                router_jitter=cfg.moe_router_jitter,
+                dispatch=cfg.moe_dispatch, mesh=cfg.moe_mesh,
+                stats=cfg.moe_stats,
+                expert_intermediate=cfg.mlp_ratio * d, dtype=dt),
+                name="moe")(h, deterministic=deterministic)
+            if cfg.moe_stats:
+                # Bundle (aux, stats) so the block's return arity
+                # stays fixed; GPT unpacks the pair.
+                h, aux_loss, moe_stats = moe_out
+                aux = (aux_loss, moe_stats)
             else:
-                h = nn.Dense(cfg.mlp_ratio * d, dtype=dt, name="c_fc")(h)
-                h = nn.gelu(h, approximate=True)
-                h = nn.Dense(d, dtype=dt, name="mlp_proj")(h)
+                h, aux = moe_out
+        else:
+            h = nn.Dense(cfg.mlp_ratio * d, dtype=dt, name="c_fc")(h)
+            h = nn.gelu(h, approximate=True)
+            h = nn.Dense(d, dtype=dt, name="mlp_proj")(h)
         h = _dropout_mod(cfg)(cfg.dropout_rate, deterministic=deterministic)(h)
         x = x + h
         out = (x, kv_cache) if kv_cache is not None else x
@@ -353,7 +278,7 @@ class GPT(nn.Module):
         ids = batch["input_ids"]
         b, s = ids.shape
         wte = self.param("wte", nn.initializers.normal(0.02),
-                         (cfg.padded_vocab, cfg.hidden_size), jnp.float32)
+                         (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         wpe = self.param("wpe", nn.initializers.normal(0.01),
                          (cfg.max_seq_len, cfg.hidden_size), jnp.float32)
         pos_ids = batch.get("position_ids") if isinstance(batch, dict) else None
@@ -367,8 +292,7 @@ class GPT(nn.Module):
             pe = jnp.take(wpe, pos + jnp.arange(s), axis=0)[None]
         from deepspeed_tpu.ops.embedding import embedding_lookup
         tok = embedding_lookup(
-            wte, ids, matmul_grad=cfg.embed_grad_matmul,
-            sparse_grad_axes=cfg.sparse_embedding_grad)
+            wte, ids, sparse_grad_axes=cfg.sparse_embedding_grad)
         x = tok.astype(cfg.dtype) + pe.astype(cfg.dtype)
         x = _dropout_mod(cfg)(cfg.dropout_rate, deterministic=deterministic)(x)
 
@@ -466,8 +390,6 @@ class GPT(nn.Module):
             logits = jnp.einsum("bsd,vd->bsv", x.astype(cfg.dtype),
                                 wte.astype(cfg.dtype),
                                 preferred_element_type=jnp.float32)
-            if cfg.padded_vocab != cfg.vocab_size:
-                logits = logits[..., :cfg.vocab_size]
         else:
             logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                               name="lm_head")(x.astype(cfg.dtype)).astype(jnp.float32)
@@ -484,12 +406,8 @@ class GPT(nn.Module):
         # can't CSE; acceptable for eval loops, free for training.)
         labels = shift_labels(batch)
         if cfg.tie_embeddings and cfg.fused_ce:
-            from deepspeed_tpu.ops.embedding import vocab_pad_mask
-            mask = (vocab_pad_mask(cfg.padded_vocab, cfg.vocab_size)
-                    if cfg.padded_vocab != cfg.vocab_size else None)
             loss = fused_cross_entropy(x.astype(cfg.dtype),
                                        wte.astype(cfg.dtype), labels,
-                                       bias=mask, bias_grad=False,
                                        logits_fp32=cfg.fused_ce_fp32_logits)
         else:
             loss = cross_entropy_with_ignore(logits, labels)

@@ -1,46 +1,15 @@
-"""Embedding lookup with an MXU-matmul gradient.
+"""Embedding lookup with a row-sparse cross-rank gradient.
 
-The forward is an ordinary row gather (cheap everywhere). The BACKWARD of a
-gather is a scatter-add into the [V, D] table, which XLA lowers on TPU to a
-slow serialized scatter (measured 0.6 GB + scatter per GPT-2 microbatch,
-PROFILE.md r3). ``matmul_grad=True`` swaps that transpose for a one-hot
-contraction ``dW = onehot(ids)ᵀ @ g`` — a [V, N] x [N, D] matmul that rides
-the MXU with fp32 accumulation; the one-hot lowers to an elementwise
-compare fused into the matmul operand.
-
-Reference analogue: none — torch's embedding backward is a CUDA
-scatter/atomics kernel (fast on GPU); this is a TPU-roofline redesign.
-Numerics: the matmul path sums contributions in fp32 in a fixed reduction
-order — parity-tested against the scatter path in tests/test_models.py.
+The forward is an ordinary row gather; its backward is XLA's scatter-add
+into the [V, D] table. With ``sparse_grad_axes`` the backward exchanges
+the touched rows over the data axes instead of all-reducing the dense
+table gradient (config ``sparse_gradients: true``; parity-tested against
+the dense path in tests/test_sparse_grads.py).
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-@jax.custom_vjp
-def _lookup_matmul_grad(table, ids):
-    return jnp.take(table, ids, axis=0)
-
-
-def _lookup_fwd(table, ids):
-    # The table residual is a reference (params stay live anyway), not a
-    # copy; it carries the static vocab size and dtype into the backward.
-    return jnp.take(table, ids, axis=0), (table, ids)
-
-
-def _lookup_bwd(res, g):
-    table, ids = res
-    v = table.shape[0]
-    d = g.shape[-1]
-    oh = jax.nn.one_hot(ids.reshape(-1), v, dtype=g.dtype)
-    dtable = jnp.einsum("nv,nd->vd", oh, g.reshape(-1, d),
-                        preferred_element_type=jnp.float32)
-    return dtable.astype(table.dtype), np.zeros(ids.shape, jax.dtypes.float0)
-
-
-_lookup_matmul_grad.defvjp(_lookup_fwd, _lookup_bwd)
 
 
 def _make_lookup_sparse(mesh, axes):
@@ -122,30 +91,15 @@ def resolve_sparse_grad_spec(setting):
 
 
 def embedding_lookup(table: jax.Array, ids: jax.Array,
-                     matmul_grad: bool = False,
                      sparse_grad_axes=None) -> jax.Array:
     """``table[ids]`` ([V, D] x [...] int -> [..., D]) with a selectable
-    gradient path: XLA scatter-add (default), the one-hot MXU matmul, or —
-    with ``sparse_grad_axes`` (mesh axis names, batch dim 0) — the
-    row-sparse cross-rank exchange (config ``sparse_gradients: true``)."""
+    gradient path: XLA scatter-add (default) or — with
+    ``sparse_grad_axes`` (mesh axis names, batch dim 0) — the row-sparse
+    cross-rank exchange (config ``sparse_gradients: true``)."""
     if sparse_grad_axes:
-        if matmul_grad:
-            raise ValueError("matmul_grad and sparse_grad_axes are "
-                             "mutually exclusive embedding-grad paths")
         spec = resolve_sparse_grad_spec(sparse_grad_axes)
         if spec is None:
             return jnp.take(table, ids, axis=0)
         mesh, axes = spec
         return _make_lookup_sparse(mesh, tuple(axes))(table, ids)
-    if matmul_grad:
-        return _lookup_matmul_grad(table, ids)
     return jnp.take(table, ids, axis=0)
-
-
-def vocab_pad_mask(padded_vocab: int, vocab_size: int) -> jax.Array:
-    """[padded_vocab] fp32 additive logit mask: 0 on real rows, -1e9 on pad
-    rows — keeps a padded-vocab CE numerically identical to the unpadded
-    model (pad logits vanish from the logsumexp; pad table rows get zero
-    gradient and stay at init)."""
-    return jnp.where(jnp.arange(padded_vocab) < vocab_size,
-                     0.0, -1e9).astype(jnp.float32)

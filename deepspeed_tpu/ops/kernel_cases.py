@@ -103,28 +103,6 @@ def _sparse_case(block: int, masked: bool) -> KernelCase:
         attend("xla", None))
 
 
-# -- fused LayerNorm + matmul (ops/transformer/fused.py) -------------------
-def _ln_matmul_case(activation) -> KernelCase:
-    from deepspeed_tpu.ops.transformer.fused import (ln_matmul,
-                                                     ln_matmul_reference)
-
-    n, d, f = 256, 128, 256     # ln_matmul_ok: lane-aligned d/f, n >= 128
-
-    def make_args(rng):
-        return (_normal(rng, (n, d)), 1.0 + 0.1 * _normal(rng, (d,)),
-                0.1 * _normal(rng, (d,)), 0.1 * _normal(rng, (d, f)),
-                0.1 * _normal(rng, (f,)), _normal(rng, (n, f)))
-
-    def run(interpret, *args):
-        return _fwd_bwd(lambda *a: ln_matmul(
-            *a, activation=activation, interpret=interpret), 5)(*args)
-
-    return KernelCase(
-        f"ln_matmul fwd+bwd activation={activation}", make_args, run,
-        _fwd_bwd(lambda *a: ln_matmul_reference(*a, activation=activation),
-                 5))
-
-
 # -- fused blockwise Adam (ops/adam/fused_update.py) -----------------------
 def _fused_adam_case(cast: bool) -> KernelCase:
     from deepspeed_tpu.ops.adam.fused_adam import AdamState, FusedAdam
@@ -242,7 +220,6 @@ def kernel_cases() -> List[KernelCase]:
         _flash_case(256, jnp.bfloat16),      # latent attention's head size
         _sparse_case(64, masked=False), _sparse_case(128, masked=False),
         _sparse_case(128, masked=True),      # masks need block % 128 == 0
-        _ln_matmul_case(None), _ln_matmul_case("gelu"),
         _fused_adam_case(cast=False), _fused_adam_case(cast=True),
         _paged_case(1, int8=False), _paged_case(1, int8=True),
         _paged_case(5, int8=False), _paged_case(5, int8=True),

@@ -3,8 +3,7 @@ round 2 for the serving hot loop (Sarathi-style chunked prefill,
 arXiv 2308.16369).
 
 The bucketed serving path compiles one prefill program per bucket (plus
-tail variants) and a capped-gather ladder for decode — a whole family of
-programs whose cold compiles land inside TTFT under bursty traffic. This
+tail variants) beside its decode program — a family of programs whose cold compiles land inside TTFT under bursty traffic. This
 kernel collapses all of it into ONE program per engine step: the batch is
 a flat **ragged token batch** ``[T]`` mixing decode tokens (one per
 running sequence) with prefill *chunks* of admitted prompts, bounded by a

@@ -56,7 +56,7 @@ def paged_decode_ok(head_dim: int, block_size: int) -> bool:
     ``[BS, H*D]`` rows: the lane dim is the head_dim (128-multiple, so a
     head's columns start on a lane-tile boundary) and the sublane dim
     the block size (8-multiple). Shapes that fail fall back to the
-    (capped) gather path — and the interpret path used by CPU tier-1
+    default decode — and the interpret path used by CPU tier-1
     takes any shape, so parity tests force ``impl="kernel"`` instead of
     relying on this gate. ``tests/test_tpu_lowering.py`` compiles the
     kernel for a v5e at the smallest geometry this gate admits."""

@@ -33,8 +33,7 @@ import numpy as np
 
 
 @functools.lru_cache(maxsize=None)
-def _make_fused_nll(with_bias: bool, logits_fp32: bool,
-                    const_bias: bool = False):
+def _make_fused_nll(with_bias: bool, logits_fp32: bool):
     """Build the custom-VJP per-token NLL for one (bias, dtype) variant.
 
     With ``logits_fp32`` every logits(-grad) einsum carries
@@ -74,10 +73,7 @@ def _make_fused_nll(with_bias: bool, logits_fp32: bool,
                             preferred_element_type=pet).astype(x.dtype)
             dw = jnp.einsum("nv,nd->vd", dlogits, x,
                             preferred_element_type=pet).astype(w.dtype)
-            # const_bias: the bias is a non-parameter mask (vocab padding)
-            # — skip the [N, V] reduction its cotangent would cost.
-            db = (jnp.zeros_like(b) if const_bias
-                  else dlog32.sum(axis=0).astype(b.dtype))
+            db = dlog32.sum(axis=0).astype(b.dtype)
             return dx, dw, db, np.zeros(labels.shape, jax.dtypes.float0)
     else:
         @jax.custom_vjp
@@ -106,16 +102,10 @@ def _make_fused_nll(with_bias: bool, logits_fp32: bool,
     return fused
 
 
-# Back-compat aliases for the default compute-dtype variants.
-_fused_nll = _make_fused_nll(False, False)
-_fused_nll_bias = _make_fused_nll(True, False)
-
-
 def fused_cross_entropy(x: jax.Array, w: jax.Array, labels: jax.Array,
                         ignore_index: int = -100,
                         w_transposed: bool = False,
                         bias: jax.Array = None,
-                        bias_grad: bool = True,
                         logits_fp32: bool = False) -> jax.Array:
     """Token-mean cross entropy of ``x @ w.T`` against ``labels``,
     ignoring ``ignore_index`` positions — drop-in for
@@ -136,7 +126,7 @@ def fused_cross_entropy(x: jax.Array, w: jax.Array, labels: jax.Array,
     valid = lf != ignore_index
     safe = jnp.where(valid, lf, 0).astype(jnp.int32)
     if bias is not None:
-        nll = _make_fused_nll(True, bool(logits_fp32), not bias_grad)(
+        nll = _make_fused_nll(True, bool(logits_fp32))(
             xf, w.astype(x.dtype), bias.astype(jnp.float32), safe)
     else:
         nll = _make_fused_nll(False, bool(logits_fp32))(

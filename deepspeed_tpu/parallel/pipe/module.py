@@ -137,7 +137,6 @@ def gpt_pipe_model(cfg, rng_key=None, example_batch=None,
         emb = params["embed"]
         tok = embedding_lookup(
             emb["wte"], ids,
-            matmul_grad=getattr(cfg, "embed_grad_matmul", False),
             sparse_grad_axes=getattr(cfg, "sparse_embedding_grad", None))
         x = tok.astype(cfg.dtype) + emb["wpe"][:s][None].astype(cfg.dtype)
         if rng is not None and cfg.dropout_rate > 0.0:
@@ -208,12 +207,8 @@ def gpt_pipe_model(cfg, rng_key=None, example_batch=None,
 
         h = ln_f.apply({"params": params["head"]["ln_f"]}, x)
         labels = shift_labels(batch)
-        mask = None
         if cfg.tie_embeddings:
             w, wt = params["embed"]["wte"], False
-            if getattr(cfg, "padded_vocab", cfg.vocab_size) != cfg.vocab_size:
-                from deepspeed_tpu.ops.embedding import vocab_pad_mask
-                mask = vocab_pad_mask(cfg.padded_vocab, cfg.vocab_size)
         else:
             w, wt = params["head"]["lm_head"]["kernel"], True
         if not getattr(cfg, "fused_ce", True):
@@ -222,11 +217,10 @@ def gpt_pipe_model(cfg, rng_key=None, example_batch=None,
             logits = jnp.einsum("bsd,vd->bsv" if not wt else "bsd,dv->bsv",
                                 h.astype(cfg.dtype), w.astype(cfg.dtype),
                                 preferred_element_type=jnp.float32)
-            return cross_entropy_with_ignore(logits[..., :cfg.vocab_size],
-                                             labels)
+            return cross_entropy_with_ignore(logits, labels)
         return fused_cross_entropy(
             h.astype(cfg.dtype), w.astype(cfg.dtype), labels,
-            w_transposed=wt, bias=mask, bias_grad=mask is None,
+            w_transposed=wt,
             logits_fp32=getattr(cfg, "fused_ce_fp32_logits", False))
 
     return PipeModel(embed_fn=embed_fn, block_fn=block_fn,

@@ -88,6 +88,31 @@ SERVING_METRIC_TAGS = frozenset({
 })
 
 
+def resolve_decode_attention(mode: str, tpu: bool, tiles: bool,
+                             geometry: str = "") -> str:
+    """Which program decodes (docs/SERVING.md "Decode fast path"), from
+    ``serving.decode_attention``, the platform and the kernel's gate
+    (``paged_decode_ok``). ``"gather"``: the default decode, over the flat
+    list of the batch's live blocks. ``"kernel"``: the Pallas paged
+    decode-attention kernel over the table's width; the compiled kernel
+    tiles only head_dim % 128 / block % 8 geometries, off the TPU the
+    interpreter takes any. ``"auto"``: the kernel on a TPU where it tiles,
+    else the default decode."""
+    from deepspeed_tpu.config.config import ConfigError
+
+    if mode == "kernel":
+        if tpu and not tiles:
+            raise ConfigError(
+                f"serving.decode_attention='kernel' cannot compile on "
+                f"this TPU: {geometry} does not tile the paged "
+                f"decode kernel (needs head_dim % 128 == 0 and "
+                f"block_size % 8 == 0) — use 'auto' or 'gather'")
+        return "kernel"
+    if mode == "auto" and tpu and tiles:
+        return "kernel"
+    return "gather"
+
+
 class ServeEngine:
     """Continuous-batching serving engine over an :class:`InferenceEngine`.
 
@@ -158,41 +183,23 @@ class ServeEngine:
 
         self._prefill_jit: Dict[int, Any] = {}
         # -- decode fast path (docs/SERVING.md "Decode fast path") ------
-        # "gather" (default): ONE decode program that attends over the
-        # flat list of the batch's live blocks (_dispatch_live); its
-        # speculative verify chunk, several queries a row, gathers the
-        # full table window. "auto"/"kernel" turn on window capping (the
-        # decode key axis covers only the max active length, ceiled to a
-        # power-of-two block count — O(log max_blocks) compiled variants
-        # instead of one) and, where the geometry tiles (or always,
-        # under "kernel" — the Pallas interpreter covers CPU), the paged
-        # decode-attention kernel.
+        # ONE decode program an engine: the default decode over the flat
+        # list of the batch's live blocks (_dispatch_live), or the paged
+        # kernel over the table's width (_dispatch_batch). The speculative
+        # verify chunk, several queries a row, reads the table's width
+        # either way.
         from deepspeed_tpu.ops.transformer.paged_attention import \
             paged_decode_ok
         mode = self.scfg.decode_attention
-        self._fast_path = mode != "gather"
-        # The compiled kernel only tiles head_dim % 128 / block % 8
-        # geometries; off-TPU the Pallas interpreter takes any shape.
         tpu = on_tpu()
-        tiles = not tpu or paged_decode_ok(self.model_cfg.head_dim, bs)
+        tiles = paged_decode_ok(self.model_cfg.head_dim, bs)
         geometry = f"head_dim={self.model_cfg.head_dim}, block_size={bs}"
-        if mode == "kernel":
-            if not tiles:
-                raise ConfigError(
-                    f"serving.decode_attention='kernel' cannot compile on "
-                    f"this TPU: {geometry} does not tile the paged "
-                    f"decode kernel (needs head_dim % 128 == 0 and "
-                    f"block_size % 8 == 0) — use 'auto' or 'gather'")
-            self._attn_impl = "kernel"
-        elif mode == "auto":
-            self._attn_impl = "kernel" if tpu and tiles else "gather"
-        else:
-            self._attn_impl = "gather"
+        self._attn_impl = resolve_decode_attention(mode, tpu, tiles,
+                                                   geometry)
         log_dist(f"serving: decode_attention={mode!r} resolved to "
                  f"{self._attn_impl!r} ({geometry}, platform "
                  f"{jax.devices()[0].platform})", ranks=[0])
-        # None -> the default decode; a window bucket -> its capped program
-        self._decode_jits: Dict[Any, Any] = {}
+        self._decode_jit = None
         # Length of the live-block list, in chunks: every slot at the
         # table's width, in whole runs (a block shared through the prefix
         # cache is listed once per row that reads it, so the pool's block
@@ -204,7 +211,7 @@ class ServeEngine:
         self._tail_prefill_jit: Dict[int, Any] = {}
         # -- speculative decoding ---------------------------------------
         self._spec_k = 0
-        self._spec_jits: Dict[Any, Any] = {}
+        self._spec_jit = None
         if self.scfg.spec_decode:
             self._init_speculative()
         # -- chunked prefill (docs/SERVING.md "Chunked prefill
@@ -220,7 +227,7 @@ class ServeEngine:
         self._mixed_jit = None
         self._chunk_tokens_last = 0
         if self._chunked:
-            if not tiles:
+            if tpu and not tiles:
                 # The user asked for this admission path: an engine that
                 # quietly served bucketed instead would report success
                 # for a path that never ran.
@@ -287,21 +294,16 @@ class ServeEngine:
         self.results: Dict[int, Dict[str, Any]] = {}
         # Host-side aggregates, kept regardless of telemetry (floats and
         # ints only — the SLO gauges are derived from these).
-        # ``gathered_positions``: cumulative key positions the decode
-        # program touched per row (window width x steps) — the modeled
-        # HBM-traffic evidence behind the capped fallback
-        # (tools/probe_serving_fastpath.py); ``full_positions`` is the
-        # uncapped counterfactual; both count the windowed dispatches
-        # only. ``read_positions``: what the decode programs read (the
-        # default decode: chunks walked x chunk x block size; a windowed
-        # program: table rows x columns x block size whatever is live);
+        # ``read_positions``: what the decode programs read (the
+        # default decode: chunks walked x chunk x block size; the kernel
+        # decode and a speculative round: table rows x columns x block
+        # size whatever is live);
         # ``live_positions``: of those, the positions of active rows that
         # hold KV. Their ratio is the useful share of the KV read.
         # ``live_blocks``/``chunks``: entries of the default decode's
         # live lists and the chunks it walked.
         self.stats = {"decode_steps": 0, "occupancy_sum": 0.0,
                       "slot_assignments": {}, "kernel_steps": 0,
-                      "gathered_positions": 0, "full_positions": 0,
                       "live_positions": 0, "read_positions": 0,
                       "live_blocks": 0, "chunks": 0,
                       "spec_rounds": 0, "spec_proposed": 0,
@@ -495,8 +497,7 @@ class ServeEngine:
         n_tokens = 0
         if active:
             if acc is not None:
-                n_djits = (len(self._decode_jits) + len(self._spec_jits)
-                           + int(self._mixed_jit is not None))
+                n_djits = self._decode_programs()
             if self._resil is not None:
                 n_tokens, dt_decode, active = self._resil.run_decode(
                     active, info)
@@ -504,8 +505,7 @@ class ServeEngine:
             else:
                 n_tokens, dt_decode = self._decode_round(active, info)
             if acc is not None:
-                grew = (len(self._decode_jits) + len(self._spec_jits)
-                        + int(self._mixed_jit is not None)) > n_djits
+                grew = self._decode_programs() > n_djits
                 acc.engine_mark("compile" if grew else "decode")
                 still = [s for s in active
                          if self.sched.running.get(s.slot) is s]
@@ -951,46 +951,22 @@ class ServeEngine:
             toks[s] = seq.tokens[-1]
         return bt, pos, toks
 
-    def _window_blocks(self, active: List[Sequence], chunk: int) -> int:
-        """Fast-path key-window width: enough table columns to cover the
-        longest active row's reads AND the chunk's writes, ceiled to a
-        power of two — O(log max_blocks) compiled decode variants, each
-        gathering/streaming only what some batch actually needs."""
-        need_pos = max(seq.pos for seq in active) + chunk
-        need = -(-need_pos // self.block_size)
-        wb = 1
-        while wb < need:
-            wb *= 2
-        return min(wb, self.max_blocks)
-
     def _dispatch_batch(self, active: List[Sequence], chunk: int,
                         scope: str):
-        """Dispatch prep shared by the windowed programs (the fast
-        path's decode, every speculative round): batch matrices, window
-        slicing under the fast path, the detector scope (per window
-        bucket when capped), the jit-cache key, the resolved attention
-        impl, and the gathered-positions evidence — ONE accounting for
-        both paths so they cannot drift."""
+        """Dispatch prep of the programs that read the table's width (the
+        kernel decode, every speculative round): the fault hook, the
+        batch matrices, the position counts and the detector check."""
         self._fault_hook()
-        mb = self.max_blocks
         bt, pos, toks = self._batch_inputs(active)
-        if self._fast_path:
-            wb = self._window_blocks(active, chunk)
-            bt = bt[:, :wb]
-            key, name, impl = wb, f"{scope}_w{wb}", self._attn_impl
-        else:
-            wb, key, name, impl = mb, None, scope, "gather"
-        self.stats["gathered_positions"] += wb * self.block_size
-        self.stats["full_positions"] += mb * self.block_size
-        if impl == "kernel":
+        if self._attn_impl == "kernel":
             self.stats["kernel_steps"] += 1
         # once this dispatch has written, row r holds pos[r] + chunk
         ids = self._count_positions(
             len(active), live=int(pos.sum()) + len(active) * chunk,
-            read=bt.shape[0] * wb * self.block_size)
+            read=bt.size * self.block_size)
         bt, pos, toks = jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(toks)
-        self.engine.recompile_detector.check(name, toks, pos, bt)
-        return bt, pos, toks, key, impl, ids
+        self.engine.recompile_detector.check(scope, toks, pos, bt)
+        return (bt, pos, toks), ids
 
     def _dispatch_live(self, active: List[Sequence]):
         """Dispatch prep of the default decode: the batch matrices and,
@@ -1013,6 +989,12 @@ class ServeEngine:
         self.engine.recompile_detector.check("serving.decode_step", *args)
         return args, ids
 
+    def _decode_programs(self) -> int:
+        """How many of the decode-side programs (decode, speculative,
+        mixed) this engine has built."""
+        return sum(jit is not None for jit in (
+            self._decode_jit, self._spec_jit, self._mixed_jit))
+
     def _count_positions(self, active: int, live: int, read: int,
                          **more: int) -> Dict[str, int]:
         """Add one decode dispatch to the running totals of positions read
@@ -1024,20 +1006,20 @@ class ServeEngine:
         return {"step": self._step_count, "active": active, **counts}
 
     def _decode(self, active: List[Sequence]):
-        if self._fast_path:
-            *args, key, impl, ids = self._dispatch_batch(
+        if self._attn_impl == "kernel":
+            args, ids = self._dispatch_batch(
                 active, 1, "serving.decode_step")
         else:
             args, ids = self._dispatch_live(active)
-            key, impl = None, "gather"
         bt, pos, toks, *live = args
         rng = jax.random.fold_in(self._base_key, 2 * self._step_count)
-        if key not in self._decode_jits:
-            self._decode_jits[key] = jax.jit(
-                functools.partial(self._decode_impl, attn_impl=impl),
+        if self._decode_jit is None:
+            self._decode_jit = jax.jit(
+                functools.partial(self._decode_impl,
+                                  attn_impl=self._attn_impl),
                 donate_argnums=(1,))
         with self.telemetry.span("decode_step", **ids):
-            tok_dev, logits, self._pools = self._decode_jits[key](
+            tok_dev, logits, self._pools = self._decode_jit(
                 self.engine.params, self._pools, bt, pos, toks, rng, *live)
             tok_host = np.asarray(tok_dev)       # host fetch: finish checks
         logits_host = np.asarray(logits) if self.capture_logits else None
@@ -1230,14 +1212,15 @@ class ServeEngine:
                 "decoding — a spec round has no single per-step logits "
                 "row to expose (docs/SERVING.md)")
         k = self._spec_k
-        bt, pos, toks, key, impl, ids = self._dispatch_batch(
+        (bt, pos, toks), ids = self._dispatch_batch(
             active, k + 1, "serving.spec_step")
-        if key not in self._spec_jits:
-            self._spec_jits[key] = jax.jit(
-                functools.partial(self._spec_impl, k=k, attn_impl=impl),
+        if self._spec_jit is None:
+            self._spec_jit = jax.jit(
+                functools.partial(self._spec_impl, k=k,
+                                  attn_impl=self._attn_impl),
                 donate_argnums=(1,))
         with self.telemetry.span("spec_step", k=k, **ids):
-            chunk_dev, greedy_dev, self._pools = self._spec_jits[key](
+            chunk_dev, greedy_dev, self._pools = self._spec_jit(
                 self.engine.params, self._pools, bt, pos, toks)
             chunk = np.asarray(chunk_dev)        # [B, k+1] verify inputs
             greedy = np.asarray(greedy_dev)      # [B, k+1] target argmax
@@ -1389,7 +1372,7 @@ class ServeEngine:
             ctr.inc(pre - ctr.total, step=step)
         # -- fast-path attribution (only when the piece is on: the tag
         # set a disabled engine emits stays what it was) ----------------
-        if self._fast_path and n_active:
+        if self.scfg.decode_attention != "gather" and n_active:
             reg.gauge("serving/decode_attn_kernel").set(
                 1.0 if self._attn_impl == "kernel" else 0.0, step=step)
         if self.prefix_cache is not None:

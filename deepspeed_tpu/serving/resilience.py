@@ -304,8 +304,9 @@ class ResilienceManager:
             action = "speculative decoding off"
         elif lvl == 2:
             eng._attn_impl = "gather"
-            eng._decode_jits.clear()
-            eng._spec_jits.clear()
+            eng._decode_jit = eng._spec_jit = None
+            # expected: the default decode takes the live-block list
+            eng.engine.recompile_detector.forget("serving.decode_step")
             action = "decode attention kernel -> gather"
         else:
             eng.sched.slot_cap = max(1, eng.scfg.max_batch_size // 2)
@@ -344,9 +345,8 @@ class ResilienceManager:
         eng._pools = init_paged_pools(
             eng.model_cfg, eng.scfg.kv_num_blocks, eng.block_size,
             int8=eng.scfg.int8_kv_cache, dtype=eng._dtype)
-        eng._decode_jits.clear()
-        eng._spec_jits.clear()
-        eng._mixed_jit = None   # donates the pools — old program's dead
+        # they donate the pools — the old programs are dead
+        eng._decode_jit = eng._spec_jit = eng._mixed_jit = None
         replayed = requeued = 0
         for seq in live:
             if self._replay(seq):

@@ -107,8 +107,9 @@ class RecompileDetector:
         as the expected one-time compile, not a retrace. For EXPECTED
         recompilations only — today that is the in-process elastic world
         change (resilience/elastic.py), whose rebuilt step functions MUST
-        recompile; warning about them would train operators to ignore the
-        detector."""
+        recompile, and the serving degradation ladder's swap of the kernel
+        decode for the default one; warning about them would train
+        operators to ignore the detector."""
         with self._lock:
             self._seen.pop(fn_name, None)
 

@@ -221,7 +221,7 @@ class TestChunkedAdmission:
         assert det.retraces("serving.mixed_step") == 0
         assert len(srv._prefill_jit) == 0
         assert len(srv._tail_prefill_jit) == 0
-        assert len(srv._decode_jits) == 0
+        assert srv._decode_jit is None
         # vs the bucketed engine, which pays per-bucket programs
         bsrv = _serve(model, params)
         _run_trace(bsrv, cfg)

@@ -151,94 +151,6 @@ class TestEngineIntegration:
         assert losses[-1] < losses[0] - 0.5, losses
 
 
-class TestGPTMFULevers:
-    """The two PROFILE.md r3 GPT-2 levers (round-3 VERDICT task 4):
-    vocab padding to an MXU tile multiple must be numerically INVISIBLE
-    (pad logits masked out of the CE), and the one-hot-matmul embedding
-    gradient must match XLA's scatter-add."""
-
-    def _batch(self, rng, v, bs=4, seq=32):
-        return {"input_ids": rng.integers(0, v, (bs, seq), dtype=np.int32)}
-
-    def test_vocab_padding_exact_parity(self):
-        from deepspeed_tpu.models.gpt import make_gpt
-
-        v = 500  # not a multiple of 128 -> pads to 512
-        m_u, c_u = make_gpt("tiny", vocab_size=v, dropout_rate=0.0,
-                            dtype=jnp.float32)
-        m_p, c_p = make_gpt("tiny", vocab_size=v, dropout_rate=0.0,
-                            dtype=jnp.float32, vocab_pad_multiple=128)
-        assert c_p.padded_vocab == 512
-        rng = np.random.default_rng(0)
-        batch = self._batch(rng, v)
-        pu = m_u.init({"params": jax.random.PRNGKey(0),
-                       "dropout": jax.random.PRNGKey(1)}, batch)["params"]
-        # Build the padded model's params by zero-padding the wte rows.
-        pp = dict(pu)
-        pp["wte"] = jnp.pad(pu["wte"], ((0, 512 - v), (0, 0)))
-
-        def loss_u(p):
-            return m_u.apply({"params": p}, batch, deterministic=True)["loss"]
-
-        def loss_p(p):
-            return m_p.apply({"params": p}, batch, deterministic=True)["loss"]
-
-        (lu, gu) = jax.value_and_grad(loss_u)(pu)
-        (lp, gp) = jax.value_and_grad(loss_p)(pp)
-        np.testing.assert_allclose(float(lu), float(lp), rtol=1e-6)
-        # real rows match; pad rows get exactly zero gradient
-        np.testing.assert_allclose(np.asarray(gp["wte"][:v]),
-                                   np.asarray(gu["wte"]), rtol=1e-5,
-                                   atol=1e-7)
-        np.testing.assert_array_equal(np.asarray(gp["wte"][v:]), 0.0)
-        # logits output stays [.., vocab_size] and matches
-        ou = m_u.apply({"params": pu}, batch, deterministic=True)["logits"]
-        op = m_p.apply({"params": pp}, batch, deterministic=True)["logits"]
-        assert op.shape[-1] == v
-        np.testing.assert_allclose(np.asarray(ou), np.asarray(op),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_embed_grad_matmul_parity(self):
-        from deepspeed_tpu.models.gpt import make_gpt
-
-        m_s, _ = make_gpt("tiny", dropout_rate=0.0, dtype=jnp.float32)
-        m_m, cfg = make_gpt("tiny", dropout_rate=0.0, dtype=jnp.float32,
-                            embed_grad_matmul=True)
-        rng = np.random.default_rng(1)
-        batch = self._batch(rng, cfg.vocab_size)
-        p = m_s.init({"params": jax.random.PRNGKey(0),
-                      "dropout": jax.random.PRNGKey(1)}, batch)["params"]
-        gs = jax.grad(lambda p: m_s.apply({"params": p}, batch,
-                                          deterministic=True)["loss"])(p)
-        gm = jax.grad(lambda p: m_m.apply({"params": p}, batch,
-                                          deterministic=True)["loss"])(p)
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), gs, gm)
-
-    def test_both_levers_train(self, eight_devices):
-        from deepspeed_tpu.models.gpt import make_gpt
-
-        model, cfg = make_gpt("tiny", vocab_size=500, dropout_rate=0.0,
-                              vocab_pad_multiple=128, embed_grad_matmul=True)
-        rng = np.random.default_rng(2)
-        batches = {"input_ids": rng.integers(0, 500, (2, 8, 32),
-                                             dtype=np.int32)}
-        params = model.init(
-            {"params": jax.random.PRNGKey(0),
-             "dropout": jax.random.PRNGKey(1)},
-            {"input_ids": batches["input_ids"][0]})["params"]
-        engine, _, _, _ = deepspeed_tpu.initialize(
-            model=model, params=params,
-            config={"train_micro_batch_size_per_gpu": 1,
-                    "gradient_accumulation_steps": 2,
-                    "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
-                    "zero_optimization": {"stage": 2},
-                    "bf16": {"enabled": True}})
-        losses = [float(engine.train_batch(batches)) for _ in range(8)]
-        assert losses[-1] < losses[0] - 0.3, losses
-
-
 class TestHashDropout:
     """Counter-hash dropout (ops/dropout.py; reference
     dropout_kernels.cu's fused-dropout economy)."""

@@ -392,7 +392,7 @@ def test_the_serving_programs_name_their_parts(gpt_setup):
     srv = serve_engine(gpt_setup)
     srv.submit([1, 2, 3, 4, 5], 3)
     srv.run_until_complete()
-    (decode,) = srv._decode_jits.values()
+    decode = srv._decode_jit
     nb, mb = srv.scfg.max_batch_size, srv.max_blocks
     text = decode.lower(
         srv.engine.params, srv._pools, jnp.zeros((nb, mb), jnp.int32),
@@ -428,7 +428,6 @@ KERNELS = {
     "ops/transformer/paged_attention.py": ["paged_attention"],
     "ops/sparse_attention/sparse_attention.py": [
         "sparse_attn_fwd", "sparse_attn_bwd_dq", "sparse_attn_bwd_dkv"],
-    "ops/transformer/fused.py": ["fused_ln_fwd", "fused_ln_bwd"],
     "ops/adam/fused_update.py": ["fused_adam"],
 }
 

@@ -8,9 +8,10 @@ The acceptance gates:
   partial last blocks, scrambled block tables and all-scratch (block 0)
   inactive rows — and within RTNE tolerance for int8 pools (dequantized
   in-kernel);
-- every fast-path configuration (kernel, capped gather, prefix cache,
-  speculative, all together) produces outputs **token-identical** to the
-  fully-off engine on a mixed continuous-batching trace;
+- every fast-path configuration (kernel, prefix cache, speculative, all
+  together) produces outputs **token-identical** to the fully-off engine
+  on a mixed continuous-batching trace, and ``auto`` where the kernel
+  does not tile IS the fully-off decode;
 - prefix COW survives youngest-first preemption (the evicted request
   re-admits warm and still finishes with correct tokens), and refcounts
   leak nothing: after ``run_until_complete`` the pool holds exactly the
@@ -41,6 +42,7 @@ from deepspeed_tpu.ops.transformer.attention import xla_attention
 from deepspeed_tpu.ops.transformer.paged_attention import (
     paged_decode_attention, paged_decode_ok)
 from deepspeed_tpu.serving import PagedLayerCache, ServeEngine
+from deepspeed_tpu.serving.engine import resolve_decode_attention
 from deepspeed_tpu.serving.kv_cache import (_quant_tokens, init_paged_pools,
                                             live_block_list)
 from deepspeed_tpu.telemetry import (InMemorySink, MetricsRegistry,
@@ -89,6 +91,14 @@ def _run_trace(srv, cfg, seed=7):
     rids = [srv.submit(p, n) for p, (_, n) in zip(prompts, TRACE)]
     res = srv.run_until_complete()
     return prompts, [res[r]["tokens"] for r in rids]
+
+
+@pytest.fixture(scope="module")
+def off_run(gpt_setup):
+    """The mixed trace with every fast-path piece off: ``(tokens, stats)``."""
+    model, cfg, params = gpt_setup
+    srv = _serve(model, params)
+    return _run_trace(srv, cfg)[1], srv.stats
 
 
 # ---------------------------------------------------------------------------
@@ -369,8 +379,8 @@ class TestLiveBlockDecode:
         assert run == 16 or srv.stats["chunks"] > 2 * dispatches
         if "int8_kv_cache" in over:
             # a quantized cache may flip a near-tie against generate();
-            # the oracle is the same pool through update() and the window
-            ref = _serve(model, params, decode_attention="auto", **over)
+            # the oracle is the same pool through the paged kernel
+            ref = _serve(model, params, decode_attention="kernel", **over)
             want = [ref.submit(p, n) for p, (_, n) in zip(prompts, TRACE)]
             ref.run_until_complete()
             want = [ref.results[r]["tokens"] for r in want]
@@ -384,48 +394,71 @@ class TestLiveBlockDecode:
         det = srv.engine.recompile_detector
         assert det.compiles("serving.decode_step") == 1
         assert det.retraces("serving.decode_step") == 0
-        assert len(srv._decode_jits) == 1
+        assert srv._decode_programs() == 1
 
 
 # ---------------------------------------------------------------------------
-# Engine-level token identity + window capping
+# Engine-level token identity
 # ---------------------------------------------------------------------------
+
+# (on a TPU?, does the kernel's gate admit the geometry?) -> what decodes
+RESOLVES = {
+    "gather": {(False, False): "gather", (True, True): "gather",
+               (True, False): "gather"},
+    "kernel": {(False, False): "kernel", (True, True): "kernel",
+               (True, False): ConfigError},
+    "auto": {(False, False): "gather", (True, True): "kernel",
+             (True, False): "gather"}}
+
+
+@pytest.mark.parametrize("tpu,tiles", list(RESOLVES["auto"]),
+                         ids=["off-tpu", "tpu-tiles", "tpu-no-tile"])
+@pytest.mark.parametrize("mode", list(RESOLVES))
+def test_decode_attention_resolves_by_its_table(mode, tpu, tiles):
+    want = RESOLVES[mode][tpu, tiles]
+    if want is ConfigError:
+        with pytest.raises(ConfigError, match="decode_attention='kernel'"):
+            resolve_decode_attention(mode, tpu, tiles, "head_dim=64")
+    else:
+        assert resolve_decode_attention(mode, tpu, tiles) == want
+        # off the TPU the interpreter takes any geometry: the gate is moot
+        assert tpu or resolve_decode_attention(mode, tpu, True) == want
+
 
 class TestFastPathTokenIdentity:
-    def test_every_configuration_matches_off(self, gpt_setup):
+    @pytest.mark.parametrize("over", [
+        {"decode_attention": "kernel"},
+        {"prefix_cache": True},
+        {"spec_decode": True, "spec_k": 3},
+        {"decode_attention": "kernel", "prefix_cache": True,
+         "spec_decode": True, "spec_k": 3}],
+        ids=["kernel", "prefix_cache", "spec_decode", "all-three"])
+    def test_every_configuration_matches_off(self, gpt_setup, off_run,
+                                             over):
         model, cfg, params = gpt_setup
-        srv_off = _serve(model, params)
-        _, base = _run_trace(srv_off, cfg)
-        for over in ({"decode_attention": "kernel"},
-                     {"decode_attention": "auto"},
-                     {"prefix_cache": True},
-                     {"spec_decode": True, "spec_k": 3},
-                     {"decode_attention": "kernel", "prefix_cache": True,
-                      "spec_decode": True, "spec_k": 3}):
-            srv = _serve(model, params, **over)
-            _, got = _run_trace(srv, cfg)
-            assert got == base, over
+        srv = _serve(model, params, **over)
+        _, got = _run_trace(srv, cfg)
+        assert got == off_run[0]
 
-    def test_capped_gather_shrinks_window(self, gpt_setup):
-        """The capped-fallback satellite: under auto (no TPU -> capped
-        gather) the decode key window tracks the max ACTIVE length, so
-        the modeled gathered positions drop well below the full-window
-        program's on the same trace."""
+    def test_auto_where_the_kernel_does_not_tile_is_the_default_decode(
+            self, gpt_setup, off_run):
+        """``auto`` off the TPU (and on one whose geometry does not tile)
+        is the default decode itself: ONE program, compiled once under the
+        only decode scope the detector holds, reading what a ``gather``
+        engine reads, and the same tokens."""
         model, cfg, params = gpt_setup
+        off_tokens, off_stats = off_run
         srv = _serve(model, params, decode_attention="auto")
-        _run_trace(srv, cfg)
-        assert srv.stats["full_positions"] == (
-            srv.stats["decode_steps"] * srv.max_blocks * srv.block_size)
-        assert srv.stats["gathered_positions"] < \
-            0.7 * srv.stats["full_positions"]
-        # each window bucket is its own expected-first-compile scope —
-        # no retraces under any of them
+        _, got = _run_trace(srv, cfg)
+        assert got == off_tokens
         det = srv.engine.recompile_detector
-        scopes = [f for f in det.stats
-                  if f.startswith("serving.decode_step_w")]
-        assert scopes, det.stats
-        for f in scopes:
-            assert det.compiles(f) == 1 and det.retraces(f) == 0
+        assert [f for f in det.stats if "decode_step" in f] == [
+            "serving.decode_step"]
+        assert det.compiles("serving.decode_step") == 1
+        assert det.retraces("serving.decode_step") == 0
+        assert srv._decode_programs() == 1
+        assert srv.stats["read_positions"] == off_stats["read_positions"] > 0
+        assert srv.stats["chunks"] == off_stats["chunks"] > 0
 
     def test_kernel_gauge_emitted(self, gpt_setup):
         model, cfg, params = gpt_setup
@@ -607,7 +640,7 @@ class TestOffContract:
         srv = _serve(model, params)
         srv.submit([1, 2, 3, 4, 5], 3)
         srv.run_until_complete()
-        (decode,) = srv._decode_jits.values()     # what the engine runs
+        decode = srv._decode_jit                  # what the engine runs
         nb, mb, bs = srv.scfg.max_batch_size, srv.max_blocks, srv.block_size
         shape = (srv._live_chunks, srv.LIVE_CHUNK_RUNS,
                  srv.LIVE_RUN_BLOCKS + 2)

@@ -1,6 +1,6 @@
-"""Acceptance probe: the paged-KV decode fast path is correct and cheaper.
+"""Acceptance probe: the paged-KV decode fast path is correct.
 
-Three claims of docs/SERVING.md "Decode fast path", measured on a tiny
+Two claims of docs/SERVING.md "Decode fast path", checked on a tiny
 GPT over the CPU backend (Pallas interpreter for the kernel):
 
 1. **Token identity** — the same mixed request trace produces
@@ -12,11 +12,6 @@ GPT over the CPU backend (Pallas interpreter for the kernel):
 2. **Prefix reuse works** — a shared-prompt-head workload drives
    ``serving/prefix_hits`` above zero and adopted blocks above zero, and
    released/cleared refcounts drain the pool completely (leak check).
-3. **Capped fallback shrinks gathered bytes** — under
-   ``decode_attention: auto`` (no TPU -> capped gather), the decode
-   program's key window covers the max ACTIVE length instead of the full
-   ``max_blocks`` table: the modeled gathered-positions total drops
-   measurably below the full window's on the same trace.
 
 Run: JAX_PLATFORMS=cpu python tools/probe_serving_fastpath.py [--selftest]
 (tier-1 via tests/test_serving_fastpath.py)
@@ -80,7 +75,7 @@ def main(argv=None) -> int:
     rows = [("off (gather)", base_srv)]
     for name, over in (
             ("kernel", {"decode_attention": "kernel"}),
-            ("auto (capped gather)", {"decode_attention": "auto"}),
+            ("auto (no TPU: off)", {"decode_attention": "auto"}),
             ("prefix_cache", {"prefix_cache": True}),
             ("speculative k=3", {"spec_decode": True, "spec_k": 3}),
             ("all on", {"decode_attention": "kernel", "prefix_cache": True,
@@ -116,18 +111,6 @@ def main(argv=None) -> int:
     assert srv.pool.used_blocks == 0, "pool not empty after cache clear"
     print(f"prefix reuse: {hits} hits, {reused} blocks adopted, pool "
           f"drains to 0 after clear")
-
-    # -- 3. capped fallback gathers measurably less ---------------------
-    capped = dict(rows)["auto (capped gather)"].stats
-    assert capped["decode_steps"] == base_srv.stats["decode_steps"], \
-        "traces not comparable"
-    ratio = capped["gathered_positions"] / max(1, capped["full_positions"])
-    print(f"capped fallback: {capped['gathered_positions']} vs "
-          f"{capped['full_positions']} gathered key positions a row "
-          f"({ratio:.2f}x)")
-    assert ratio < 0.7, (
-        f"capped gather should cut gathered positions well below the "
-        f"full window on this trace, measured {ratio:.2f}x")
 
     if selftest:
         print("selftest ok")
