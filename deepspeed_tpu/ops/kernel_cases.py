@@ -47,12 +47,14 @@ def _fwd_bwd(f: Callable, n_diff: int) -> Callable:
 
 
 # -- flash attention (ops/transformer/flash_attention.py) ------------------
-def _flash_case(head_dim: int, dtype) -> KernelCase:
+def _flash_case(head_dim: int, dtype, seq: int = 512,
+                heads: int = 2) -> KernelCase:
     from deepspeed_tpu.ops.transformer.attention import xla_attention
     from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
 
-    # seq 512 is the auto-dispatch crossover (attention.PALLAS_MIN_SEQ_K).
-    shape = (1, 512, 2, head_dim)
+    # seq 512 is the auto-dispatch crossover (attention.PALLAS_MIN_SEQ_K):
+    # one block. The longer cases walk the causal triangle in several.
+    shape = (1, seq, heads, head_dim)
 
     def make_args(rng):
         return tuple(_normal(rng, shape, dtype) for _ in range(4))
@@ -62,7 +64,8 @@ def _flash_case(head_dim: int, dtype) -> KernelCase:
             q, k, v, causal=True, interpret=interpret), 3)(*args)
 
     return KernelCase(
-        f"flash_attention fwd+bwd d{head_dim} {jnp.dtype(dtype).name}",
+        f"flash_attention fwd+bwd d{head_dim} {jnp.dtype(dtype).name}"
+        + ("" if seq == 512 else f" seq {seq}"),
         make_args, run,
         _fwd_bwd(lambda q, k, v: xla_attention(q, k, v, causal=True), 3))
 
@@ -218,6 +221,9 @@ def kernel_cases() -> List[KernelCase]:
         _flash_case(64, jnp.float32), _flash_case(128, jnp.float32),
         _flash_case(64, jnp.bfloat16),       # the trainer's dtype
         _flash_case(256, jnp.bfloat16),      # latent attention's head size
+        # the two training cells' shapes in miniature batch, default blocks
+        _flash_case(64, jnp.bfloat16, seq=1024),
+        _flash_case(256, jnp.bfloat16, seq=4096, heads=1),
         _sparse_case(64, masked=False), _sparse_case(128, masked=False),
         _sparse_case(128, masked=True),      # masks need block % 128 == 0
         _fused_adam_case(cast=False), _fused_adam_case(cast=True),

@@ -90,8 +90,10 @@ def _as_kv_mask(mask, batch, sk):
     return None
 
 
-# Auto-mode crossover, measured on a real v5e (8-layer BERT-large-shaped
-# stacks, fwd+bwd, with the flash kernel's tuned 512x1024 blocks):
+# Auto-mode crossover. HISTORY, not re-measured on current code: taken on a
+# v5e on 2026-07-30/31 (8-layer BERT-large-shaped stacks, fwd+bwd, NON-causal,
+# the flash kernels at their 512x1024 blocks), before this round's benchmark
+# existed and through another harness:
 #   seq 128:  XLA  97 vs pallas 86 TFLOP/s  -> XLA
 #   seq 512:  XLA  79 vs pallas 87          -> pallas
 #   seq 1024: XLA  64 vs pallas 96          -> pallas
@@ -103,7 +105,13 @@ def _as_kv_mask(mask, batch, sk):
 # dropout ON the gap widens further (the xla path adds bernoulli + an
 # [S,S] mask; in-kernel hash dropout costs ~2%): measured r3, fwd+bwd
 # 8-layer stacks — seq 512: 19.7 vs 32.8 ms; 1024: 23.8 vs 56.1;
-# 2048: 25.9 vs 101.3 (PROFILE.md). Overridable with impl="pallas"/"xla".
+# 2048: 25.9 vs 101.3 (PROFILE.md, history too). The non-causal kernels are
+# what they were then; the CAUSAL walk and its blocks changed since
+# (flash_attention.py's header has what was measured for them), which can
+# only have moved a causal crossover DOWN: 512 stays the gate until a cell
+# below it says otherwise. What the benchmark holds today: at 128 keys the
+# XLA path (bert-large-train-s128), at 1024 and 4096 causal keys the kernels
+# (PERF.md section 5). Overridable with impl="pallas"/"xla".
 PALLAS_MIN_SEQ_K = 512
 
 
@@ -224,12 +232,24 @@ def _padded_flash(q, k, v, *, causal, kv_mask, softmax_scale, dropout_rate,
 
 
 @functools.lru_cache(maxsize=None)
-def _log_auto_choice(impl: str, sq: int, sk: int, head_dim: int) -> None:
+def _log_auto_choice(impl: str, causal: bool, sq: int, sk: int,
+                     head_dim: int) -> None:
     """Say once per shape which implementation ``impl="auto"`` resolved to
     (the cache is the once) — a run that was meant to use the flash kernel
-    and landed on the XLA path must be visible in its log."""
-    logger.info(f"attention impl=auto -> {impl} "
-                f"(seq_q={sq}, seq_k={sk}, head_dim={head_dim})")
+    and landed on the XLA path must be visible in its log. For the causal
+    flash kernels also how much of the score square their forward walk
+    visits, and how much of that it masks."""
+    walk = ""
+    if impl == "pallas" and causal:
+        from deepspeed_tpu.ops.transformer.flash_attention import (
+            causal_walk, fitted_blocks)
+
+        bq, bk = fitted_blocks(causal, sq, sk, head_dim)
+        w = causal_walk(sq, sk, bq, bk)
+        walk = (f"; causal walk in {bq}x{bk}: blocks visited {w.visited} of "
+                f"{w.total}, {w.crossed} masked")
+    logger.info(f"attention impl=auto -> {impl} (seq_q={sq}, seq_k={sk}, "
+                f"head_dim={head_dim}{walk})")
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -247,7 +267,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if impl == "auto":
         impl = ("pallas" if on_tpu() and _pallas_ok(
             q, k, bias, mask, dropout_active) else "xla")
-        _log_auto_choice(impl, q.shape[1], k.shape[1], q.shape[-1])
+        _log_auto_choice(impl, causal, q.shape[1], k.shape[1], q.shape[-1])
     if impl == "pallas_pad":
         kv_mask = _as_kv_mask(mask, q.shape[0], k.shape[1])
         if bias is not None or (mask is not None and kv_mask is None):

@@ -3,9 +3,11 @@ reference's fused attention CUDA path (``csrc/transformer/softmax_kernels.cu``
 + the strided-batch attention GEMMs in ``ds_transformer_cuda.cpp:147``) with
 O(seq) memory instead of materialising the [S, S] score matrix.
 
-Forward: one kernel per (batch·head, q-block): K/V stream through VMEM in
-kv-blocks while running max / normaliser / fp32 accumulator live in scratch
-(online softmax). Saves the per-row logsumexp for the backward pass.
+Forward: one kernel per (batch·head, q-block): that head's K/V sit whole in
+VMEM and are walked in kv-blocks while running max / normaliser / fp32
+accumulator are carried (online softmax). Saves the per-row logsumexp for
+the backward pass. Under ``causal=True`` the walk covers the causal triangle
+only (``causal_walk``).
 
 Backward: custom VJP with two kernels — dq over q-blocks, dk/dv over
 kv-blocks — using the standard flash-attention recomputation identity
@@ -18,7 +20,7 @@ strategy of reference tests/unit/test_cuda_forward.py).
 """
 
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,22 +29,47 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.utils.platform import on_tpu
 
-# Measured on v5e at seq 4096 (fwd+bwd, d=64): 128x128 blocks run at
-# ~1 TF/s (grid/stream overhead dominates) while 512x1024 reaches ~31 TF/s
-# — large blocks keep the MXU fed and amortize the per-program K/V stream.
-# VMEM check (fp32): q bq·d + k/v 2·bk·d + score block bq·bk —
-#   d=64:  (32K + 131K + 524K)·4 B ≈ 2.7 MB
-#   d=128: (65K + 262K + 524K)·4 B ≈ 3.4 MB
-#   d=256: (131K + 524K + 524K)·4 B ≈ 4.7 MB
-# all comfortably inside 16 MB, so the 512x1024 default serves every
-# admitted head_dim (r2 VERDICT weak #8: no per-head-dim table needed —
-# the score block dominates and is head_dim-independent). Both are
-# clamped to the actual sequence lengths for short inputs; sequences that
-# are 128-multiples but lack large 128-multiple divisors (e.g. 640)
-# degrade to small blocks — pad such inputs to a friendlier length
-# upstream (pad_to_block_size) if they are hot.
+# Blocks. K and V (dkv: Q, dO, lse, delta) stay whole in VMEM per (b, h), so
+# a smaller block on the looped side adds loop trips, not DMAs; a smaller
+# block on the grid side adds grid steps. VMEM at 512 x 1024 (fp32): q bq·d
+# + k/v 2·bk·d + score block bq·bk = 2.7 / 3.4 / 4.7 MB at d = 64 / 128 /
+# 256, inside the 16 MB default; `_vmem_params` raises the cap for the long
+# backward.
+#
+# causal=False runs 512 x 1024 over the whole rectangle, as it always has
+# (HISTORY, 2026-07-30, not re-measured: 128 x 128 ran at ~1 TFLOP/s, 512 x
+# 1024 at ~31, fwd+bwd at seq 4096, d=64). Its lowered text is pinned.
+#
+# causal=True visits only the blocks at or under the diagonal and masks only
+# those it crosses (`causal_walk`), in CAUSAL_BLOCK x CAUSAL_BLOCK blocks.
+# Measured on a v5e (PR 29, tools/probe_flash_blocks.py; median device us of
+# one call, bf16, fwd / dq / dkv), at the two training cells' shapes:
+#
+#   block_q x block_k            bh 64, seq 1024, d 64    bh 20, seq 4096, d 256
+#   before PR 29: 512 x 1024 (2/2, 20/32), every block masked and in a
+#   loop                             342 / 317 / 444      1526 / 1841 / 2663
+#   512 x 512  (visits 3/4, 36/64)   236 / 251 / 340      1393 / 1720 / 2535
+#   256 x 256  (10/16, 136/256)      367 / 336 / 480      1656 / 1841 / 2794
+#   1024 x 1024 (1/1, 10/16)         201 / 273 / 374      1446 / 1797 / 2493
+#   with every run of the walk a loop (the first form tried):
+#   512 x 512                        324 / 268 / 387      1558 / 1785 / 2628
+#   512 x 256                        451 / 329 / 438      1793 / 1901 / 2686
+#   256 x 512                        307 / 287 / 468      1523 / 1797 / 2936
+#
+# What the table says: (1) the time does not follow the score elements
+# alone. The same block inside a `fori_loop` of one trip (or under a `cond`:
+# tried, no better) costs 1.3-1.7 times what it costs written out, and a
+# grid step about 1 us: so the one diagonal block of a program is written
+# out (`CausalWalk`: a Python 1 for its count), which is worth as much as
+# the blocks not visited (512 x 512: 979 -> 827 us for the three at d 64),
+# and 256-wide blocks lose although they visit least. (2) 512 x 512 is the
+# best pair for the three kernels together at both head sizes, so the rule
+# does not read head_dim; the forward alone prefers 1024 x 1024 at seq 1024.
+# (3) A length with no 128-multiple divisor from 256 to 512 (640, 896) runs
+# as one block (358 and 662 us for the three; 1,096 and 2,080 in 128 x 128).
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
+CAUSAL_BLOCK = 512
 LANES = 128   # TPU lane width: per-row scalars (lse/delta) are broadcast
               # across the lane dim so their blocks satisfy (8,128) tiling
 NEG_INF = -1e30
@@ -57,6 +84,134 @@ def fit_block(block: int, seq: int) -> int:
         while seq % block:
             block -= 128
     return block
+
+
+def default_blocks(causal: bool, seq_q: int, seq_k: int,
+                   head_dim: int) -> Tuple[int, int]:
+    """``(block_q, block_k)`` when the caller names none: a function of
+    what the call can see (the header has the measurements behind it).
+    ``head_dim`` is seen and not read: 64 and 256 chose the same blocks."""
+    if not causal:
+        return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
+
+    def side(seq):
+        # a length with no 128-multiple divisor from 256 to 512 (640, 896)
+        # runs as one block, as it did: 128-wide blocks are 2-3 times slower
+        block = fit_block(CAUSAL_BLOCK, seq)
+        return block if block >= 256 else fit_block(DEFAULT_BLOCK_K, seq)
+
+    return side(seq_q), side(seq_k)
+
+
+def fitted_blocks(causal: bool, seq_q: int, seq_k: int, head_dim: int,
+                  block_q: Optional[int] = None,
+                  block_k: Optional[int] = None) -> Tuple[int, int]:
+    """The blocks a call runs with: the caller's or the default, each fitted
+    to its sequence length."""
+    auto_q, auto_k = default_blocks(causal, seq_q, seq_k, head_dim)
+    return (fit_block(auto_q if block_q is None else block_q, seq_q),
+            fit_block(auto_k if block_k is None else block_k, seq_k))
+
+
+class CausalWalk(NamedTuple):
+    """Which score blocks the three kernels visit under ``causal=True``.
+
+    ``kv_runs(qi)`` is a q-block's walk over the kv-blocks (forward, dq),
+    ``q_runs(ki)`` a kv-block's walk over the q-blocks (dkv): each a tuple
+    of ``(first, count, masked)`` runs in ascending order, ``qi`` / ``ki`` a
+    Python int or the kernel's traced ``program_id``. Where the diagonal
+    crosses exactly one block of every block of a side (``block_q ==
+    block_k`` on aligned lengths: every default), the masked run's count is
+    the Python int 1, and the kernels write that block out with no loop
+    round it. ``visited`` / ``crossed`` / ``total`` count the blocks of the
+    whole walk (the same from either side)."""
+    kv_runs: Callable
+    q_runs: Callable
+    visited: int
+    crossed: int
+    total: int
+
+
+def _blocks_below(n, block: int, num_blocks: int):
+    """``floor(n / block)`` clipped to ``[0, num_blocks]``. A negative ``n``
+    clips to 0 whichever way the division rounds, so Python's ``//`` and the
+    kernel's truncating ``lax.div`` agree."""
+    if isinstance(n, int):
+        return min(max(n // block, 0), num_blocks)
+    return jnp.clip(jax.lax.div(n, block), 0, num_blocks)
+
+
+def causal_walk(seq_q: int, seq_k: int, block_q: int,
+                block_k: int) -> CausalWalk:
+    """The causal triangle in blocks. Causality is bottom-right aligned
+    (``xla_attention``'s ``tril`` with ``k = seq_k - seq_q``): query row i
+    attends keys ``j <= i + offset``. A block with no such pair is not
+    visited; one where every pair is such needs no mask (``masked`` False);
+    the diagonal crosses the rest."""
+    num_q, num_kv = seq_q // block_q, seq_k // block_k
+    offset = seq_k - seq_q
+
+    def kv_edges(qi):       # [0, full) wholly under, [full, end) crossed
+        # wholly under: the block's last key <= its first row + offset
+        full = _blocks_below(qi * block_q + offset + 1, block_k, num_kv)
+        # visited: the block's first key <= its last row + offset
+        end = _blocks_below((qi + 1) * block_q + offset + block_k - 1,
+                            block_k, num_kv)
+        return full, end
+
+    def q_edges(ki):        # [start, full) crossed, [full, num_q) wholly under
+        start = _blocks_below(ki * block_k - offset, block_q, num_q)
+        full = _blocks_below((ki + 1) * block_k - offset + block_q - 2,
+                             block_q, num_q)
+        return start, full
+
+    kv = [kv_edges(qi) for qi in range(num_q)]
+    kv_one = all(end - full == 1 for full, end in kv)
+    q_one = all(full - start == 1
+                for start, full in map(q_edges, range(num_kv)))
+
+    def kv_runs(qi):
+        full, end = kv_edges(qi)
+        return (0, full, False), (full, 1 if kv_one else end - full, True)
+
+    def q_runs(ki):
+        start, full = q_edges(ki)
+        return ((start, 1 if q_one else full - start, True),
+                (full, num_q - full, False))
+
+    return CausalWalk(kv_runs, q_runs,
+                      visited=sum(end for _, end in kv),
+                      crossed=sum(end - full for full, end in kv),
+                      total=num_q * num_kv)
+
+
+def _walk(body, carry, runs):
+    """``body(i, carry, masked)`` over each ``(first, count, masked)`` run,
+    in order: a loop, or straight-line code where the count is a Python int
+    (the header has what a loop round one block costs)."""
+    for first, count, masked in runs:
+        step = functools.partial(body, masked=masked)
+        if isinstance(count, int):
+            for i in range(count):
+                carry = step(first + i, carry)
+        else:
+            carry = jax.lax.fori_loop(first, first + count, step, carry)
+    return carry
+
+
+def _needs_coords(causal: bool, masked: bool, dropout_rate: float) -> bool:
+    """Whether a block's body builds its elements' coordinates: for the
+    causal mask and for the dropout hash. The non-causal walk builds them
+    too and, without dropout, reads them nowhere (the compiler drops them):
+    its lowered text stays what it was before the causal walk changed."""
+    return masked or dropout_rate > 0.0 or not causal
+
+
+def _block_coords(qi, ki, block_q: int, block_k: int):
+    """Absolute (row, column) of every element of score block (qi, ki)."""
+    shape = (block_q, block_k)
+    return (qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -121,28 +276,16 @@ def _fwd_kernel(seed_ref, *refs, causal: bool, scale: float, block_k: int,
     d = q_ref.shape[2]
     q = q_ref[0].astype(jnp.float32) * scale          # [bq, d]
 
-    num_kv = seq_k // block_k
-    # Bottom-right aligned causality (matches xla_attention's tril offset
-    # k = sk - sq): query row i may attend keys j <= i + offset.
-    offset = seq_k - seq_q
-    if causal:
-        hi = jax.lax.div((qi + 1) * block_q + offset + block_k - 1, block_k)
-        hi = jnp.clip(hi, 0, num_kv)
-    else:
-        hi = num_kv
-
-    def body(ki, carry):
+    def body(ki, carry, masked):
         m_prev, l_prev, acc = carry
         k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [bq, bk]
-        q_idx = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_idx = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        if causal:
-            s = jnp.where(q_idx + offset >= k_idx, s, NEG_INF)
+        if _needs_coords(causal, masked, dropout_rate):
+            q_idx, k_idx = _block_coords(qi, ki, block_q, block_k)
+        if masked:
+            s = jnp.where(q_idx + (seq_k - seq_q) >= k_idx, s, NEG_INF)
         m_cur = jnp.max(s, axis=1)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new[:, None])
@@ -171,7 +314,12 @@ def _fwd_kernel(seed_ref, *refs, causal: bool, scale: float, block_k: int,
     init = (jnp.full((block_q,), NEG_INF, jnp.float32),
             jnp.zeros((block_q,), jnp.float32),
             jnp.zeros((block_q, d), jnp.float32))
-    m, l, acc = jax.lax.fori_loop(0, hi, body, init)
+    if causal:
+        m, l, acc = _walk(body, init, causal_walk(
+            seq_q, seq_k, block_q, block_k).kv_runs(qi))
+    else:
+        m, l, acc = jax.lax.fori_loop(
+            0, seq_k // block_k, functools.partial(body, masked=False), init)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     lse = m + jnp.log(l_safe)
@@ -245,25 +393,15 @@ def _bwd_dq_kernel(seed_ref, *refs, causal: bool, scale: float, block_k: int,
     lse = lse_ref[0, :, 0]
     delta = delta_ref[0, :, 0]
 
-    num_kv = seq_k // block_k
-    offset = seq_k - seq_q
-    if causal:
-        hi = jnp.clip(jax.lax.div(
-            (qi + 1) * block_q + offset + block_k - 1, block_k), 0, num_kv)
-    else:
-        hi = num_kv
-
-    def body(ki, dq):
+    def body(ki, dq, masked):
         k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        q_idx = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_idx = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        if causal:
-            s = jnp.where(q_idx + offset >= k_idx, s, NEG_INF)
+        if _needs_coords(causal, masked, dropout_rate):
+            q_idx, k_idx = _block_coords(qi, ki, block_q, block_k)
+        if masked:
+            s = jnp.where(q_idx + (seq_k - seq_q) >= k_idx, s, NEG_INF)
         p = jnp.exp(s - lse[:, None])
         if mask_ref is not None:
             p = p * mask_ref[0, :, pl.ds(ki * block_k, block_k)]
@@ -279,7 +417,13 @@ def _bwd_dq_kernel(seed_ref, *refs, causal: bool, scale: float, block_k: int,
         ds = p * (dp - delta[:, None])
         return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(0, hi, body, jnp.zeros((block_q, d), jnp.float32))
+    dq = jnp.zeros((block_q, d), jnp.float32)
+    if causal:
+        dq = _walk(body, dq, causal_walk(
+            seq_q, seq_k, block_q, block_k).kv_runs(qi))
+    else:
+        dq = jax.lax.fori_loop(
+            0, seq_k // block_k, functools.partial(body, masked=False), dq)
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
@@ -300,14 +444,7 @@ def _bwd_dkv_kernel(seed_ref, *refs, causal: bool, scale: float, block_q: int,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
 
-    num_q = seq_q // block_q
-    offset = seq_k - seq_q
-    if causal:
-        lo = jnp.clip(jax.lax.div(ki * block_k - offset, block_q), 0, num_q)
-    else:
-        lo = 0
-
-    def body(qi, carry):
+    def body(qi, carry, masked):
         dk, dv = carry
         q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32) * scale
         do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
@@ -315,12 +452,10 @@ def _bwd_dkv_kernel(seed_ref, *refs, causal: bool, scale: float, block_q: int,
         delta = delta_ref[0, pl.ds(qi * block_q, block_q), 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        q_idx = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_idx = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        if causal:
-            s = jnp.where(q_idx + offset >= k_idx, s, NEG_INF)
+        if _needs_coords(causal, masked, dropout_rate):
+            q_idx, k_idx = _block_coords(qi, ki, block_q, block_k)
+        if masked:
+            s = jnp.where(q_idx + (seq_k - seq_q) >= k_idx, s, NEG_INF)
         p = jnp.exp(s - lse[:, None])                       # [bq, bk]
         if mask_ref is not None:
             p = p * mask_ref[0]
@@ -341,10 +476,14 @@ def _bwd_dkv_kernel(seed_ref, *refs, causal: bool, scale: float, block_q: int,
                                       preferred_element_type=jnp.float32)
         return dk, dv
 
-    dk, dv = jax.lax.fori_loop(
-        lo, num_q, body,
-        (jnp.zeros((block_k, d), jnp.float32),
-         jnp.zeros((block_k, d), jnp.float32)))
+    init = (jnp.zeros((block_k, d), jnp.float32),
+            jnp.zeros((block_k, d), jnp.float32))
+    if causal:
+        dk, dv = _walk(body, init, causal_walk(
+            seq_q, seq_k, block_q, block_k).q_runs(ki))
+    else:
+        dk, dv = jax.lax.fori_loop(
+            0, seq_q // block_q, functools.partial(body, masked=False), init)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -506,8 +645,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False,
                     kv_mask: Optional[jax.Array] = None,
                     softmax_scale: Optional[float] = None,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     dropout_rate: float = 0.0,
                     dropout_rng: Optional[jax.Array] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
@@ -521,12 +660,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (reference dropout_kernels.cu): the keep-mask is regenerated in the
     backward kernels from a counter-based hash (see ``dropout_keep_mask``),
     so no [S, S] mask is ever materialized.
+
+    ``block_q`` / ``block_k``: ``None`` takes ``default_blocks`` for this
+    call's ``causal``, lengths and head size; either way the block is fitted
+    to its length (``fit_block``).
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
 
-    block_q = fit_block(block_q, sq)
-    block_k = fit_block(block_k, sk)
+    block_q, block_k = fitted_blocks(causal, sq, sk, d, block_q, block_k)
     if sq % block_q or sk % block_k:
         raise ValueError(f"seq lengths ({sq},{sk}) must divide blocks "
                          f"({block_q},{block_k})")

@@ -21,6 +21,41 @@ def _make_qkv(rng, b, s, h, d, dtype=jnp.float32):
     return q, k, v
 
 
+def _seed_of(key):
+    """The int32 seed ``flash_attention`` derives from ``dropout_rng``."""
+    kd = jax.random.key_data(key).astype(jnp.uint32).reshape(-1)
+    return (kd[0] ^ (kd[-1] << 1)).astype(jnp.int32)
+
+
+def _dense_oracle(q, k, v, seed, rate, causal, kv_mask=None):
+    """Dense float32 attention applying the SAME hash-derived keep mask the
+    kernels use, post-softmax (``rate`` 0: no dropout)."""
+    from deepspeed_tpu.ops.transformer.flash_attention import \
+        dropout_keep_mask
+
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    b, s, h, d = q.shape
+    sk = k.shape[1]
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) / (d ** 0.5)
+    neg = jnp.finfo(jnp.float32).min
+    if causal:
+        cm = jnp.tril(jnp.ones((s, sk), jnp.bool_), k=sk - s)
+        logits = jnp.where(cm[None, None], logits, neg)
+    if kv_mask is not None:
+        logits = jnp.where(kv_mask[:, None, None, :].astype(bool),
+                           logits, neg)
+    p = jax.nn.softmax(logits, axis=-1)
+    if rate > 0.0:
+        rows = jax.lax.broadcasted_iota(jnp.int32, (s, sk), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (s, sk), 1)
+        bh = (jnp.arange(b)[:, None] * h + jnp.arange(h)[None, :])
+        keep = jax.vmap(jax.vmap(
+            lambda i: dropout_keep_mask(seed, i, rows, cols, rate)))(bh)
+        p = jnp.where(keep, p / (1.0 - rate), 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
 GRID = [
     # (batch, seq, heads, head_dim, causal)
     (2, 128, 2, 64, False),
@@ -78,6 +113,167 @@ class TestCrossLength:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-4, rtol=5e-4,
                                        err_msg=f"d{n}")
+
+
+BLOCKS = (128, 256, 512, 1024)
+
+
+class TestCausalWalk:
+    """The block walk of the three kernels under ``causal=True``: only the
+    blocks at or under the (bottom-right aligned) diagonal are visited, and
+    only those the diagonal crosses are masked."""
+
+    @pytest.mark.parametrize("block_q", BLOCKS)
+    @pytest.mark.parametrize("block_k", BLOCKS)
+    def test_matches_brute_force(self, block_q, block_k):
+        from deepspeed_tpu.ops.transformer.flash_attention import (
+            causal_walk, fit_block)
+
+        for seq_q in range(128, 1153, 128):
+            for seq_k in range(128, 1153, 128):
+                bq, bk = fit_block(block_q, seq_q), fit_block(block_k, seq_k)
+                nq, nk = seq_q // bq, seq_k // bk
+                allowed = (np.arange(seq_k)[None, :]
+                           <= np.arange(seq_q)[:, None] + (seq_k - seq_q))
+                blocks = allowed.reshape(nq, bq, nk, bk)
+                visited = blocks.any(axis=(1, 3))            # [nq, nk]
+                crossed = visited & ~blocks.all(axis=(1, 3))
+                walk = causal_walk(seq_q, seq_k, bq, bk)
+                what = f"{seq_q}x{seq_k} in {bq}x{bk}"
+                assert (walk.visited, walk.crossed, walk.total) == (
+                    visited.sum(), crossed.sum(), nq * nk), what
+
+                def marks(runs, n):
+                    seen, masked = np.zeros(n, bool), np.zeros(n, bool)
+                    for first, count, is_masked in runs:
+                        blocks = slice(first, first + count)
+                        assert not seen[blocks].any(), what     # disjoint
+                        seen[blocks] = True
+                        masked[blocks] = is_masked
+                    return seen, masked
+
+                for qi in range(nq):          # forward and dq: a q-block's row
+                    seen, masked = marks(walk.kv_runs(qi), nk)
+                    assert (seen == visited[qi]).all(), (what, qi)
+                    assert (masked == crossed[qi]).all(), (what, qi)
+                for ki in range(nk):          # dkv: a kv-block's column
+                    seen, masked = marks(walk.q_runs(ki), nq)
+                    assert (seen == visited[:, ki]).all(), (what, ki)
+                    assert (masked == crossed[:, ki]).all(), (what, ki)
+                # what the kernels compute from a traced program_id (a
+                # truncating lax.div) is what Python's ints give
+                for runs, ids in ((walk.kv_runs, nq), (walk.q_runs, nk)):
+                    traced = runs(jnp.arange(ids, dtype=jnp.int32))
+                    for j, (first, count, _) in enumerate(traced):
+                        want = [runs(i)[j] for i in range(ids)]
+                        np.testing.assert_array_equal(
+                            np.broadcast_to(first, (ids,)),
+                            [w[0] for w in want])
+                        np.testing.assert_array_equal(
+                            np.broadcast_to(count, (ids,)),
+                            [w[1] for w in want])
+                # one diagonal block a program: written out, not looped over
+                if bq == bk and seq_q == seq_k:
+                    for count in (walk.kv_runs(jnp.int32(0))[1][1],
+                                  walk.q_runs(jnp.int32(0))[0][1]):
+                        assert isinstance(count, int) and count == 1, what
+
+    @pytest.mark.parametrize("seq,head_dim,share", [
+        (1024, 64, 0.75),       # gpt2m-train-s1024 (the square: 1.0)
+        (4096, 256, 0.5625),    # glm47flash-train-s4096 (512x1024: 0.625)
+    ])
+    def test_default_blocks_visit_little_more_than_the_triangle(
+            self, seq, head_dim, share):
+        from deepspeed_tpu.ops.transformer.flash_attention import (
+            causal_walk, fitted_blocks)
+
+        walk = causal_walk(seq, seq, *fitted_blocks(True, seq, seq, head_dim))
+        assert walk.visited / walk.total <= share, walk
+        assert walk.crossed < walk.visited        # interior blocks exist
+
+    def test_non_causal_defaults_are_what_they_were(self):
+        from deepspeed_tpu.ops.transformer.flash_attention import \
+            fitted_blocks
+
+        assert fitted_blocks(False, 4096, 4096, 64) == (512, 1024)
+        assert fitted_blocks(False, 512, 512, 64) == (512, 512)
+        assert fitted_blocks(True, 1024, 1024, 64, 128, 256) == (128, 256)
+
+    @pytest.mark.parametrize("impl,causal", [
+        ("pallas", True),
+        ("pallas", False),      # the non-causal walk is the whole rectangle
+        ("xla", True),
+    ])
+    def test_dispatch_logs_the_walk_once_per_shape(self, monkeypatch, impl,
+                                                   causal):
+        from deepspeed_tpu.ops.transformer import attention as att
+        from deepspeed_tpu.ops.transformer.flash_attention import (
+            causal_walk, fitted_blocks)
+
+        lines = []
+        monkeypatch.setattr(att.logger, "info", lines.append)
+        att._log_auto_choice.__wrapped__(impl, causal, 1024, 1024, 64)
+        (line,) = lines
+        assert f"-> {impl} (seq_q=1024, seq_k=1024, head_dim=64" in line
+        if impl == "pallas" and causal:
+            w = causal_walk(1024, 1024, *fitted_blocks(True, 1024, 1024, 64))
+            assert (f"blocks visited {w.visited} of {w.total}, "
+                    f"{w.crossed} masked") in line
+        else:
+            assert "visited" not in line
+
+
+class TestCausalWalkParity:
+    """Forward and the three gradients against the dense oracle where the
+    diagonal crosses several blocks and interior blocks exist."""
+
+    CASES = {
+        # name: (seq_q, seq_k, block_q, block_k, dtype, kv_mask, dropout)
+        "s1024-defaults-f32": (1024, 1024, None, None, jnp.float32, False, 0.0),
+        "s1024-defaults-bf16": (1024, 1024, None, None, jnp.bfloat16, False,
+                                0.0),
+        "q256-k512-f32": (256, 512, 128, 128, jnp.float32, False, 0.0),
+        "q256-k512-bf16": (256, 512, 128, 128, jnp.bfloat16, False, 0.0),
+        "s512-kvmask-f32": (512, 512, 128, 128, jnp.float32, True, 0.0),
+        "s512-dropout-f32": (512, 512, 128, 128, jnp.float32, False, 0.3),
+        "s512-wide-k-f32": (512, 512, 128, 256, jnp.float32, False, 0.0),
+        "s512-wide-q-kvmask-dropout-bf16": (512, 512, 256, 128, jnp.bfloat16,
+                                            True, 0.3),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_forward_and_gradients(self, case):
+        sq, sk, bq, bk, dtype, masked, rate = self.CASES[case]
+        rng = np.random.default_rng(7)
+        q = jnp.asarray(rng.standard_normal((1, sq, 2, 64)), dtype)
+        k = jnp.asarray(rng.standard_normal((1, sk, 2, 64)), dtype)
+        v = jnp.asarray(rng.standard_normal((1, sk, 2, 64)), dtype)
+        ct = jnp.asarray(rng.standard_normal((1, sq, 2, 64)), jnp.float32)
+        km = None
+        if masked:      # pad keys at the end; key 0 stays for every row
+            km = jnp.asarray(np.arange(sk)[None, :] < sk - 100, jnp.int32)
+        key = jax.random.PRNGKey(11)
+
+        def flash(q, k, v):
+            return flash_attention(
+                q, k, v, causal=True, kv_mask=km, block_q=bq, block_k=bk,
+                dropout_rate=rate, dropout_rng=key if rate else None,
+                interpret=True)
+
+        def oracle(q, k, v):
+            return _dense_oracle(q, k, v, _seed_of(key), rate, True, km)
+
+        def with_grads(f):
+            out, vjp = jax.vjp(f, q, k, v)
+            return (out,) + vjp(ct.astype(out.dtype))
+
+        tol = 5e-4 if dtype == jnp.float32 else 6e-2
+        for name, got, want in zip(("out", "dq", "dk", "dv"),
+                                   with_grads(flash), with_grads(oracle)):
+            assert got.dtype == dtype, name
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), np.asarray(want, np.float32),
+                atol=tol, rtol=tol, err_msg=f"{case}: {name}")
 
 
 class TestKvMask:
@@ -202,40 +398,10 @@ class TestInKernelDropout:
                                  jnp.float32)
         return mk(), mk(), mk()
 
-    def _oracle(self, q, k, v, seed, rate, causal, kv_mask=None):
-        """Dense attention applying the SAME hash-derived keep mask the
-        kernel uses, post-softmax."""
-        from deepspeed_tpu.ops.transformer.flash_attention import \
-            dropout_keep_mask
-
-        b, s, h, d = q.shape
-        sk = k.shape[1]
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32) / (d ** 0.5)
-        neg = jnp.finfo(jnp.float32).min
-        if causal:
-            cm = jnp.tril(jnp.ones((s, sk), jnp.bool_), k=sk - s)
-            logits = jnp.where(cm[None, None], logits, neg)
-        if kv_mask is not None:
-            logits = jnp.where(kv_mask[:, None, None, :].astype(bool),
-                               logits, neg)
-        p = jax.nn.softmax(logits, axis=-1)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (s, sk), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (s, sk), 1)
-        bh = (jnp.arange(b)[:, None] * h + jnp.arange(h)[None, :])
-        keep = jax.vmap(jax.vmap(
-            lambda i: dropout_keep_mask(seed, i, rows, cols, rate)))(bh)
-        p = jnp.where(keep, p / (1.0 - rate), 0.0)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
     def _flash(self, q, k, v, seed_key, causal, kv_mask=None):
         return flash_attention(q, k, v, causal=causal, kv_mask=kv_mask,
                                dropout_rate=self.RATE, dropout_rng=seed_key,
                                interpret=True)
-
-    def _seed_of(self, key):
-        kd = jax.random.key_data(key).astype(jnp.uint32).reshape(-1)
-        return (kd[0] ^ (kd[-1] << 1)).astype(jnp.int32)
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_forward_matches_oracle(self, causal):
@@ -243,7 +409,7 @@ class TestInKernelDropout:
         q, k, v = self._qkv(rng)
         key = jax.random.PRNGKey(5)
         out = self._flash(q, k, v, key, causal)
-        ref = self._oracle(q, k, v, self._seed_of(key), self.RATE, causal)
+        ref = _dense_oracle(q, k, v, _seed_of(key), self.RATE, causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
@@ -255,7 +421,7 @@ class TestInKernelDropout:
         mask = jnp.asarray(mask)
         key = jax.random.PRNGKey(6)
         out = self._flash(q, k, v, key, False, kv_mask=mask)
-        ref = self._oracle(q, k, v, self._seed_of(key), self.RATE, False,
+        ref = _dense_oracle(q, k, v, _seed_of(key), self.RATE, False,
                            kv_mask=mask)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
@@ -265,7 +431,7 @@ class TestInKernelDropout:
         rng = np.random.default_rng(2)
         q, k, v = self._qkv(rng)
         key = jax.random.PRNGKey(7)
-        seed = self._seed_of(key)
+        seed = _seed_of(key)
 
         def loss_flash(q, k, v):
             o = self._flash(q, k, v, key, causal)
@@ -273,7 +439,7 @@ class TestInKernelDropout:
             return jnp.sum(o * w) / o.size
 
         def loss_ref(q, k, v):
-            o = self._oracle(q, k, v, seed, self.RATE, causal)
+            o = _dense_oracle(q, k, v, seed, self.RATE, causal)
             w = jnp.arange(o.size, dtype=jnp.float32).reshape(o.shape)
             return jnp.sum(o * w) / o.size
 
