@@ -152,10 +152,10 @@ def inspect_step(rep, engine, batches, n_layers, stage, rehearsal):
         rep.say("  (rehearsal: attention runs in XLA below the seq-512 "
                 "crossover — no Mosaic calls to count)")
     else:
-        # flash forward + dq + dkv kernels per layer
-        rep.check(n_mosaic >= 3 * n_layers,
-                  f"train step holds the Mosaic flash kernels "
-                  f"({n_mosaic} >= {3 * n_layers})")
+        # flash_fwd + flash_bwd per layer
+        rep.check(n_mosaic == 2 * n_layers,
+                  f"train step holds the two Mosaic flash kernels a layer "
+                  f"({n_mosaic} == {2 * n_layers})")
     if engine.mesh.size > 1:
         rep.check(counts["all-gather"] > 0
                   and counts["reduce-scatter"] + counts["all-reduce"] > 0,

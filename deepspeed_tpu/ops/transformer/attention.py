@@ -237,8 +237,8 @@ def _log_auto_choice(impl: str, causal: bool, sq: int, sk: int,
     """Say once per shape which implementation ``impl="auto"`` resolved to
     (the cache is the once) — a run that was meant to use the flash kernel
     and landed on the XLA path must be visible in its log. For the causal
-    flash kernels also how much of the score square their forward walk
-    visits, and how much of that it masks."""
+    flash kernels also how much of the score square their walk visits, how
+    much of that it masks, and that the backward is one kernel."""
     walk = ""
     if impl == "pallas" and causal:
         from deepspeed_tpu.ops.transformer.flash_attention import (
@@ -247,7 +247,7 @@ def _log_auto_choice(impl: str, causal: bool, sq: int, sk: int,
         bq, bk = fitted_blocks(causal, sq, sk, head_dim)
         w = causal_walk(sq, sk, bq, bk)
         walk = (f"; causal walk in {bq}x{bk}: blocks visited {w.visited} of "
-                f"{w.total}, {w.crossed} masked")
+                f"{w.total}, {w.crossed} masked; backward: one kernel")
     logger.info(f"attention impl=auto -> {impl} (seq_q={sq}, seq_k={sk}, "
                 f"head_dim={head_dim}{walk})")
 

@@ -9,9 +9,12 @@ accumulator are carried (online softmax). Saves the per-row logsumexp for
 the backward pass. Under ``causal=True`` the walk covers the causal triangle
 only (``causal_walk``).
 
-Backward: custom VJP with two kernels — dq over q-blocks, dk/dv over
-kv-blocks — using the standard flash-attention recomputation identity
-ds = p ⊙ (dp − delta), delta = rowsum(dO ⊙ O).
+Backward: custom VJP with ONE kernel per (batch·head, kv-block), named
+``flash_bwd``: that head's Q, dO, lse and delta sit whole in VMEM, the
+q-blocks are walked, and S, the mask, P, dP and dS = P ⊙ (dP − delta), delta
+= rowsum(dO ⊙ O), are built once per visited block for all three gradients
+(5 matmuls a block). dK and dV are summed over the walk; dQ over the kv axis
+of the grid in a float32 VMEM scratch that leaves once a head.
 
 All matmuls accumulate in fp32 on the MXU (preferred_element_type); block
 sizes are 128-aligned for MXU/VPU tiling. ``interpret=True`` runs the same
@@ -29,8 +32,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.utils.platform import on_tpu
 
-# Blocks. K and V (dkv: Q, dO, lse, delta) stay whole in VMEM per (b, h), so
-# a smaller block on the looped side adds loop trips, not DMAs; a smaller
+# Blocks. K and V (backward: Q, dO, lse, delta) stay whole in VMEM per (b, h),
+# so a smaller block on the looped side adds loop trips, not DMAs; a smaller
 # block on the grid side adds grid steps. VMEM at 512 x 1024 (fp32): q bq·d
 # + k/v 2·bk·d + score block bq·bk = 2.7 / 3.4 / 4.7 MB at d = 64 / 128 /
 # 256, inside the 16 MB default; `_vmem_params` raises the cap for the long
@@ -38,35 +41,46 @@ from deepspeed_tpu.utils.platform import on_tpu
 #
 # causal=False runs 512 x 1024 over the whole rectangle, as it always has
 # (HISTORY, 2026-07-30, not re-measured: 128 x 128 ran at ~1 TFLOP/s, 512 x
-# 1024 at ~31, fwd+bwd at seq 4096, d=64). Its lowered text is pinned.
+# 1024 at ~31, fwd+bwd at seq 4096, d=64). Its forward's lowered text is
+# pinned.
 #
 # causal=True visits only the blocks at or under the diagonal and masks only
 # those it crosses (`causal_walk`), in CAUSAL_BLOCK x CAUSAL_BLOCK blocks.
-# Measured on a v5e (PR 29, tools/probe_flash_blocks.py; median device us of
-# one call, bf16, fwd / dq / dkv), at the two training cells' shapes:
+# Measured on a v5e (tools/probe_flash_blocks.py; median device us of one
+# call, bf16), at the two training cells' shapes. PR 32, one backward kernel
+# (fwd / bwd), beside its parent's two (fwd / dq + dkv):
 #
 #   block_q x block_k            bh 64, seq 1024, d 64    bh 20, seq 4096, d 256
-#   before PR 29: 512 x 1024 (2/2, 20/32), every block masked and in a
-#   loop                             342 / 317 / 444      1526 / 1841 / 2663
-#   512 x 512  (visits 3/4, 36/64)   236 / 251 / 340      1393 / 1720 / 2535
-#   256 x 256  (10/16, 136/256)      367 / 336 / 480      1656 / 1841 / 2794
-#   1024 x 1024 (1/1, 10/16)         201 / 273 / 374      1446 / 1797 / 2493
-#   with every run of the walk a loop (the first form tried):
-#   512 x 512                        324 / 268 / 387      1558 / 1785 / 2628
-#   512 x 256                        451 / 329 / 438      1793 / 1901 / 2686
-#   256 x 512                        307 / 287 / 468      1523 / 1797 / 2936
+#   parent, 512 x 512            236 / 251 + 340 = 591    1393 / 1720 + 2534 = 4254
+#   512 x 512  (visits 3/4, 36/64)   236 / 424            1392 / 3134
+#   1024 x 1024 (1/1, 10/16)         201 / 489            1446 / 3094
+#   256 x 512  (6/8, 72/128)         239 / 546            1385 / 3529
+#   512 x 256  (6/8, 72/128)         451 / 501            1793 / 3139
+#   512 x 1024 (2/2, 20/32)          266 / 546            1420 / 3372
+#   256 x 256  (10/16, 136/256)      367 / 566            (not timed)
 #
-# What the table says: (1) the time does not follow the score elements
+# PR 29 (fwd / dq / dkv), what the causal walk was chosen from:
+#
+#   before PR 29: 512 x 1024, every block masked and in a
+#   loop                             342 / 317 / 444      1526 / 1841 / 2663
+#   256 x 256                        367 / 336 / 480      1656 / 1841 / 2794
+#   1024 x 1024                      201 / 273 / 374      1446 / 1797 / 2493
+#   512 x 512, every run of the walk a loop (the first form tried)
+#                                    324 / 268 / 387      1558 / 1785 / 2628
+#
+# What the tables say: (1) the time does not follow the score elements
 # alone. The same block inside a `fori_loop` of one trip (or under a `cond`:
 # tried, no better) costs 1.3-1.7 times what it costs written out, and a
 # grid step about 1 us: so the one diagonal block of a program is written
 # out (`CausalWalk`: a Python 1 for its count), which is worth as much as
-# the blocks not visited (512 x 512: 979 -> 827 us for the three at d 64),
-# and 256-wide blocks lose although they visit least. (2) 512 x 512 is the
-# best pair for the three kernels together at both head sizes, so the rule
-# does not read head_dim; the forward alone prefers 1024 x 1024 at seq 1024.
-# (3) A length with no 128-multiple divisor from 256 to 512 (640, 896) runs
-# as one block (358 and 662 us for the three; 1,096 and 2,080 in 128 x 128).
+# the blocks not visited, and 256-wide blocks lose although they visit
+# least. (2) 512 x 512 is the best pair for the forward and the backward
+# together at both head sizes, so the rule does not read head_dim; the
+# forward alone prefers 1024 x 1024 at seq 1024. (3) One backward kernel
+# costs what the dkv kernel cost plus a quarter (424 v 340, 3134 v 2534):
+# the dq kernel's whole second pass over S, P and dP (251, 1720) went.
+# (4) A length with no 128-multiple divisor from 256 to 512 (640, 896) runs
+# as one block (128 x 128 blocks were 2-3 times slower, PR 29).
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 CAUSAL_BLOCK = 512
@@ -114,10 +128,10 @@ def fitted_blocks(causal: bool, seq_q: int, seq_k: int, head_dim: int,
 
 
 class CausalWalk(NamedTuple):
-    """Which score blocks the three kernels visit under ``causal=True``.
+    """Which score blocks the kernels visit under ``causal=True``.
 
-    ``kv_runs(qi)`` is a q-block's walk over the kv-blocks (forward, dq),
-    ``q_runs(ki)`` a kv-block's walk over the q-blocks (dkv): each a tuple
+    ``kv_runs(qi)`` is a q-block's walk over the kv-blocks (forward),
+    ``q_runs(ki)`` a kv-block's walk over the q-blocks (backward): each a tuple
     of ``(first, count, masked)`` runs in ascending order, ``qi`` / ``ki`` a
     Python int or the kernel's traced ``program_id``. Where the diagonal
     crosses exactly one block of every block of a side (``block_q ==
@@ -223,7 +237,7 @@ def _block_coords(qi, ki, block_q: int, block_k: int):
 # absolute col) via a murmur3-style integer hash — vector int ops that run
 # identically inside the Mosaic kernel, in the Pallas interpreter, and in
 # plain jnp (`dropout_keep_mask` is the oracle the parity tests use). The
-# backward kernels regenerate exactly the forward's mask because the hash
+# backward kernel regenerates exactly the forward's mask because the hash
 # depends only on absolute element coordinates, not the block walk order.
 
 def _hash_u32(x):
@@ -372,70 +386,22 @@ def _flash_forward(q, k, v, kv_mask, causal, scale, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels
+# Backward kernel
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(seed_ref, *refs, causal: bool, scale: float, block_k: int,
-                   seq_q: int, seq_k: int, has_mask: bool,
-                   dropout_rate: float):
+def _bwd_kernel(seed_ref, *refs, causal: bool, scale: float, block_q: int,
+                seq_q: int, seq_k: int, has_mask: bool, dropout_rate: float):
+    """dQ, dK and dV of one (batch·head, kv-block) program: S, the mask, P, dP
+    and dS are built once per visited block. dK and dV are summed over the
+    q-blocks in registers; dQ over the kv-blocks (the grid's second axis, in
+    ascending order) in the float32 scratch ``dq_acc``, which leaves for the
+    head's dQ block at its last kv-block."""
     if has_mask:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-         dq_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
-        mask_ref = None
-    bh_idx = pl.program_id(0)
-    qi = pl.program_id(1)
-    block_q = q_ref.shape[1]
-    d = q_ref.shape[2]
-    q = q_ref[0].astype(jnp.float32) * scale
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, :, 0]
-    delta = delta_ref[0, :, 0]
-
-    def body(ki, dq, masked):
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if _needs_coords(causal, masked, dropout_rate):
-            q_idx, k_idx = _block_coords(qi, ki, block_q, block_k)
-        if masked:
-            s = jnp.where(q_idx + (seq_k - seq_q) >= k_idx, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        if mask_ref is not None:
-            p = p * mask_ref[0, :, pl.ds(ki * block_k, block_k)]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        # With dropout D: o = Σ D p̂ v / l, and Σ_j p̂_j D_j dp_j = do·o =
-        # delta still holds, so ds = p (D∘dp − delta) — regenerate the
-        # forward's exact keep-mask from the hash.
-        if dropout_rate > 0.0:
-            keep = dropout_keep_mask(seed_ref[0], bh_idx, q_idx, k_idx,
-                                     dropout_rate)
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
-        ds = p * (dp - delta[:, None])
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
-
-    dq = jnp.zeros((block_q, d), jnp.float32)
-    if causal:
-        dq = _walk(body, dq, causal_walk(
-            seq_q, seq_k, block_q, block_k).kv_runs(qi))
-    else:
-        dq = jax.lax.fori_loop(
-            0, seq_k // block_k, functools.partial(body, masked=False), dq)
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(seed_ref, *refs, causal: bool, scale: float, block_q: int,
-                    seq_q: int, seq_k: int, has_mask: bool,
-                    dropout_rate: float):
-    if has_mask:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-         dk_ref, dv_ref) = refs
+         dq_ref, dk_ref, dv_ref, dq_acc) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref) = refs
+         dq_ref, dk_ref, dv_ref, dq_acc) = refs
         mask_ref = None
     bh_idx = pl.program_id(0)
     ki = pl.program_id(1)
@@ -444,12 +410,17 @@ def _bwd_dkv_kernel(seed_ref, *refs, causal: bool, scale: float, block_q: int,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
 
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
     def body(qi, carry, masked):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), 0]
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), 0]
+        rows = pl.ds(qi * block_q, block_q)
+        q = q_ref[0, rows, :].astype(jnp.float32) * scale
+        do = do_ref[0, rows, :].astype(jnp.float32)
+        lse = lse_ref[0, rows, 0]
+        delta = delta_ref[0, rows, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if _needs_coords(causal, masked, dropout_rate):
@@ -461,6 +432,9 @@ def _bwd_dkv_kernel(seed_ref, *refs, causal: bool, scale: float, block_q: int,
             p = p * mask_ref[0]
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
+        # With dropout D: o = Σ D p̂ v / l, and Σ_j p̂_j D_j dp_j = do·o =
+        # delta still holds, so ds = p (D∘dp − delta) — regenerate the
+        # forward's exact keep-mask from the hash.
         if dropout_rate > 0.0:
             keep = dropout_keep_mask(seed_ref[0], bh_idx, q_idx, k_idx,
                                      dropout_rate)
@@ -474,6 +448,7 @@ def _bwd_dkv_kernel(seed_ref, *refs, causal: bool, scale: float, block_q: int,
         ds = p * (dp - delta[:, None])
         dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
+        dq_acc[rows, :] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
         return dk, dv
 
     init = (jnp.zeros((block_k, d), jnp.float32),
@@ -487,19 +462,26 @@ def _bwd_dkv_kernel(seed_ref, *refs, causal: bool, scale: float, block_q: int,
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
-def _vmem_params(est_bytes: int):
+
+def _vmem_params(est_bytes: int, dimension_semantics=None):
     """Raise Mosaic's scoped-VMEM cap (default 16 MiB) when a kernel
     instance's double-buffered working set won't fit — the long-sequence
-    backward keeps whole-sequence q/do/lse/delta refs per instance, which
-    at seq 4096 overflows the default by ~1 MiB (v5e has 128 MiB VMEM).
+    backward keeps whole-sequence q/do/lse/delta/dq refs per instance, which
+    at seq 4096 overflows the default (v5e has 128 MiB VMEM).
     ``est_bytes`` is the single-buffered per-instance sum; ×4 + 16 MiB
     covers double buffering plus the compiler's own stack slack (measured:
     Mosaic asked for ~2% above a bare ×4 at seq 16384)."""
-    if est_bytes * 4 <= 16 * 2**20:
+    limit = None
+    if est_bytes * 4 > 16 * 2**20:
+        limit = int(min(100 * 2**20, est_bytes * 4 + 16 * 2**20))
+    if limit is None and dimension_semantics is None:
         return None
-    return pltpu.CompilerParams(
-        vmem_limit_bytes=int(min(100 * 2**20, est_bytes * 4 + 16 * 2**20)))
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=limit)
 
 
 def _flash_backward(res, g, causal, scale, block_q, block_k, interpret,
@@ -512,73 +494,40 @@ def _flash_backward(res, g, causal, scale, block_q, block_k, interpret,
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
     has_mask = kv_mask is not None
 
-    dq_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
-        pl.BlockSpec((1, sk, d), lambda b, i, s: (b, 0, 0)),
-        pl.BlockSpec((1, sk, d), lambda b, i, s: (b, 0, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0)),
-        pl.BlockSpec((1, block_q, LANES), lambda b, i, s: (b, i, 0)),
-        pl.BlockSpec((1, block_q, LANES), lambda b, i, s: (b, i, 0)),
-    ]
-    dq_inputs = [q, k, v, do, lse, delta]
-    if has_mask:
-        dq_in_specs.append(
-            pl.BlockSpec((1, 1, sk), lambda b, i, s: (b // nheads, 0, 0)))
-        dq_inputs.append(kv_mask)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
-                          block_k=block_k, seq_q=sq, seq_k=sk,
-                          has_mask=has_mask, dropout_rate=dropout_rate),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, sq // block_q),
-            in_specs=dq_in_specs,
-            out_specs=pl.BlockSpec((1, block_q, d),
-                                   lambda b, i, s: (b, i, 0))),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        name="flash_bwd_dq",
-        interpret=interpret,
-        compiler_params=_vmem_params(
-            (2 * sk * d + 3 * block_q * d) * q.dtype.itemsize
-            + 2 * block_q * LANES * 4),
-    )(seed, *dq_inputs)
+    def whole(width):       # the head's rows whole: fetched once a head
+        return pl.BlockSpec((1, sq, width), lambda b, i, s: (b, 0, 0))
 
-    dkv_in_specs = [
-        pl.BlockSpec((1, sq, d), lambda b, i, s: (b, 0, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, s: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, s: (b, i, 0)),
-        pl.BlockSpec((1, sq, d), lambda b, i, s: (b, 0, 0)),
-        pl.BlockSpec((1, sq, LANES), lambda b, i, s: (b, 0, 0)),
-        pl.BlockSpec((1, sq, LANES), lambda b, i, s: (b, 0, 0)),
-    ]
-    dkv_inputs = [q, k, v, do, lse, delta]
+    kv_block = pl.BlockSpec((1, block_k, d), lambda b, i, s: (b, i, 0))
+    in_specs = [whole(d), kv_block, kv_block, whole(d), whole(LANES),
+                whole(LANES)]
+    inputs = [q, k, v, do, lse, delta]
     if has_mask:
-        dkv_in_specs.append(
+        in_specs.append(
             pl.BlockSpec((1, 1, block_k), lambda b, i, s: (b // nheads, 0, i)))
-        dkv_inputs.append(kv_mask)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
+        inputs.append(kv_mask)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, causal=causal, scale=scale,
                           block_q=block_q, seq_q=sq, seq_k=sk,
                           has_mask=has_mask, dropout_rate=dropout_rate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, sk // block_k),
-            in_specs=dkv_in_specs,
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda b, i, s: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, s: (b, i, 0)),
-            ]),
+            in_specs=in_specs,
+            # dQ's block does not follow the kv axis: it leaves VMEM once a head
+            out_specs=[whole(d), kv_block, kv_block],
+            scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32)]),
         out_shape=[
+            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
-        name="flash_bwd_dkv",
+        name="flash_bwd",
         interpret=interpret,
         compiler_params=_vmem_params(
-            (2 * sq * d + 4 * block_k * d) * q.dtype.itemsize
-            + 2 * sq * LANES * 4),
-    )(seed, *dkv_inputs)
-    return dq, dk, dv
+            (3 * sq * d + 4 * block_k * d) * q.dtype.itemsize
+            + 2 * sq * LANES * 4 + sq * d * 4,
+            dimension_semantics=("parallel", "arbitrary")),
+    )(seed, *inputs)
 
 
 # ---------------------------------------------------------------------------
@@ -658,7 +607,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     ``dropout_rate`` + ``dropout_rng``: in-kernel attention dropout
     (reference dropout_kernels.cu): the keep-mask is regenerated in the
-    backward kernels from a counter-based hash (see ``dropout_keep_mask``),
+    backward kernel from a counter-based hash (see ``dropout_keep_mask``),
     so no [S, S] mask is ever materialized.
 
     ``block_q`` / ``block_k``: ``None`` takes ``default_blocks`` for this

@@ -540,7 +540,7 @@ class TestSpanTable:
                 src = f.read()
             if "pallas_call(" in src:
                 kernels.update(_KERNEL_NAME_RE.findall(src))
-        assert len(kernels) == 8, kernels
+        assert len(kernels) == 7, kernels
         assert not sorted(k for k in kernels if f"`{k}`" not in section)
         # the section says how to read them and what they cost
         assert "dump_xplane.py" in section and "sync_spans" in section

@@ -56,6 +56,34 @@ def _dense_oracle(q, k, v, seed, rate, causal, kv_mask=None):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
+def _assert_matches_oracle(case, q, k, v, ct, *, causal, km, rate, block_q,
+                           block_k):
+    """The kernels' output and three gradients against the dense oracle
+    with the same keep-mask, each in the inputs' dtype."""
+    key = jax.random.PRNGKey(11)
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, causal=causal, kv_mask=km, block_q=block_q,
+            block_k=block_k, dropout_rate=rate,
+            dropout_rng=key if rate else None, interpret=True)
+
+    def oracle(q, k, v):
+        return _dense_oracle(q, k, v, _seed_of(key), rate, causal, km)
+
+    def with_grads(f):
+        out, vjp = jax.vjp(f, q, k, v)
+        return (out,) + vjp(ct.astype(out.dtype))
+
+    tol = 5e-4 if q.dtype == jnp.float32 else 6e-2
+    for name, got, want in zip(("out", "dq", "dk", "dv"),
+                               with_grads(flash), with_grads(oracle)):
+        assert got.dtype == q.dtype, name
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=tol, rtol=tol, err_msg=f"{case}: {name}")
+
+
 GRID = [
     # (batch, seq, heads, head_dim, causal)
     (2, 128, 2, 64, False),
@@ -218,7 +246,7 @@ class TestCausalWalk:
         if impl == "pallas" and causal:
             w = causal_walk(1024, 1024, *fitted_blocks(True, 1024, 1024, 64))
             assert (f"blocks visited {w.visited} of {w.total}, "
-                    f"{w.crossed} masked") in line
+                    f"{w.crossed} masked; backward: one kernel") in line
         else:
             assert "visited" not in line
 
@@ -252,28 +280,84 @@ class TestCausalWalkParity:
         km = None
         if masked:      # pad keys at the end; key 0 stays for every row
             km = jnp.asarray(np.arange(sk)[None, :] < sk - 100, jnp.int32)
-        key = jax.random.PRNGKey(11)
+        _assert_matches_oracle(case, q, k, v, ct, causal=True, km=km,
+                               rate=rate, block_q=bq, block_k=bk)
 
-        def flash(q, k, v):
-            return flash_attention(
-                q, k, v, causal=True, kv_mask=km, block_q=bq, block_k=bk,
-                dropout_rate=rate, dropout_rng=key if rate else None,
-                interpret=True)
 
-        def oracle(q, k, v):
-            return _dense_oracle(q, k, v, _seed_of(key), rate, True, km)
+def _pallas_call_names(jaxpr):
+    """``name=`` of every ``pallas_call`` in a jaxpr, sub-jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(_pallas_call_names(sub))
+    return names
 
-        def with_grads(f):
-            out, vjp = jax.vjp(f, q, k, v)
-            return (out,) + vjp(ct.astype(out.dtype))
 
-        tol = 5e-4 if dtype == jnp.float32 else 6e-2
-        for name, got, want in zip(("out", "dq", "dk", "dv"),
-                                   with_grads(flash), with_grads(oracle)):
-            assert got.dtype == dtype, name
-            np.testing.assert_allclose(
-                np.asarray(got, np.float32), np.asarray(want, np.float32),
-                atol=tol, rtol=tol, err_msg=f"{case}: {name}")
+class TestOneBackwardKernel:
+    """dQ, dK and dV come from ONE kernel: dQ is summed over the kv-blocks in
+    a scratch that every head zeroes anew and writes out at its last
+    kv-block. Held to the dense oracle where a q-block's sum runs over
+    several kv-blocks, over several heads, and at one-block lengths."""
+
+    CASES = {
+        # name: (batch, heads, seq_q, seq_k, block_q, block_k, dtype, causal,
+        #        kv_mask, dropout)
+        "causal-4-kv-blocks-f32": (2, 2, 512, 512, 128, 128, jnp.float32,
+                                   True, False, 0.0),
+        "causal-4-kv-blocks-bf16": (2, 2, 512, 512, 128, 128, jnp.bfloat16,
+                                    True, False, 0.0),
+        "whole-square-4-kv-blocks-f32": (2, 2, 512, 512, 128, 128,
+                                         jnp.float32, False, False, 0.0),
+        "whole-square-wide-q-f32": (1, 3, 512, 512, 256, 128, jnp.float32,
+                                    False, False, 0.0),
+        "causal-q256-k512-f32": (2, 2, 256, 512, 128, 128, jnp.float32, True,
+                                 False, 0.0),
+        "whole-q512-k256-f32": (2, 2, 512, 256, 128, 128, jnp.float32, False,
+                                False, 0.0),
+        "whole-q128-k512-kvmask-bf16": (2, 2, 128, 512, 128, 128,
+                                        jnp.bfloat16, False, True, 0.0),
+        "whole-kvmask-dropout-f32": (2, 2, 512, 512, 128, 128, jnp.float32,
+                                     False, True, 0.3),
+        "causal-kvmask-dropout-f32": (2, 2, 512, 512, 128, 128, jnp.float32,
+                                      True, True, 0.3),
+        "causal-s640-one-block-f32": (1, 2, 640, 640, None, None,
+                                      jnp.float32, True, False, 0.0),
+        "causal-s896-one-block-bf16": (1, 2, 896, 896, None, None,
+                                       jnp.bfloat16, True, False, 0.0),
+        "whole-s640-f32": (1, 2, 640, 640, None, None, jnp.float32, False,
+                           False, 0.0),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_output_and_three_gradients(self, case):
+        b, h, sq, sk, bq, bk, dtype, causal, masked, rate = self.CASES[case]
+        rng = np.random.default_rng(13)
+        q = jnp.asarray(rng.standard_normal((b, sq, h, 64)), dtype)
+        k = jnp.asarray(rng.standard_normal((b, sk, h, 64)), dtype)
+        v = jnp.asarray(rng.standard_normal((b, sk, h, 64)), dtype)
+        ct = jnp.asarray(rng.standard_normal((b, sq, h, 64)), dtype)
+        km = None
+        if masked:      # each batch row pads another number of trailing keys
+            pad = 60 + 70 * np.arange(b)[:, None]
+            km = jnp.asarray(np.arange(sk)[None, :] < sk - pad, jnp.int32)
+        _assert_matches_oracle(case, q, k, v, ct, causal=causal, km=km,
+                               rate=rate, block_q=bq, block_k=bk)
+
+    @pytest.mark.parametrize("masked", [False, True],
+                             ids=["plain", "kv_mask"])
+    def test_the_backward_is_one_pallas_call(self, masked):
+        q = jnp.zeros((1, 256, 2, 64), jnp.bfloat16)
+        km = jnp.ones((1, 256), jnp.int32) if masked else None
+
+        def grads(q, k, v, ct):
+            return jax.vjp(lambda q, k, v: flash_attention(
+                q, k, v, causal=True, kv_mask=km, block_q=128, block_k=128,
+                interpret=True), q, k, v)[1](ct)
+
+        names = _pallas_call_names(jax.make_jaxpr(grads)(q, q, q, q).jaxpr)
+        assert sorted(names) == ["flash_bwd", "flash_fwd"]
 
 
 class TestKvMask:

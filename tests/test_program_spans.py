@@ -424,7 +424,7 @@ def test_the_serving_programs_name_their_parts(gpt_setup):
 
 KERNELS = {
     "ops/transformer/flash_attention.py": [
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
+        "flash_fwd", "flash_bwd"],
     "ops/transformer/paged_attention.py": ["paged_attention"],
     "ops/sparse_attention/sparse_attention.py": [
         "sparse_attn_fwd", "sparse_attn_bwd_dq", "sparse_attn_bwd_dkv"],

@@ -89,7 +89,7 @@ def test_flash_attention_compiles_on_a_four_chip_mesh(axes, spec, v5e_devices,
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3   # fwd, dq, dkv
+    assert compiled.as_text().count("tpu_custom_call") >= 2   # fwd, bwd
 
 
 # The benchmark's serving cell (gpt2m-serve-chat): 4097 blocks x 16

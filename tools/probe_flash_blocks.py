@@ -1,16 +1,18 @@
-"""Device time of the three flash kernels, one by one, per block pair.
+"""Device time of the flash kernels, one by one, per block pair.
 
     chiprun -- python tools/probe_flash_blocks.py            # the chip
     JAX_PLATFORMS=cpu python tools/probe_flash_blocks.py --rehearsal
 
 For each shape (the two training cells' attention calls by default) and each
-``block_q x block_k`` pair, ``flash_fwd``, ``flash_bwd_dq`` and
-``flash_bwd_dkv`` run ``--reps`` times each under one ``jax.profiler``
-capture; the table is the median device duration of each kernel's Mosaic
-call, in microseconds (dq and dkv are taken apart by asking the backward for
-one gradient: XLA drops the other call). Results also land in
-``chiprun_out/probe_flash_blocks.json``. ``--rehearsal`` is the same control
-flow tiny through the interpreter, every time null.
+``block_q x block_k`` pair, ``flash_fwd`` and ``flash_bwd`` run ``--reps``
+times each under one ``jax.profiler`` capture; the table is the median
+device duration of each kernel's Mosaic call, in microseconds. Copied into
+the ``tools/`` of a checkout that still has two backward kernels it times
+``dq`` and ``dkv`` in place of ``bwd`` (taken apart by asking the backward
+for one gradient: XLA drops the other call). Results also land in
+``chiprun_out/probe_flash_blocks.json`` (``--out`` names another file there).
+``--rehearsal`` is the same control flow tiny through the interpreter, every
+time null.
 """
 
 import argparse
@@ -37,7 +39,7 @@ PAIRS = [(512, 1024), (512, 512), (512, 256), (256, 512), (256, 256)]
 
 
 def kernels(bh, seq, d, block_q, block_k, interpret):
-    """The three kernels as jitted calls of one output each."""
+    """Each kernel as a jitted call that runs that one Mosaic call."""
     scale = 1.0 / d ** 0.5
     seed = jnp.zeros((1,), jnp.int32)
 
@@ -49,9 +51,11 @@ def kernels(bh, seq, d, block_q, block_k, interpret):
         return fa._flash_backward((q, k, v, None, out, lse, seed), g, True,
                                   scale, block_q, block_k, interpret)
 
-    return {"fwd": jax.jit(fwd),
-            "dq": jax.jit(lambda *a: bwd(*a)[0]),
-            "dkv": jax.jit(lambda *a: bwd(*a)[1:])}
+    if hasattr(fa, "_bwd_dq_kernel"):       # a checkout before the one kernel
+        return {"fwd": jax.jit(fwd),
+                "dq": jax.jit(lambda *a: bwd(*a)[0]),
+                "dkv": jax.jit(lambda *a: bwd(*a)[1:])}
+    return {"fwd": jax.jit(fwd), "bwd": jax.jit(bwd)}
 
 
 def mosaic_us(trace_dir):
@@ -70,8 +74,8 @@ def measure(bh, seq, d, block_q, block_k, reps, rehearsal):
                   for _ in range(4))
     fns = kernels(bh, seq, d, block_q, block_k, interpret=rehearsal)
     out, lse = fns["fwd"](q, k, v)
-    args = {"fwd": (q, k, v), "dq": (q, k, v, out, lse, g),
-            "dkv": (q, k, v, out, lse, g)}
+    args = {name: (q, k, v) if name == "fwd" else (q, k, v, out, lse, g)
+            for name in fns}
     for name, fn in fns.items():                       # compile, warm
         jax.block_until_ready(fn(*args[name]))
     if rehearsal:
@@ -84,7 +88,7 @@ def measure(bh, seq, d, block_q, block_k, reps, rehearsal):
             jax.block_until_ready(r)
         jax.profiler.stop_trace()
         us = mosaic_us(tmp)
-    assert len(us) == 3 * reps, (len(us), reps)
+    assert len(us) == len(fns) * reps, (len(us), len(fns), reps)
     return {name: statistics.median(us[i * reps:(i + 1) * reps])
             for i, name in enumerate(fns)}
 
@@ -97,6 +101,8 @@ def main():
                     help="bh,seq,head_dim (repeatable; default: the cells')")
     ap.add_argument("--pair", action="append", default=[],
                     help="block_q,block_k (repeatable; default: the five)")
+    ap.add_argument("--out", default="probe_flash_blocks.json",
+                    help="file name under chiprun_out/")
     a = ap.parse_args()
     if not a.rehearsal and jax.devices()[0].platform != "tpu":
         sys.exit(f"needs a TPU, found {jax.devices()[0].platform} "
@@ -111,10 +117,12 @@ def main():
     for bh, seq, d in shapes:
         for bq, bk in pairs:
             bq, bk = fa.fit_block(bq, seq), fa.fit_block(bk, seq)
-            us = measure(bh, seq, d, bq, bk, a.reps, a.rehearsal)
             row = {"bh": bh, "seq": seq, "head_dim": d, "block_q": bq,
-                   "block_k": bk, "us": us,
-                   "device": jax.devices()[0].device_kind}
+                   "block_k": bk, "device": jax.devices()[0].device_kind}
+            try:
+                row["us"] = measure(bh, seq, d, bq, bk, a.reps, a.rehearsal)
+            except Exception as e:  # noqa: BLE001 — a pair Mosaic refuses is a row
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
             if hasattr(fa, "causal_walk"):    # a parent checkout has none
                 walk = fa.causal_walk(seq, seq, bq, bk)
                 row.update(visited=walk.visited, crossed=walk.crossed,
@@ -122,7 +130,7 @@ def main():
             rows.append(row)
             print(json.dumps(row), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/probe_flash_blocks.json", "w") as f:
+    with open(os.path.join("chiprun_out", a.out), "w") as f:
         json.dump(rows, f, indent=1)
 
 
