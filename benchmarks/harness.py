@@ -2,6 +2,7 @@
 files by the names the data gives, the context a run carries, the compile
 counter, the profiler switch and the reduction of a traced window."""
 
+import atexit
 import glob
 import importlib.util
 import json
@@ -18,6 +19,16 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 OUT_DIR = os.path.join(ROOT, ".bench_out")   # traces; listed in .gitignore
+
+
+def capture_dir(cell: str, rehearsal: bool) -> str:
+    """Where a traced run of ``cell`` leaves its capture, the last one
+    taking the place of the one before: one directory a cell on the chip,
+    and one a cell and PROCESS in a rehearsal, because the tests rehearse
+    one cell from several worker processes at once and each removes what
+    it finds."""
+    return os.path.join(OUT_DIR, f"{cell}.rehearsal.{os.getpid()}"
+                        if rehearsal else cell)
 
 
 def say(msg: str) -> None:
@@ -180,8 +191,11 @@ class Run:
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0     # op and annotation events only
         options.host_tracer_level = 2
-        self.xplane_dir = os.path.join(OUT_DIR, self.cell["name"])
+        self.xplane_dir = capture_dir(self.cell["name"], self.rehearsal)
         shutil.rmtree(self.xplane_dir, ignore_errors=True)
+        if self.rehearsal:      # a process's own directory goes with it
+            atexit.register(shutil.rmtree, self.xplane_dir,
+                            ignore_errors=True)
         jax.profiler.start_trace(self.xplane_dir, profiler_options=options)
 
     def stop_trace(self) -> None:

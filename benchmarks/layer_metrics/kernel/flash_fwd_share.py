@@ -1,7 +1,7 @@
 """Share of the device's busy time, in percent, spent in the Pallas kernel
-named ``flash_fwd`` (flash attention's forward). The three
-flash shares add up to ``kernel.mosaic_share`` where no other Mosaic
-kernel runs."""
+named ``flash_fwd`` (flash attention's forward). With
+``kernel.flash_bwd_share`` it adds up to ``kernel.mosaic_share`` where no
+other Mosaic kernel runs."""
 
 from benchmarks import program_trace as pt
 

@@ -135,15 +135,17 @@ def seconds_in(trace: pt.ProgramTrace, stretches: Dict[int, tr.Interval],
 
 def main(argv) -> int:
     """The six readers on the capture the cell's last traced run left."""
-    from benchmarks.harness import (OUT_DIR, Run, load_module, open_cell,
-                                    read_layer_metrics, start_device)
+    from benchmarks.harness import (Run, capture_dir, load_module,
+                                    open_cell, read_layer_metrics,
+                                    start_device)
     rehearsal = "--rehearsal" in argv[2:]
     _, cell, config, traffic = open_cell(argv[1], rehearsal)
     _, peaks, _ = start_device(cell, rehearsal)
     run = Run(cell=cell, config=config, traffic=traffic,
               family=load_module("families", config["family"]), seed=0,
               seconds=0.0, trace=True, rehearsal=rehearsal, peaks=peaks,
-              compiles=None, xplane_dir=os.path.join(OUT_DIR, cell["name"]))
+              compiles=None,
+              xplane_dir=capture_dir(cell["name"], rehearsal))
     values, _ = read_layer_metrics([{"name": n} for n in READERS], run, {})
     for name in READERS:
         print(f"{name} {values.get(name)}")

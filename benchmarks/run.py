@@ -3,8 +3,10 @@
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The last line of standard output is one JSON object: ``correct``,
-``attempted``, ``failed``, ``metrics``, ``device`` and, with ``--trace 1``,
-``breakdown``. With ``--trace 0`` the metrics are the cell's end-to-end
+``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
+``breakdown``, and last, where the driver gives them, ``compared``: every
+number ``correct`` held beside its limit (they are the last lines of
+standard error too). With ``--trace 0`` the metrics are the cell's end-to-end
 metrics, with ``--trace 1`` its per-layer metrics. Everything else the run
 has to say goes on earlier lines.
 
@@ -118,7 +120,17 @@ def main(argv=None) -> int:
         result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
     if args.rehearsal:
         result["rehearsal"] = True
+    # Each number ``correct`` compared, beside its limit: last in the
+    # result line and the last lines of standard error, which is what the
+    # driver's record keeps of a run that is not correct.
+    compared = out.get("compared")
+    if compared:
+        result["compared"] = {k: {"value": v, "limit": limit}
+                              for k, (v, limit) in compared.items()}
     say(json.dumps(result))
+    for name, (value, limit) in (compared or {}).items():
+        print(f"compared {name}: {value:.6g} (limit {limit:g})",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -20,7 +20,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmarks import run as bench_run  # noqa: E402
-from benchmarks.harness import load_json, metrics_of  # noqa: E402
+from benchmarks.harness import load_json, metrics_of, open_cell  # noqa: E402
 
 BENCH = load_json(ROOT, "BENCHMARK.json")
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -45,8 +45,13 @@ def rehearse(capsys, cell, trace, seconds="0.3", seed="1", fresh=False):
 def test_a_cell_rehearses_end_to_end(cell, capsys):
     lines, result = rehearse(capsys, cell, 0,
                              "2" if "serve" in cell else "0.3")
-    assert set(result) == RESULT_KEYS | {"rehearsal"}
+    assert set(result) - {"compared"} == RESULT_KEYS | {"rehearsal"}
     assert result["rehearsal"] is True and result["correct"] is True, lines
+    # what correct held, each number beside its limit, comes last
+    if "compared" in result:
+        assert list(result)[-1] == "compared"
+        assert all(set(c) == {"value", "limit"} and c["value"] <= c["limit"]
+                   for c in result["compared"].values())
     assert result["attempted"] > 0 and result["failed"] == 0
     assert result["device"]["platform"] == "cpu"
     assert set(result["device"]) == {"platform", "kind", "count",
@@ -79,6 +84,33 @@ def test_a_traced_rehearsal_reports_counters_and_leaves_out_the_device(
                if wanted[n]["source"] != "program_counter")
     # compiles in the window are counted in every cell, on an earlier line
     assert any(", 0 of them inside the window" in l for l in lines)
+
+
+def test_the_serving_rehearsal_is_primed_stratified_and_replayed(capsys):
+    """The driver's own path at the rehearsal's sizes: a priming stretch
+    that is attempted and not measured, and after the window the sampled
+    requests replayed with the decode's logits captured, compiling
+    nothing and emitting the window's tokens again."""
+    cell = "gpt2m-serve-chat"
+    lines, result = rehearse(capsys, cell, 1, "2")
+    _, _, _, traffic = open_cell(cell, rehearsal=True)
+    assert traffic["prime_seconds"] and traffic["stratum_seconds"]
+    in_window = round(traffic["rate_per_s"] * 2)
+    primed = round(traffic["rate_per_s"] * traffic["prime_seconds"])
+    assert result["attempted"] == in_window + primed
+    assert any(l.startswith(f"window: {in_window} requests due in 2s")
+               and f"({in_window + primed} attempted with it)" in l
+               for l in lines)
+    compared = result["compared"]
+    assert list(compared) == [
+        "failed", "compiles_in_window", "replay_requests_that_differ",
+        "compiles_in_replay", "e_median", "e_far_share", "gap_far_share",
+        "first_gap_far_share"]
+    assert compared["replay_requests_that_differ"]["value"] == 0
+    assert compared["compiles_in_replay"]["value"] == 0
+    replay = next(l for l in lines if l.startswith("replay of "))
+    assert f"replay of {traffic['check_requests']} sampled" in replay
+    assert 0 < compared["e_median"]["value"] < compared["e_median"]["limit"]
 
 
 def test_the_same_seed_reproduces_the_losses_and_another_does_not(capsys):

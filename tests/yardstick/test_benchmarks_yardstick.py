@@ -209,10 +209,13 @@ def test_open_loop_schedule_is_a_function_of_the_seed():
     b = generate.open_loop_requests(traffic, 50257, seed=5, seconds=30)
     c = generate.open_loop_requests(traffic, 50257, seed=6, seconds=30)
     assert a == b and a != c
-    n = round(traffic["rate_per_s"] * 30)
+    prime = traffic["prime_seconds"]
+    n = round(traffic["rate_per_s"] * (30 + prime))
     assert len(a) == len(c) == n
     due = [r["due"] for r in a]
-    assert due == sorted(due) and 0 < due[0] and due[-1] < 30
+    assert due == sorted(due) and -prime < due[0] and due[-1] < 30
+    in_window = [r for r in a if r["due"] >= 0]
+    assert len(in_window) == round(traffic["rate_per_s"] * 30)
     # the same work whatever the seed: the seed only shuffles
     lengths = lambda rs, k: sorted(len(r[k]) if k == "prompt" else r[k]
                                    for r in rs)
@@ -226,10 +229,80 @@ def test_open_loop_schedule_is_a_function_of_the_seed():
     # the medians are the distributions'
     assert np.median(lengths(a, "prompt")) == pytest.approx(p["median"],
                                                             rel=0.05)
-    gaps = np.diff([0.0] + due)
+    gaps = np.diff(due)
     assert gaps.mean() == pytest.approx(1 / traffic["rate_per_s"], rel=0.02)
     # exponential: the standard deviation is about the mean
     assert gaps.std() == pytest.approx(gaps.mean(), rel=0.1)
+
+
+def test_every_stratum_holds_the_same_arrivals_and_lengths():
+    """``stratum_seconds``: the seed shuffles WITHIN a stratum, so no
+    stretch of the window is given more work than another."""
+    traffic = load_json(HERE, "traffic", "chat-poisson.json")
+    size, prime = traffic["stratum_seconds"], traffic["prime_seconds"]
+    assert (30 / size).is_integer() and (prime / size).is_integer()
+    per = round(traffic["rate_per_s"] * size)
+    seen = set()
+    for seed in (5, 6, 2**31 + 7):
+        rs = generate.open_loop_requests(traffic, 50257, seed, 30)
+        strata = {}
+        for r in rs:
+            strata.setdefault(int(np.floor(r["due"] / size)), []).append(r)
+        assert sorted(strata) == list(range(-int(prime / size),
+                                            int(30 / size)))
+        pairs = {tuple(sorted((len(r["prompt"]), r["max_new_tokens"])
+                              for r in s)) for s in strata.values()}
+        assert all(len(s) == per for s in strata.values())
+        # one multiset of (prompt, output) PAIRS: which prompt meets which
+        # output is work (positions held in the cache), so it is the
+        # stratum's and not the seed's
+        assert len(pairs) == 1
+        seen |= pairs
+        # and the order inside a stratum is the seed's
+        first = [len(r["prompt"]) for r in strata[0]]
+        assert first != sorted(first)
+    # lengths stay independent: prompt i meets output i * s mod n
+    (pairs,) = seen
+    p, o = np.array(pairs).T
+    assert abs(np.corrcoef(p, o)[0, 1]) < 0.05
+    assert len(seen) == 1               # the same pairs whatever the seed
+
+
+def test_a_stratum_as_long_as_the_window_is_the_one_stratum():
+    traffic = load_json(HERE, "traffic", "chat-poisson.json")
+    traffic["stratum_seconds"] = 10
+    rs = generate.open_loop_requests(traffic, 50257, 3, 10)
+    rate, prime = traffic["rate_per_s"], traffic["prime_seconds"]
+    assert sum(r["due"] < 0 for r in rs) == round(rate * prime)
+    assert sum(r["due"] >= 0 for r in rs) == round(rate * 10)
+    with pytest.raises(KeyError):       # the two keys are the schedule
+        generate.open_loop_requests(
+            {k: v for k, v in traffic.items() if k != "prime_seconds"},
+            50257, 3, 10)
+
+
+# sha256 of the JSON of the schedule that ``chat-poisson.json`` gives, by
+# seed and window (PR 34). The schedule is what the serving cell times: a
+# change to the generator that moves it is a change to every accepted
+# number of the cell, and shows here first.
+SCHEDULE = {(5, 30): "819f849b319462b3", (5, 2.0): "bdaec764ba66923b",
+            (5, 0.3): "c1b218e784e0d5be",
+            (2147483999, 30): "f8d94b209b2704d8",
+            (2147483999, 2.0): "2d4055d2b1de79a7",
+            (2147483999, 0.3): "e71f6eec0f204b1f"}
+
+
+@pytest.mark.parametrize("seed,seconds", list(SCHEDULE))
+def test_the_schedule_of_a_seed_is_the_one_the_cell_was_measured_on(
+        seed, seconds):
+    import hashlib
+    traffic = load_json(HERE, "traffic", "chat-poisson.json")
+    got = generate.open_loop_requests(traffic, 50257, seed, seconds)
+    assert hashlib.sha256(json.dumps(got).encode()).hexdigest()[:16] \
+        == SCHEDULE[(seed, seconds)]
+    prime = traffic["prime_seconds"]
+    assert sum(r["due"] < 0 for r in got) == round(
+        traffic["rate_per_s"] * prime)
 
 
 def test_percentile_helpers():

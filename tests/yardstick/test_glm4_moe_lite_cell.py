@@ -242,7 +242,7 @@ def test_the_cell_is_appended_to_the_metrics_it_reports():
         assert find(BENCH["per_layer"], name, "metric")["workloads"][-1] \
             == CELL, name
     reported = [m["name"] for m in metrics_of(BENCH, "per_layer", CELL)]
-    assert reported == APPENDED_TO
+    assert reported == APPENDED_TO + NEW
     # kernel.flash_roofline divides by ALL Mosaic time, and in this cell
     # XLA's grouped-matmul kernel is Mosaic time too: the cell stays off
     # it until its reader takes the flash kernels by name
@@ -251,13 +251,21 @@ def test_the_cell_is_appended_to_the_metrics_it_reports():
 
 
 @pytest.mark.parametrize("name", NEW)
-def test_a_reader_waits_for_its_entry(name):
-    """The reader is a file the harness can load; no entry lists it yet
-    (the module's docstring says why), and ``moe_trace.py`` runs it by
-    hand."""
+def test_a_reader_has_its_entry(name):
+    """Listed since PR 34 (the pins that kept them out are rules now,
+    ``test_program_trace.py``): the reader is a file the harness loads,
+    the entry names this cell alone, and ``moe_trace.py`` still runs the
+    six by hand on a capture."""
     assert callable(reader(name))
     assert name in mt.READERS
-    assert name not in [m["name"] for m in BENCH["per_layer"]]
+    entry = find(BENCH["per_layer"], name, "metric")
+    assert entry["workloads"] == [CELL]
+    assert entry["moves"] == "tokens_per_s_per_chip"
+    assert entry["layer"] == ("experts" if name.startswith("moe.")
+                              else "model")
+    assert entry["source"] == ("program_counter" if name ==
+                               "moe.held_load_max_over_mean"
+                               else "device_trace")
 
 
 # ---------------------------------------------------------------------------
@@ -425,20 +433,26 @@ def test_a_traced_rehearsal_ends_correct_and_reports_the_counter(capsys):
     got = result["metrics"]
     wanted = {m["name"]: m for m in metrics_of(BENCH, "per_layer", CELL)}
     assert set(got) <= set(wanted)
-    # no device plane on the CPU: only the counter is read
+    # no device plane on the CPU: only the counters are read
     assert not [n for n in got if wanted[n]["source"] == "device_trace"]
     assert got["setup.compiles_in_window"]["value"] == 0
-    # the waiting readers, by hand, on the capture that run left: four
-    # traced steps, and the counters of the two before them and of the
-    # first two of them
+    load = got["moe.held_load_max_over_mean"]["value"]
+    assert load >= 1.0
+    # the same six readers by hand, on the capture that run left. The
+    # program emits a step's counters only once the device has finished
+    # it and keeps two steps' worth (``_trace_step_counters``), so how
+    # many of the traced steps' counters reach the capture follows the
+    # machine's load: four on a quiet one (the two steps before the traced
+    # ones and the first two of them), fewer or more under ``-n 6``, which
+    # is what made this test unsteady in the driver's run of PR 32's tree.
     assert mt.main(["moe_trace.py", CELL, "--rehearsal"]) == 0
     lines = capsys.readouterr().out.strip().splitlines()
     (told,) = [l for l in lines
                if l.startswith("step counters in the traced window")]
-    assert told.count("of_step") == TRAFFIC["trace_steps"]
+    assert 1 <= told.count("of_step") <= TRAFFIC["trace_steps"] + 2
     by_hand = dict(l.split(" ", 1) for l in lines[-len(mt.READERS):])
     assert list(by_hand) == list(mt.READERS)
-    assert float(by_hand.pop("moe.held_load_max_over_mean")) >= 1.0
+    assert float(by_hand.pop("moe.held_load_max_over_mean")) == load
     assert set(by_hand.values()) == {"None"}
     # the rehearsal holds 4 of 8 experts, 2 a token
     _, _, config, _ = open_cell(CELL, rehearsal=True)
