@@ -1210,7 +1210,8 @@ class ServingConfig:
         if cfg.resil_slow_step_ms is not None and cfg.resil_slow_step_ms <= 0:
             raise ConfigError(
                 "serving.resilience.slow_step_ms must be > 0")
-        if cfg.chunked_token_budget < cfg.max_batch_size:
+        if cfg.chunked_prefill \
+                and cfg.chunked_token_budget < cfg.max_batch_size:
             raise ConfigError(
                 "serving.chunked_prefill.token_budget must be >= "
                 "max_batch_size (every decoding slot needs a row in each "

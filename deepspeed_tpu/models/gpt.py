@@ -426,6 +426,31 @@ class GPT(nn.Module):
         return out
 
 
+    # -- what the serving engine asks of a model (serving/engine.py) -----
+    def serving_cache_spec(self) -> Tuple:
+        """One ``LayerCacheSpec`` a layer: every block keeps keys and
+        values, a key/value head a query head."""
+        from deepspeed_tpu.serving.kv_cache import kv
+        return tuple(kv(self.cfg.num_heads, self.cfg.head_dim)
+                     for _ in range(self.cfg.num_layers))
+
+    def serve_prefill(self, params, ids, length, dtype=None):
+        """One right-padded prompt ``ids [1, bucket]`` -> ``{"logits",
+        "cache"}``, ``cache[i]`` the layer's ``(k, v)`` ``[1, bucket, H,
+        D]``. Causality keeps the padding out of the real positions, so
+        ``length`` is not needed here."""
+        cache = init_kv_cache(self.cfg, 1, ids.shape[1], dtype=dtype)
+        return self.apply({"params": params}, {"input_ids": ids},
+                          deterministic=True, cache=cache, pos=0)
+
+    def serve_decode(self, params, ids, pos_ids, cache, live=None):
+        """``ids`` / ``pos_ids [rows, S]`` through the paged ``cache`` (a
+        layer's view each) -> ``{"logits", "cache"}``."""
+        return self.apply({"params": params},
+                          {"input_ids": ids, "position_ids": pos_ids},
+                          deterministic=True, cache=cache, pos=None)
+
+
 def init_kv_cache(cfg: GPTConfig, batch_size: int, max_len: int,
                   dtype=None) -> Tuple:
     """Per-layer (k, v) cache arrays [B, max_len, H, Dh] for incremental

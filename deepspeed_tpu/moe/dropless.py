@@ -33,6 +33,24 @@ although the number of held assignments depends on the data:
 
 ``DroplessMoE.__call__(x)`` returns ``(y, counters)``; the counters are
 float32 scalars a model hands to the engine under ``step_counters``.
+
+Two more shapes of the same layer, for Nemotron-H's latent experts
+(``models/nemotron_h.py``), which is also where the layer is called at
+decode and prefill of the serving engine:
+
+- ``latent_size > 0``: the routed experts read and write a latent of that
+  width, ``x W_in`` (``latent_in [D, L]``), and their summed result goes
+  back through ``latent_out [L, D]``. The router still reads the full
+  width ``x``, and so does the shared expert. ``latent_out`` is linear, so
+  the parts of the chips still add up.
+- ``activation="relu2"``: an expert is ``W_down relu(W_up x)^2`` with no
+  gate (``experts_gate`` and ``shared_gate`` do not exist).
+- ``__call__(x, live=...)``: rows that are not ``live`` (a dead slot of
+  the decode batch, the padding of a prompt's bucket) are routed nowhere:
+  they take no row of any group, so the grouped matmul's work and the
+  counters follow the rows that are there. With ``live`` the counters
+  are the int32 ``SERVING_COUNTERS`` the serving programs return beside
+  the token.
 """
 
 from dataclasses import dataclass
@@ -59,8 +77,25 @@ class DroplessMoEConfig:
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
+    latent_size: int = 0                # > 0: routed experts on a latent
+    activation: str = "swiglu"          # or "relu2": no gate
+    # float32: the latent's way out and the shared expert's last matmul
+    # hand over their float32 accumulators and are summed unrounded, for a
+    # caller that adds the result to its residual before rounding once
+    out_dtype: Any = None
+    # the shared expert's two matmuls read their float32 inputs (``x`` as
+    # it is handed in, then ``relu(.)^2``) as TWO bfloat16 halves, hi + lo:
+    # 16 bits of mantissa where one rounding keeps 8 (``_in_two_halves``)
+    shared_two_pass: bool = False
 
     def __post_init__(self):
+        if self.activation not in ("swiglu", "relu2"):
+            raise ValueError(f"activation {self.activation!r}: 'swiglu' or "
+                             f"'relu2'")
+        if self.shared_two_pass and (self.activation != "relu2"
+                                     or self.out_dtype is None):
+            raise ValueError("shared_two_pass is for the relu2 shared expert "
+                             "with a float32 out_dtype")
         last = self.first_held_expert + self.n_held_experts
         if not 0 <= self.first_held_expert < last <= self.n_routed_experts:
             raise ValueError(
@@ -126,18 +161,27 @@ def _unsorted_rows_bwd(res, g):
 _unsorted_rows.defvjp(_unsorted_rows_fwd, _unsorted_rows_bwd)
 
 
+SERVING_COUNTERS = ("held_assignments", "experts_touched", "held_rows_max")
+
+
 def held_expert_part(x, chosen, weights, w_gate, w_up, w_down, *,
-                     first_held: int) -> Tuple[jax.Array, Dict[str, Any]]:
+                     first_held: int, live=None
+                     ) -> Tuple[jax.Array, Dict[str, Any]]:
     """The held experts' part of the layer's result for ``x [T, D]``:
-    ``sum over chosen e in [first, first + held) of w_e SwiGLU_e(x)``,
+    ``sum over chosen e in [first, first + held) of w_e expert_e(x)``,
     and the counters of this call. ``w_gate`` / ``w_up`` are
-    ``[held, D, F]``, ``w_down`` is ``[held, F, D]``."""
+    ``[held, D, F]``, ``w_down`` is ``[held, F, D]``; an expert is SwiGLU,
+    or with ``w_gate`` None ``w_down relu(w_up x)^2``. Rows that are not
+    ``live`` (``[T]`` bool) choose no expert; with ``live`` the counters
+    are the int32 ``SERVING_COUNTERS``."""
     t, d = x.shape
     k = chosen.shape[1]
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     with device_scope("moe_dispatch"):
         local = chosen.reshape(-1) - first_held
         is_held = (local >= 0) & (local < held)
+        if live is not None:
+            is_held = is_held & jnp.repeat(live, k)
         local = jnp.where(is_held, local, held)     # not held: sorts last
         order = jnp.argsort(local, stable=True).astype(jnp.int32)
         inverse = jnp.zeros_like(order).at[order].set(
@@ -150,9 +194,14 @@ def held_expert_part(x, chosen, weights, w_gate, w_up, w_down, *,
         # Rows past the last group are no expert's: the kernel leaves
         # them as it finds them, so they are zeroed on the way in and out.
         dot = partial(jax.lax.ragged_dot, group_sizes=sizes)
-        gate = jnp.where(in_a_group, dot(rows, w_gate), 0)
-        up = jnp.where(in_a_group, dot(rows, w_up), 0)
-        out = jnp.where(in_a_group, dot(jax.nn.silu(gate) * up, w_down), 0)
+        if w_gate is None:
+            up = jnp.where(in_a_group, dot(rows, w_up), 0)
+            mid = jnp.square(jax.nn.relu(up))
+        else:
+            gate = jnp.where(in_a_group, dot(rows, w_gate), 0)
+            up = jnp.where(in_a_group, dot(rows, w_up), 0)
+            mid = jax.nn.silu(gate) * up
+        out = jnp.where(in_a_group, dot(mid, w_down), 0)
     with device_scope("moe_combine"):
         per_choice = _unsorted_rows(out, order, inverse).reshape(t, k, d)
         is_held = is_held.reshape(t, k)
@@ -160,6 +209,10 @@ def held_expert_part(x, chosen, weights, w_gate, w_up, w_down, *,
         y = jnp.einsum("tkd,tk->td", per_choice,
                        held_weights.astype(per_choice.dtype),
                        preferred_element_type=jnp.float32).astype(x.dtype)
+    if live is not None:
+        return y, {"held_assignments": sizes.sum(),
+                   "experts_touched": (sizes > 0).sum(dtype=jnp.int32),
+                   "held_rows_max": sizes.max()}
     f32 = jnp.float32
     counters = {
         "held_assignments_per_token": sizes.sum().astype(f32) / t,
@@ -171,18 +224,43 @@ def held_expert_part(x, chosen, weights, w_gate, w_up, w_down, *,
     return y, counters
 
 
+def _in_two_halves(dense: nn.Module, x: jax.Array, dtype) -> jax.Array:
+    """``dense(x)`` for a float32 ``x`` and a ``dense`` that takes
+    bfloat16 and hands back float32: ``x`` goes in as ``hi`` (its upper 16
+    bits: sign, exponent, 7 bits of mantissa, which IS a bfloat16) and
+    ``lo = round(x - hi)``, stacked as ``2 T`` rows of ONE matmul (the
+    weights are read once; at decode that is what the time is), and the two
+    halves of the result are added. What is lost of ``x`` is under
+    ``2^-16`` of it where ``x.astype(bfloat16)`` alone loses up to
+    ``2^-9``. ``hi`` is cut by a mask on the bits and not by
+    ``x.astype(bfloat16).astype(float32)``: the TPU's compiler drops such a
+    round trip as excess precision it is allowed to keep, ``lo`` is then 0
+    and the second pass reads nothing (my chip run, PR 35)."""
+    if dtype != jnp.bfloat16:           # float32 weights: nothing to halve
+        return dense(x.astype(dtype))
+    x = x.astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    hi = jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                      jnp.float32)
+    both = dense(jnp.concatenate([hi, x - hi], axis=0).astype(dtype))
+    return both[:x.shape[0]] + both[x.shape[0]:]
+
+
 class DroplessMoE(nn.Module):
     """Input ``[B, S, D]`` -> ``([B, S, D], counters)``."""
 
     cfg: DroplessMoEConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, live=None):
         cfg = self.cfg
         b, s, d = x.shape
         f, held, dt = cfg.expert_intermediate, cfg.n_held_experts, cfg.dtype
         init = nn.initializers.normal(0.02)
-        h = x.reshape(b * s, d).astype(dt)
+        flat = x.reshape(b * s, d)
+        h = flat.astype(dt)
+        gated = cfg.activation == "swiglu"
+        dense = partial(nn.Dense, use_bias=False, dtype=dt, kernel_init=init)
 
         router = self.param("router", init, (d, cfg.n_routed_experts),
                             jnp.float32)
@@ -192,25 +270,54 @@ class DroplessMoE(nn.Module):
         bias = self.param("e_score_correction_bias", nn.initializers.zeros,
                           (cfg.n_routed_experts,), jnp.float32)
         with device_scope("moe_route"):
+            # the router reads x as it is handed in: a caller that keeps
+            # its norm's float32 output spares the scores one rounding
             chosen, weights = route(
-                h, router, bias, k=cfg.experts_per_token,
+                flat, router, bias, k=cfg.experts_per_token,
                 scaling_factor=cfg.routed_scaling_factor,
                 norm_topk_prob=cfg.norm_topk_prob)
 
-        w_gate = self.param("experts_gate", init, (held, d, f), jnp.float32)
-        w_up = self.param("experts_up", init, (held, d, f), jnp.float32)
-        w_down = self.param("experts_down", init, (held, f, d), jnp.float32)
+        # what the routed experts read and write: x itself, or a latent
+        wide = cfg.latent_size or d
+        rows = h
+        if cfg.latent_size:
+            with device_scope("moe_dispatch"):
+                rows = dense(wide, name="latent_in")(h)
+        w_gate = (self.param("experts_gate", init, (held, wide, f),
+                             jnp.float32).astype(dt) if gated else None)
+        w_up = self.param("experts_up", init, (held, wide, f), jnp.float32)
+        w_down = self.param("experts_down", init, (held, f, wide),
+                            jnp.float32)
         y, counters = held_expert_part(
-            h, chosen, weights, w_gate.astype(dt), w_up.astype(dt),
-            w_down.astype(dt), first_held=cfg.first_held_expert)
+            rows, chosen, weights, w_gate, w_up.astype(dt),
+            w_down.astype(dt), first_held=cfg.first_held_expert,
+            live=None if live is None else live.reshape(b * s))
+        last = dense if cfg.out_dtype is None else partial(
+            dense, dot_general=partial(
+                jax.lax.dot_general, preferred_element_type=cfg.out_dtype))
+        if cfg.latent_size:
+            with device_scope("moe_combine"):
+                y = last(d, name="latent_out")(y)
 
         if cfg.shared_intermediate:
             with device_scope("moe_shared"):
-                dense = partial(nn.Dense, use_bias=False, dtype=dt,
-                                kernel_init=init)
-                gate = dense(cfg.shared_intermediate, name="shared_gate")(h)
-                up = dense(cfg.shared_intermediate, name="shared_up")(h)
-                y = y + dense(d, name="shared_down")(nn.silu(gate) * up)
+                gate = (dense(cfg.shared_intermediate, name="shared_gate")(h)
+                        if gated else None)
+                if cfg.shared_two_pass:
+                    # the shared expert is most of what this layer adds to
+                    # the stream, and relu^2 doubles a relative error: its
+                    # one-pass roundings are what flips most near ties of
+                    # the routers after it (PERF.md section 6, PR 35)
+                    up = _in_two_halves(
+                        last(cfg.shared_intermediate, name="shared_up"),
+                        flat, dt)
+                    y = y + _in_two_halves(last(d, name="shared_down"),
+                                           jnp.square(nn.relu(up)), dt)
+                else:
+                    up = dense(cfg.shared_intermediate, name="shared_up")(h)
+                    mid = (nn.silu(gate) * up if gated
+                           else jnp.square(nn.relu(up)))
+                    y = y + last(d, name="shared_down")(mid)
         return y.reshape(b, s, d), counters
 
 
@@ -220,5 +327,5 @@ def dropless_partition_rules() -> Tuple[Tuple[str, Tuple], ...]:
     return (
         (r".*experts_(gate|up|down)$", (EXPERT_AXIS, None, None)),
         (r".*(router|e_score_correction_bias)$", None),
-        (r".*shared_(gate|up|down)/kernel$", None),
+        (r".*(shared_(gate|up|down)|latent_(in|out))/kernel$", None),
     )

@@ -23,6 +23,30 @@ in steady state**:
   host-side numpy inputs, which XLA never sees as a new signature.
 - scheduling between steps is pure host python (microseconds).
 
+**What the engine asks of a model** (GPT and Nemotron-H answer; any module
+with a ``cfg`` that does can be served):
+
+- ``model.serving_cache_spec()``: one ``LayerCacheSpec`` a layer
+  (``serving/kv_cache.py``): ``kv(heads, head_dim)`` (a K and a V pool,
+  sized by the layer's KEY/VALUE heads), ``recurrent(shapes)`` (state a
+  slot, which does not grow with positions: a Mamba-2 layer's) or
+  ``none()``;
+- ``model.serve_prefill(params, ids [1, bucket], length)`` ->
+  ``{"logits", "cache"[, "counters"]}``: per ``kv`` layer the prompt's
+  ``(k, v)``, per ``recurrent`` layer the arrays the slot keeps, as they
+  stand after the prompt's last real position;
+- ``model.serve_decode(params, ids, pos_ids, cache[, live])`` ->
+  the same keys, ``cache`` a view a layer (``PagedLayerCache``,
+  ``RecurrentLayerState``, None).
+
+What recurrent state cannot do yet is refused at construction, never
+served wrong: the prefix cache (a prompt head's blocks say nothing of the
+state behind them), speculative decoding and chunked prefill (both push
+several positions a row through the decode-side program; a recurrent
+layer's state advances one), and ``serving.resilience`` (its recovery
+rebuilds blocks alone). Preemption is NOT refused: an evicted request
+restarts from its prompt, whose prefill writes the slot's state whole.
+
 SLO telemetry rides the established contract: metrics through the
 ``MetricsRegistry`` (no sinks -> no-ops), spans through the ``StepTracer``
 (disabled -> reusable null span, zero device syncs), and
@@ -42,7 +66,8 @@ from deepspeed_tpu.inference.engine import (InferenceEngine, bucket_length,
                                             sample_logits)
 from deepspeed_tpu.serving.kv_cache import (BlockPool, ChunkedLayerCache,
                                             PagedLayerCache,
-                                            init_paged_pools,
+                                            RecurrentLayerState,
+                                            init_serving_state,
                                             live_block_list, pack_prefill)
 from deepspeed_tpu.serving.scheduler import (PrefixCache, Scheduler,
                                              Sequence)
@@ -89,7 +114,8 @@ SERVING_METRIC_TAGS = frozenset({
 
 
 def resolve_decode_attention(mode: str, tpu: bool, tiles: bool,
-                             geometry: str = "") -> str:
+                             geometry: str = "", grouped: bool = False
+                             ) -> str:
     """Which program decodes (docs/SERVING.md "Decode fast path"), from
     ``serving.decode_attention``, the platform and the kernel's gate
     (``paged_decode_ok``). ``"gather"``: the default decode, over the flat
@@ -97,9 +123,21 @@ def resolve_decode_attention(mode: str, tpu: bool, tiles: bool,
     decode-attention kernel over the table's width; the compiled kernel
     tiles only head_dim % 128 / block % 8 geometries, off the TPU the
     interpreter takes any. ``"auto"``: the kernel on a TPU where it tiles,
-    else the default decode."""
+    else the default decode. ``grouped`` (a key/value head serves several
+    query heads): both of those are written for one query head a
+    key/value head, so the answer is ``"window"``, the model's own
+    attention over each row's gathered table window
+    (``PagedLayerCache.update``), and ``"kernel"`` is refused."""
     from deepspeed_tpu.config.config import ConfigError
 
+    if grouped:
+        if mode == "kernel":
+            raise ConfigError(
+                f"serving.decode_attention='kernel': the paged decode "
+                f"kernel takes one query head a key/value head; this "
+                f"model's attention is grouped ({geometry}) — use 'auto' "
+                f"or 'gather'")
+        return "window"
     if mode == "kernel":
         if tpu and not tiles:
             raise ConfigError(
@@ -116,8 +154,10 @@ def resolve_decode_attention(mode: str, tpu: bool, tiles: bool,
 class ServeEngine:
     """Continuous-batching serving engine over an :class:`InferenceEngine`.
 
-    ``engine``: an InferenceEngine wrapping a cache-capable causal LM (the
-    in-tree GPT family). ``config``: a parsed ``ServingConfig`` (or None
+    ``engine``: an InferenceEngine wrapping a causal LM that answers the
+    three questions of the module docstring (``serving_cache_spec``,
+    ``serve_prefill``, ``serve_decode``: in-tree, GPT and Nemotron-H).
+    ``config``: a parsed ``ServingConfig`` (or None
     for defaults). ``telemetry``: the run's ``Telemetry`` facade — omit it
     (or pass a disabled one) and the engine performs zero telemetry
     work beyond host float arithmetic.
@@ -143,10 +183,18 @@ class ServeEngine:
         from deepspeed_tpu.config.config import ConfigError, ServingConfig
         from deepspeed_tpu.telemetry import null_telemetry
 
-        if engine.model_cfg is None or not hasattr(engine.module, "cfg"):
+        asked = ("serving_cache_spec", "serve_prefill", "serve_decode")
+        lacks = [name for name in asked
+                 if not callable(getattr(engine.module, name, None))]
+        if engine.model_cfg is None or lacks:
             raise ValueError(
-                "ServeEngine needs a cache-capable in-tree causal LM "
-                f"(the GPT family); {type(engine.module).__name__} is not")
+                f"ServeEngine needs a cache-capable causal LM: a module "
+                f"with a ``cfg`` that says what its layers cache and how "
+                f"to run them through it "
+                f"({', '.join(asked)}: models/gpt.py and "
+                f"models/nemotron_h.py do); "
+                f"{type(engine.module).__name__} lacks "
+                f"{', '.join(lacks) or 'cfg'}")
         self.engine = engine
         self.module = engine.module
         self.model_cfg = engine.model_cfg
@@ -154,6 +202,39 @@ class ServeEngine:
         self.telemetry = telemetry if telemetry is not None \
             else null_telemetry()
         self.capture_logits = bool(capture_logits)
+
+        # What each layer keeps between steps, by the model's own word.
+        self._specs = tuple(engine.module.serving_cache_spec())
+        self._kinds = tuple(spec.kind for spec in self._specs)
+        kv_specs = [spec for spec in self._specs if spec.kind == "kv"]
+        if len({(sp.heads, sp.head_dim) for sp in kv_specs}) > 1:
+            raise ValueError("the kv layers of a model share one block "
+                             "table and so one (heads, head_dim)")
+        self._stateful = "recurrent" in self._kinds
+        self._all_kv = set(self._kinds) == {"kv"}
+        if not self._all_kv:
+            refused = [
+                (self.scfg.prefix_cache, "serving.prefix_cache",
+                 "a prompt head's KV blocks say nothing of the recurrent "
+                 "state behind them"),
+                (self.scfg.spec_decode, "serving.speculative",
+                 "a verify chunk pushes several positions a row through "
+                 "the decode program and a recurrent state advances one"),
+                (self.scfg.chunked_prefill, "serving.chunked_prefill",
+                 "the mixed program pushes a prompt through the "
+                 "decode-side cache in chunks and a recurrent state "
+                 "advances one position a step"),
+                (self.scfg.resilience, "serving.resilience",
+                 "its recovery rebuilds KV blocks alone")]
+            for on, option, why in refused:
+                if on:
+                    raise ConfigError(
+                        f"{option} cannot be served with layers that keep "
+                        f"anything but keys and values: this model "
+                        f"({type(engine.module).__name__}) has "
+                        + ", ".join(f"{self._kinds.count(k)} {k}"
+                                    for k in sorted(set(self._kinds)))
+                        + f" layers; {why}")
 
         model_max = int(getattr(self.model_cfg, "max_seq_len"))
         self.max_model_len = min(self.scfg.max_model_len or model_max,
@@ -177,9 +258,10 @@ class ServeEngine:
                                prefix_cache=self.prefix_cache)
         self._dtype = engine.config.dtype
         self._dtype_name = jnp.dtype(self._dtype).name
-        self._pools = init_paged_pools(
-            self.model_cfg, self.scfg.kv_num_blocks, bs,
-            int8=self.scfg.int8_kv_cache, dtype=self._dtype)
+        self._pools = init_serving_state(
+            self._specs, self.scfg.kv_num_blocks, bs,
+            self.scfg.max_batch_size, int8=self.scfg.int8_kv_cache,
+            dtype=self._dtype)
 
         self._prefill_jit: Dict[int, Any] = {}
         # -- decode fast path (docs/SERVING.md "Decode fast path") ------
@@ -192,10 +274,17 @@ class ServeEngine:
             paged_decode_ok
         mode = self.scfg.decode_attention
         tpu = on_tpu()
-        tiles = paged_decode_ok(self.model_cfg.head_dim, bs)
-        geometry = f"head_dim={self.model_cfg.head_dim}, block_size={bs}"
+        head_dim = kv_specs[0].head_dim if kv_specs \
+            else self.model_cfg.head_dim
+        tiles = paged_decode_ok(head_dim, bs)
+        geometry = f"head_dim={head_dim}, block_size={bs}"
+        q_heads = int(getattr(self.model_cfg, "num_heads", 0))
+        grouped = bool(kv_specs) and q_heads != kv_specs[0].heads
+        if grouped:
+            geometry = (f"{q_heads} query heads on {kv_specs[0].heads} "
+                        f"key/value heads, {geometry}")
         self._attn_impl = resolve_decode_attention(mode, tpu, tiles,
-                                                   geometry)
+                                                   geometry, grouped)
         log_dist(f"serving: decode_attention={mode!r} resolved to "
                  f"{self._attn_impl!r} ({geometry}, platform "
                  f"{jax.devices()[0].platform})", ranks=[0])
@@ -281,7 +370,13 @@ class ServeEngine:
         # and copies it per token (same rationale as the training
         # engine's donated TrainState). Backends without donation (CPU
         # tier-1) just warn and copy.
-        self._pack_jit = jax.jit(pack_prefill, donate_argnums=(0,))
+        self._pack_jit = jax.jit(pack_prefill, static_argnames=("kinds",),
+                                 donate_argnums=(0,))
+        # The names of the int32 counters a model's serving programs
+        # return beside the token (Nemotron-H: its expert layers'), which
+        # ride the decode and prefill spans; fetched WITH the token.
+        self._counter_names = tuple(
+            getattr(engine.module, "SERVING_COUNTERS", ()))
         self._base_key = jax.random.PRNGKey(self.scfg.seed)
         self._step_count = 0
         # Cumulative decode work behind the throughput gauge: a
@@ -677,18 +772,41 @@ class ServeEngine:
         # retrace under any of these names is a real bug.
         self.engine.recompile_detector.check(
             f"serving.prefill_b{bucket}", dev_ids, length)
+        with self._prefill_span(seq, bucket, t) as span:
+            tok, counters = self._prefill_and_pack(
+                seq, bucket, dev_ids, length, rng, measure=self._measure_kv)
+            first = int(tok)                     # host fetch = first token
+            self._note_counters(span, counters)
+        self._record_first_token(seq, first)
+
+    def _note_counters(self, span, counters) -> None:
+        """A model's own counters onto the span of the dispatch that made
+        them; called after the token's fetch, so they are ready and cost
+        no wait of their own."""
+        if counters is not None:
+            span.set_metadata(**dict(zip(
+                self._counter_names, map(int, np.asarray(counters)))))
+
+    def _prefill_and_pack(self, seq: Sequence, bucket: int, dev_ids, length,
+                          rng, measure: bool = False):
+        """Dispatch the bucket's prefill program and the pack that puts
+        what it made where the slot keeps it: the prompt's keys and values
+        into the sequence's blocks, a recurrent layer's state into row
+        ``seq.slot`` of that layer's arrays, whole (so a slot's earlier
+        tenant leaves nothing behind). Returns the sampled token and the
+        model's counters (None where it has none), both still on the
+        device."""
         if bucket not in self._prefill_jit:
             self._prefill_jit[bucket] = jax.jit(functools.partial(
                 self._prefill_impl, bucket=bucket))
-        with self._prefill_span(seq, bucket, t):
-            tok, _logits, ks, vs = self._prefill_jit[bucket](
-                self.engine.params, dev_ids, length, rng)
-            if self._measure_kv:
-                self._emit_kv_quant_error(ks, vs, length, bucket)
-            blocks = jnp.asarray(seq.block_table, jnp.int32)
-            self._pools = self._pack_jit(self._pools, blocks, ks, vs)
-            first = int(tok)                     # host fetch = first token
-        self._record_first_token(seq, first)
+        tok, _logits, ks, vs, states, counters = self._prefill_jit[bucket](
+            self.engine.params, dev_ids, length, rng)
+        if measure:
+            self._emit_kv_quant_error(ks, vs, length, bucket)
+        self._pools = self._pack_jit(
+            self._pools, jnp.asarray(seq.block_table, jnp.int32), ks, vs,
+            jnp.asarray(seq.slot, jnp.int32), states, kinds=self._kinds)
+        return tok, counters
 
     def _prefill_tail(self, seq: Sequence) -> None:
         """Prefill only the unshared prompt tail through the paged cache:
@@ -799,14 +917,8 @@ class ServeEngine:
         length = jnp.asarray(t, jnp.int32)
         self.engine.recompile_detector.check(
             f"serving.prefill_b{bucket}", dev_ids, length)
-        if bucket not in self._prefill_jit:
-            self._prefill_jit[bucket] = jax.jit(functools.partial(
-                self._prefill_impl, bucket=bucket))
         with self._prefill_span(seq, bucket, t, replay=1):
-            _tok, _logits, ks, vs = self._prefill_jit[bucket](
-                self.engine.params, dev_ids, length, rng)
-            blocks = jnp.asarray(seq.block_table, jnp.int32)
-            self._pools = self._pack_jit(self._pools, blocks, ks, vs)
+            self._prefill_and_pack(seq, bucket, dev_ids, length, rng)
 
     def _replay_chunked(self, seq: Sequence, replay: List[int]) -> None:
         """Chunked-mode replay: rebuild ``[shared_len, len(replay))`` in
@@ -836,10 +948,8 @@ class ServeEngine:
             for i in range(self.model_cfg.num_layers))
         pos_ids = jnp.minimum(start[:, None] + jnp.arange(tail_bucket),
                               self.model_cfg.max_seq_len - 1)
-        out = self.module.apply(
-            {"params": self.engine._materialized(params)},
-            {"input_ids": ids, "position_ids": pos_ids},
-            deterministic=True, cache=cache, pos=None)
+        out = self.module.serve_decode(
+            self.engine._materialized(params), ids, pos_ids, cache)
         last = jax.lax.dynamic_index_in_dim(out["logits"], length - 1,
                                             axis=1, keepdims=False)  # [1,V]
         tok = sample_logits(last.astype(jnp.float32), rng,
@@ -848,22 +958,27 @@ class ServeEngine:
 
     @device_scope("prefill")
     def _prefill_impl(self, params, ids, length, rng, *, bucket: int):
-        from deepspeed_tpu.models.gpt import init_kv_cache
-
-        cache = init_kv_cache(self.model_cfg, 1, bucket, dtype=self._dtype)
-        out = self.module.apply(
-            {"params": self.engine._materialized(params)},
-            {"input_ids": ids}, deterministic=True, cache=cache, pos=0)
+        out = self.module.serve_prefill(
+            self.engine._materialized(params), ids, length,
+            dtype=self._dtype)
         # Right-padded prompt: causality alone keeps pad positions out of
         # every real token's attention, so the last REAL position's logits
-        # are exact; pad-position K/V are garbage the position mask hides.
+        # are exact; pad-position K/V are garbage the position mask hides
+        # (and a recurrent layer hands back its state as it stood after
+        # position ``length - 1``: the model's business).
         last = jax.lax.dynamic_index_in_dim(out["logits"], length - 1,
                                             axis=1, keepdims=False)  # [1,V]
         tok = sample_logits(last.astype(jnp.float32), rng,
                             self.scfg.temperature, self.scfg.top_k)[0]
-        k_stack = jnp.stack([c[0][0] for c in out["cache"]])  # [L,Tb,H,D]
-        v_stack = jnp.stack([c[1][0] for c in out["cache"]])
-        return tok, last, k_stack, v_stack
+        of_kind = lambda kind: [c for c, k in zip(out["cache"], self._kinds)
+                                if k == kind]
+        kvs = of_kind("kv")
+        k_stack = jnp.stack([c[0][0] for c in kvs]) if kvs else None
+        v_stack = jnp.stack([c[1][0] for c in kvs]) if kvs else None
+        # k_stack, v_stack: [kv layers, Tb, H, D]; a model with no
+        # recurrent layer and no counters adds no output to the program
+        return (tok, last, k_stack, v_stack, tuple(of_kind("recurrent")),
+                out.get("counters"))
 
     # -- decode ---------------------------------------------------------
     def _decode_round(self, active: List[Sequence],
@@ -1006,7 +1121,7 @@ class ServeEngine:
         return {"step": self._step_count, "active": active, **counts}
 
     def _decode(self, active: List[Sequence]):
-        if self._attn_impl == "kernel":
+        if self._attn_impl in ("kernel", "window"):
             args, ids = self._dispatch_batch(
                 active, 1, "serving.decode_step")
         else:
@@ -1018,29 +1133,45 @@ class ServeEngine:
                 functools.partial(self._decode_impl,
                                   attn_impl=self._attn_impl),
                 donate_argnums=(1,))
-        with self.telemetry.span("decode_step", **ids):
-            tok_dev, logits, self._pools = self._decode_jit(
-                self.engine.params, self._pools, bt, pos, toks, rng, *live)
+        more = {}
+        if not self._all_kv:
+            # which rows hold a request: a dead slot's recurrent state
+            # stays as it is and its row is routed to no expert
+            alive = np.zeros((self.scfg.max_batch_size,), bool)
+            alive[[s.slot for s in active]] = True
+            more["alive"] = jnp.asarray(alive)
+            if self._stateful:
+                ids["state_slots_live"] = len(active)
+        with self.telemetry.span("decode_step", **ids) as span:
+            tok_dev, logits, self._pools, counters = self._decode_jit(
+                self.engine.params, self._pools, bt, pos, toks, rng, *live,
+                **more)
             tok_host = np.asarray(tok_dev)       # host fetch: finish checks
+            self._note_counters(span, counters)
         logits_host = np.asarray(logits) if self.capture_logits else None
         return [int(tok_host[s.slot]) for s in active], logits_host
 
     @device_scope("decode")
     def _decode_impl(self, params, pools, bt, pos, toks, rng, live=None,
-                     n_chunks=None, *, attn_impl: str = "gather"):
-        cache = tuple(
-            PagedLayerCache(*pools[i], bt, pos, self.block_size,
-                            self._dtype_name, attn_impl, live=live,
-                            n_chunks=n_chunks)
-            for i in range(self.model_cfg.num_layers))
-        out = self.module.apply(
-            {"params": self.engine._materialized(params)},
-            {"input_ids": toks[:, None], "position_ids": pos[:, None]},
-            deterministic=True, cache=cache, pos=None)
+                     n_chunks=None, alive=None, *,
+                     attn_impl: str = "gather"):
+        def view(kind, pool):
+            if kind == "kv":
+                return PagedLayerCache(*pool, bt, pos, self.block_size,
+                                       self._dtype_name, attn_impl,
+                                       live=live, n_chunks=n_chunks)
+            return (RecurrentLayerState(pool, alive)
+                    if kind == "recurrent" else None)
+
+        cache = tuple(map(view, self._kinds, pools))
+        out = self.module.serve_decode(
+            self.engine._materialized(params), toks[:, None], pos[:, None],
+            cache, **({} if alive is None else {"live": alive}))
         logits = out["logits"][:, -1].astype(jnp.float32)
         tok = sample_logits(logits, rng, self.scfg.temperature,
                             self.scfg.top_k)
-        return tok, logits, tuple(c.pools for c in out["cache"])
+        kept = tuple(None if c is None else c.pools for c in out["cache"])
+        return tok, logits, kept, out.get("counters")
 
     # -- chunked prefill (the mixed ragged round) -----------------------
     def _mixed_round(self, active: List[Sequence],
@@ -1148,11 +1279,9 @@ class ServeEngine:
             ChunkedLayerCache(*pools[i], bt, slots, pos, self.block_size,
                               self._dtype_name)
             for i in range(self.model_cfg.num_layers))
-        out = self.module.apply(
-            {"params": self.engine._materialized(params)},
-            {"input_ids": toks[None, :],
-             "position_ids": jnp.minimum(pos, max_pos)[None, :]},
-            deterministic=True, cache=cache, pos=None)
+        out = self.module.serve_decode(
+            self.engine._materialized(params), toks[None, :],
+            jnp.minimum(pos, max_pos)[None, :], cache)
         logits = out["logits"][0].astype(jnp.float32)      # [T, V]
         tok = sample_logits(logits, rng, self.scfg.temperature,
                             self.scfg.top_k)
@@ -1270,11 +1399,9 @@ class ServeEngine:
                                 self._dtype_name, attn_impl,
                                 clamp_writes=True)
                 for i in range(dl))
-            out = self._draft_module.apply(
-                {"params": dp},
-                {"input_ids": cur[:, None],
-                 "position_ids": jnp.minimum(pos + j, max_pos)[:, None]},
-                deterministic=True, cache=cache, pos=None)
+            out = self._draft_module.serve_decode(
+                dp, cur[:, None], jnp.minimum(pos + j, max_pos)[:, None],
+                cache)
             nxt = jnp.argmax(out["logits"][:, -1].astype(jnp.float32),
                              axis=-1).astype(jnp.int32)
             new_pools = tuple(out["cache"][i].pools if i < dl else pools_c[i]
@@ -1289,9 +1416,7 @@ class ServeEngine:
             PagedLayerCache(*pools[i], bt, pos, bs, self._dtype_name,
                             attn_impl, clamp_writes=True)
             for i in range(nl))
-        out = self.module.apply(
-            {"params": p}, {"input_ids": chunk, "position_ids": pos_ids},
-            deterministic=True, cache=cache, pos=None)
+        out = self.module.serve_decode(p, chunk, pos_ids, cache)
         greedy = jnp.argmax(out["logits"].astype(jnp.float32),
                             axis=-1).astype(jnp.int32)   # [B, k+1]
         return chunk, greedy, tuple(c.pools for c in out["cache"])

@@ -52,10 +52,26 @@ Layout (per transformer layer, all layers share one block table):
 
 Host-side block accounting (:class:`BlockPool`) is plain python — a free
 list is microseconds per step and never touches the device.
+
+**Which layers have what** is the model's to say (``model.
+serving_cache_spec()``, one :class:`LayerCacheSpec` a layer):
+
+- :func:`kv` ``(heads, head_dim)``: a K and a V pool as above, sized by the
+  layer's KEY/VALUE heads (a grouped-query layer with 2 of them at 128
+  holds 256 lanes a position whatever its query heads);
+- :func:`recurrent` ``(shapes)``: state that does not grow with positions,
+  one array ``[slots, *shape]`` per named entry, a row a batch slot
+  (:class:`RecurrentLayerState` inside the decode program): a Mamba-2
+  layer's float32 SSM state and the tail of its convolution. A prefill
+  starts from zeros and :func:`pack_prefill` writes the slot's rows
+  whole, so admission needs no clearing pass and release none either:
+  the slot's rows are simply the next request's to overwrite;
+- :func:`none`: nothing (an expert layer, an MLP).
 """
 
 import functools
-from typing import Dict, Iterable, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +79,31 @@ import numpy as np
 
 from deepspeed_tpu.comm.quantize import quantize_blockwise
 from deepspeed_tpu.telemetry.tracer import device_scope
+
+
+@dataclass(frozen=True)
+class LayerCacheSpec:
+    """What one layer keeps between the steps of a request."""
+    kind: str                           # "kv" | "recurrent" | "none"
+    heads: int = 0                      # kv: key/value heads
+    head_dim: int = 0
+    shapes: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()  # recurrent
+
+
+def kv(heads: int, head_dim: int) -> LayerCacheSpec:
+    return LayerCacheSpec("kv", int(heads), int(head_dim))
+
+
+def recurrent(shapes: Dict[str, Tuple[Tuple[int, ...], Any]]
+              ) -> LayerCacheSpec:
+    """``shapes``: name -> (shape of ONE slot's entry, dtype)."""
+    return LayerCacheSpec("recurrent", shapes=tuple(
+        (name, tuple(shape), dtype) for name, (shape, dtype)
+        in shapes.items()))
+
+
+def none() -> LayerCacheSpec:
+    return LayerCacheSpec("none")
 
 
 class BlockPool:
@@ -196,30 +237,75 @@ def live_block_list(rows: Iterable[Tuple[int, List[int], int]],
 
 def init_paged_pools(cfg, num_blocks: int, block_size: int,
                      int8: bool = False, dtype=None) -> Tuple:
-    """Per-layer ``(k, v, k_scale, v_scale)`` pool arrays (scales are None
-    in the fp path). Zero-initialised: scratch/unwritten slots dequantize
-    to exact zeros, so masked attention terms stay exactly ``0 * 0``.
+    """:func:`init_serving_state` for a model whose every layer keeps keys
+    and values, a key/value head a query head (``cfg.num_layers``,
+    ``cfg.num_heads``, ``cfg.head_dim``)."""
+    return init_serving_state(
+        (kv(cfg.num_heads, cfg.head_dim),) * cfg.num_layers, num_blocks,
+        block_size, slots=0, int8=int8,
+        dtype=dtype if dtype is not None else cfg.dtype)
 
-    K/V pools are ``[num_blocks, block_size, heads * head_dim]`` (the
-    module docstring says why). The int8 SCALE pools stay
-    ``[num_blocks, block_size, heads]``: at 1/32 of the pool's bytes
-    their own layout round trip is small, and the two obvious folds
-    (``[N, BS * H]`` written through a reshape, or by an element scatter)
-    each still compile to pool-sized copies or reshapes."""
-    dtype = dtype if dtype is not None else cfg.dtype
-    shape = (num_blocks, block_size, cfg.num_heads * cfg.head_dim)
-    sshape = (num_blocks, block_size, cfg.num_heads)
+
+def init_serving_state(specs: Tuple[LayerCacheSpec, ...], num_blocks: int,
+                       block_size: int, slots: int, int8: bool = False,
+                       dtype=jnp.bfloat16) -> Tuple:
+    """One entry a layer, by its spec. A ``kv`` layer: ``(k, v, k_scale,
+    v_scale)`` pool arrays (scales are None in the fp path).
+    Zero-initialised: scratch/unwritten slots dequantize to exact zeros,
+    so masked attention terms stay exactly ``0 * 0``. K/V pools are
+    ``[num_blocks, block_size, heads * head_dim]`` (the module docstring
+    says why). The int8 SCALE pools stay ``[num_blocks, block_size,
+    heads]``: at 1/32 of the pool's bytes their own layout round trip is
+    small, and the two obvious folds (``[N, BS * H]`` written through a
+    reshape, or by an element scatter) each still compile to pool-sized
+    copies or reshapes. A ``recurrent`` layer: a tuple of ``[slots,
+    *shape]`` zeros in the spec's order. A layer that keeps nothing:
+    ``None``."""
     layers = []
-    for _ in range(cfg.num_layers):
-        if int8:
-            layers.append((jnp.zeros(shape, jnp.int8),
-                           jnp.zeros(shape, jnp.int8),
-                           jnp.ones(sshape, jnp.float32),
-                           jnp.ones(sshape, jnp.float32)))
+    for spec in specs:
+        if spec.kind == "kv":
+            lanes = (num_blocks, block_size, spec.heads * spec.head_dim)
+            scales = (num_blocks, block_size, spec.heads)
+            layers.append(
+                (jnp.zeros(lanes, jnp.int8), jnp.zeros(lanes, jnp.int8),
+                 jnp.ones(scales, jnp.float32), jnp.ones(scales, jnp.float32))
+                if int8 else
+                (jnp.zeros(lanes, dtype), jnp.zeros(lanes, dtype), None, None))
+        elif spec.kind == "recurrent":
+            layers.append(tuple(jnp.zeros((slots,) + shape, dt)
+                                for _, shape, dt in spec.shapes))
         else:
-            layers.append((jnp.zeros(shape, dtype),
-                           jnp.zeros(shape, dtype), None, None))
+            layers.append(None)
     return tuple(layers)
+
+
+@jax.tree_util.register_pytree_node_class
+class RecurrentLayerState:
+    """One recurrent layer's view of its state inside the decode program:
+    ``arrays``, each ``[slots, ...]`` in the spec's order, and ``live``
+    (``[slots]`` bool), the rows that hold a request. The layer reads its
+    rows, returns ``replaced(new arrays)``, and leaves the rows of dead
+    slots as they are."""
+
+    def __init__(self, arrays: Tuple, live: jax.Array):
+        self.arrays = tuple(arrays)
+        self.live = live
+
+    def tree_flatten(self):
+        return (self.arrays, self.live), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    def replaced(self, arrays) -> "RecurrentLayerState":
+        return RecurrentLayerState(arrays, self.live)
+
+    @property
+    def pools(self) -> Tuple:
+        """What the engine keeps of this layer, as ``PagedLayerCache``
+        names it."""
+        return self.arrays
 
 
 def _quant_tokens(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -579,18 +665,37 @@ class ChunkedLayerCache:
 
 @device_scope("pack")
 def pack_prefill(pools: Tuple, blocks: jax.Array,
-                 k_stack: jax.Array, v_stack: jax.Array) -> Tuple:
+                 k_stack: jax.Array, v_stack: jax.Array,
+                 slot: Optional[jax.Array] = None,
+                 states: Tuple = (), *,
+                 kinds: Optional[Tuple[str, ...]] = None) -> Tuple:
     """Scatter a prefilled contiguous cache into pool blocks (jit this).
 
-    ``pools``: the per-layer ``(k, v, k_scale, v_scale)`` tuple;
+    ``pools``: one entry a layer (:func:`init_serving_state`);
     ``blocks``: [nb] int32 pool blocks assigned to the sequence;
-    ``k_stack``/``v_stack``: [layers, T, H, D] from the prefill forward,
+    ``k_stack``/``v_stack``: [kv layers, T, H, D] from the prefill forward,
     with ``T == nb * block_size`` (bucketed — trailing positions beyond
     the true prompt length carry garbage that stays masked by ``pos``).
+    ``states``: for each recurrent layer in order, the arrays the prefill
+    left (``[1, *shape]`` each): they become row ``slot`` of that layer's
+    state, whole. ``kinds`` (static): each layer's ``LayerCacheSpec.kind``;
+    absent, every layer is a ``kv`` layer.
     """
     nb = blocks.shape[0]
     out = []
-    for i, (k, v, ks, vs) in enumerate(pools):
+    states = iter(states)
+    i = -1                              # index among the kv layers
+    for pool, kind in zip(pools, kinds or ("kv",) * len(pools)):
+        if kind == "none":
+            out.append(None)
+            continue
+        if kind == "recurrent":
+            out.append(tuple(
+                mine.at[slot].set(new[0].astype(mine.dtype))
+                for mine, new in zip(pool, next(states))))
+            continue
+        i += 1
+        k, v, ks, vs = pool
         rows = (nb, k.shape[1], -1)     # whole blocks, [H, D] folded
         if ks is not None:
             kq, ksc = _quant_tokens(k_stack[i])

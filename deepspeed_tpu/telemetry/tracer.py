@@ -70,6 +70,13 @@ DEVICE_SCOPES = {
     "moe_shared": "the shared expert",
     "moe_combine": "the buffer's rows back to their tokens, weighted sum",
     "mtp": "the multi-token-prediction module (its block and head included)",
+    "ssm": "a Mamba-2 mixer whole: projections, convolution, recurrence, "
+           "gate and group norm",
+    "ssm_conv": "the causal depthwise convolution in front of the recurrence",
+    "ssm_scan": "the recurrence over a whole prompt, in chunks (prefill)",
+    "ssm_step": "the recurrence's one step on the slots' state (decode)",
+    "attn": "a grouped-query attention mixer: projections, the attention "
+            "over the cache, the output projection",
 }
 
 

@@ -261,3 +261,54 @@ def test_the_dropless_expert_layer_at_the_cells_size(v5e_sharding):
     scattered = re.findall(r"= (\w+)\[([\d,]*)\][^=]* scatter\(", hlo)
     assert sorted(scattered) == [("f32", "262144"), ("s32", "16384")], \
         scattered
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_the_nemotron_h_mixers_at_the_cells_widths(phase, v5e_sharding):
+    """``nemotron3s-serve-chat``'s three mixers at their published widths
+    (one layer of each, fewer slots and a shorter prompt than the cell:
+    the widths are what Mosaic and the grouped matmul tile by): the
+    Mamba-2 recurrence (one step on the slots' float32 state; the chunked
+    scan of a prompt), grouped-query attention over the paged window, and
+    the latent experts, whose two matmuls reach the chip as XLA's
+    grouped-matmul kernel at decode as in training. The whole cell at its
+    real size: ``tools/rehearse_serving_compile.py``."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+    from deepspeed_tpu.serving.kv_cache import (PagedLayerCache,
+                                                RecurrentLayerState,
+                                                init_serving_state)
+
+    model = NemotronH(NemotronHConfig(pattern="EM*", vocab_size=4096,
+                                      n_held_experts=16))
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=v5e_sharding), tree)
+    params = on_chip(jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16), model.init(
+            jax.random.PRNGKey(0),
+            {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"])))
+    slots, blocks, bs = 8, 16, 16
+    if phase == "prefill":
+        def run(p, ids, length):
+            out = model.serve_prefill(p, ids, length)
+            return out["logits"][:, -1], out["cache"], out["counters"]
+        args = (params, on_chip(jax.ShapeDtypeStruct((1, 256), jnp.int32)),
+                on_chip(jax.ShapeDtypeStruct((), jnp.int32)))
+    else:
+        pools = on_chip(jax.eval_shape(lambda: init_serving_state(
+            model.serving_cache_spec(), slots * blocks + 1, bs, slots)))
+
+        def run(p, pools, bt, pos, toks, live):
+            cache = (None, RecurrentLayerState(pools[1], live),
+                     PagedLayerCache(*pools[2], bt, pos, bs, "bfloat16",
+                                     "window"))
+            out = model.serve_decode(p, toks[:, None], pos[:, None], cache,
+                                     live)
+            return (out["logits"], out["cache"][1].arrays,
+                    out["cache"][2].pools, out["counters"])
+        ints = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+        args = (params, pools, ints(slots, blocks), ints(slots), ints(slots),
+                on_chip(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    hlo = jax.jit(run).lower(*args).compile().as_text()
+    assert len(set(re.findall(r"%(ragged-dot-none[.\d]*) = ", hlo))) == 2
