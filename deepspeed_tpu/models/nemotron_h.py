@@ -294,7 +294,8 @@ class NemotronH(nn.Module):
     """``__call__(batch)`` -> ``{"logits"}`` (float32, ``[B, S, vocab]``);
     with ``cache`` also ``"cache"`` (a layer's entry each) and
     ``"counters"`` (``SERVING_COUNTERS`` over the expert layers, int32:
-    assignments and touched experts summed, the fullest expert's rows)."""
+    assignments, touched experts and rows computed summed, the fullest
+    expert's rows the largest)."""
 
     cfg: NemotronHConfig
 
@@ -349,11 +350,10 @@ class NemotronH(nn.Module):
         if serving:
             out["cache"] = tuple(new_cache)
             if counted:
-                total = lambda k: sum(c[k] for c in counted)
                 out["counters"] = jnp.stack([
-                    total("held_assignments"), total("experts_touched"),
-                    jnp.stack([c["held_rows_max"] for c in counted]).max()
-                ]).astype(jnp.int32)
+                    jnp.stack([c[k] for c in counted]).max()
+                    if k == "held_rows_max" else sum(c[k] for c in counted)
+                    for k in SERVING_COUNTERS]).astype(jnp.int32)
         return out
 
     # -- what the serving engine asks of a model ------------------------

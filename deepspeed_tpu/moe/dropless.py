@@ -20,11 +20,17 @@ although the number of held assignments depends on the data:
   buffer that can never drop: every token may choose ``k`` held experts.
   A buffer bounded per expert (``[held, C, D]``) would need ``C = T`` to
   be as safe, ``held / k`` times the rows;
-- the held experts' SwiGLU is three ``jax.lax.ragged_dot`` calls over
-  that buffer with the group sizes counted from the routing: on a TPU
-  XLA lowers each to its own grouped-matmul kernel, which visits the row
+- the held experts' SwiGLU is three grouped matmuls over that buffer
+  with the group sizes counted from the routing, each visiting the row
   tiles of the groups and not the unused tail, so the work follows the
-  held assignments and not the buffer;
+  held assignments and not the buffer. Which kernel, the call's shape
+  says (``_grouped_dot``): where a held expert expects few rows (``r = T
+  k / n_routed`` under 128: serving) and on a TPU, ``moe_gmm``
+  (``ops/grouped_matmul.py``) in row tiles sized to ``r``, which reads
+  each touched expert's weights once; elsewhere ``jax.lax.ragged_dot``,
+  which XLA lowers to its own grouped-matmul kernel (512-row tiles,
+  PR 26: every touched group costs one, so at a few rows an expert it
+  computes rows that do not exist, 4-6 times the weights' time);
 - the buffer's rows go back by the inverse permutation, and each token
   sums its ``k`` rows with its weights (zero for an expert not held).
   Dispatch and combine are gathers in both directions (``_sorted_rows``,
@@ -54,15 +60,19 @@ decode and prefill of the serving engine:
 """
 
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.grouped_matmul import (grouped_matmul, row_tile,
+                                               row_tiles_visited)
 from deepspeed_tpu.parallel.mesh import EXPERT_AXIS
 from deepspeed_tpu.telemetry.tracer import device_scope
+from deepspeed_tpu.utils.logging import logger
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 @dataclass(frozen=True)
@@ -161,19 +171,44 @@ def _unsorted_rows_bwd(res, g):
 _unsorted_rows.defvjp(_unsorted_rows_fwd, _unsorted_rows_bwd)
 
 
-SERVING_COUNTERS = ("held_assignments", "experts_touched", "held_rows_max")
+# ``rows_computed``: the rows the grouped matmul computes, row tiles
+# visited x ``tm`` (0 where XLA's kernel runs: its tiles are not ours to
+# count); ``held_assignments / rows_computed`` is the share that is real
+SERVING_COUNTERS = ("held_assignments", "experts_touched", "held_rows_max",
+                    "rows_computed")
+
+
+@lru_cache(maxsize=None)
+def _log_choice(rows_per_group: float, tm) -> None:
+    """Say once per shape which grouped matmul the held experts take."""
+    logger.info(f"experts matmul rows/group ~{rows_per_group:.1f} -> "
+                + (f"pallas tm={tm}" if tm else "ragged_dot"))
+
+
+def _grouped_dot(rows_per_group: float, sizes):
+    """``(dot, rows_computed)`` for groups that expect ``rows_per_group``
+    rows: ``moe_gmm`` in the row tile ``row_tile`` gives, on a TPU, or
+    ``jax.lax.ragged_dot`` (``rows_computed`` 0). The call's shape alone
+    decides."""
+    tm = row_tile(rows_per_group) if on_tpu() else None
+    _log_choice(rows_per_group, tm)
+    if tm is None:
+        return partial(jax.lax.ragged_dot, group_sizes=sizes), jnp.int32(0)
+    return (lambda a, w: grouped_matmul(a, w, sizes, tm),
+            tm * row_tiles_visited(sizes, tm))
 
 
 def held_expert_part(x, chosen, weights, w_gate, w_up, w_down, *,
-                     first_held: int, live=None
+                     first_held: int, n_routed: int, live=None
                      ) -> Tuple[jax.Array, Dict[str, Any]]:
     """The held experts' part of the layer's result for ``x [T, D]``:
     ``sum over chosen e in [first, first + held) of w_e expert_e(x)``,
     and the counters of this call. ``w_gate`` / ``w_up`` are
     ``[held, D, F]``, ``w_down`` is ``[held, F, D]``; an expert is SwiGLU,
-    or with ``w_gate`` None ``w_down relu(w_up x)^2``. Rows that are not
-    ``live`` (``[T]`` bool) choose no expert; with ``live`` the counters
-    are the int32 ``SERVING_COUNTERS``."""
+    or with ``w_gate`` None ``w_down relu(w_up x)^2``. ``n_routed`` is the
+    router's width. Rows that are not ``live`` (``[T]`` bool) choose no
+    expert; with ``live`` the counters are the int32
+    ``SERVING_COUNTERS``."""
     t, d = x.shape
     k = chosen.shape[1]
     held = w_up.shape[0]
@@ -193,7 +228,7 @@ def held_expert_part(x, chosen, weights, w_gate, w_up, w_down, *,
     with device_scope("moe_experts"):
         # Rows past the last group are no expert's: the kernel leaves
         # them as it finds them, so they are zeroed on the way in and out.
-        dot = partial(jax.lax.ragged_dot, group_sizes=sizes)
+        dot, rows_computed = _grouped_dot(t * k / n_routed, sizes)
         if w_gate is None:
             up = jnp.where(in_a_group, dot(rows, w_up), 0)
             mid = jnp.square(jax.nn.relu(up))
@@ -212,7 +247,8 @@ def held_expert_part(x, chosen, weights, w_gate, w_up, w_down, *,
     if live is not None:
         return y, {"held_assignments": sizes.sum(),
                    "experts_touched": (sizes > 0).sum(dtype=jnp.int32),
-                   "held_rows_max": sizes.max()}
+                   "held_rows_max": sizes.max(),
+                   "rows_computed": rows_computed}
     f32 = jnp.float32
     counters = {
         "held_assignments_per_token": sizes.sum().astype(f32) / t,
@@ -291,6 +327,7 @@ class DroplessMoE(nn.Module):
         y, counters = held_expert_part(
             rows, chosen, weights, w_gate, w_up.astype(dt),
             w_down.astype(dt), first_held=cfg.first_held_expert,
+            n_routed=cfg.n_routed_experts,
             live=None if live is None else live.reshape(b * s))
         last = dense if cfg.out_dtype is None else partial(
             dense, dot_general=partial(

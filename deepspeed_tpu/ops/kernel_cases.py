@@ -216,6 +216,35 @@ def _chunked_case(int8: bool) -> KernelCase:
         reference)
 
 
+# -- grouped matmul (ops/grouped_matmul.py) --------------------------------
+def _grouped_matmul_case(slots: int) -> KernelCase:
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul, row_tile
+
+    # nemotron3s-serve-chat's latent experts: slots x 22 rows of the sorted
+    # buffer, 128 held of 512 experts, the up matmul 1024 -> 2688; every
+    # slot alive, so a held expert gets 22/512 of the rows, and the tail
+    # past the last group is no group's (zeroed, as the layer does)
+    m, held = slots * 22, 128
+    tm = row_tile(m / 512)
+
+    def make_args(rng):
+        sizes = rng.multinomial(m * held // 512, np.full(held, 1 / held))
+        return (_normal(rng, (m, 1024), jnp.bfloat16),
+                _normal(rng, (held, 1024, 2688), jnp.bfloat16),
+                jnp.asarray(sizes, jnp.int32))
+
+    def in_groups(out, sizes):
+        return jnp.where((jnp.arange(m) < sizes.sum())[:, None], out, 0)
+
+    return KernelCase(
+        f"grouped_matmul {m}x1024->2688 over {held} groups tm {tm}",
+        make_args,
+        lambda interpret, lhs, rhs, sizes: in_groups(grouped_matmul(
+            lhs, rhs, sizes, tm, interpret), sizes),
+        lambda lhs, rhs, sizes: in_groups(
+            jax.lax.ragged_dot(lhs, rhs, sizes), sizes))
+
+
 def kernel_cases() -> List[KernelCase]:
     return [
         _flash_case(64, jnp.float32), _flash_case(128, jnp.float32),
@@ -230,4 +259,6 @@ def kernel_cases() -> List[KernelCase]:
         _paged_case(1, int8=False), _paged_case(1, int8=True),
         _paged_case(5, int8=False), _paged_case(5, int8=True),
         _chunked_case(int8=False), _chunked_case(int8=True),
+        # the cell's decode (128 slots) and its largest prefill bucket
+        _grouped_matmul_case(128), _grouped_matmul_case(2048),
     ]

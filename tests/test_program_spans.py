@@ -429,6 +429,7 @@ KERNELS = {
     "ops/sparse_attention/sparse_attention.py": [
         "sparse_attn_fwd", "sparse_attn_bwd_dq", "sparse_attn_bwd_dkv"],
     "ops/adam/fused_update.py": ["fused_adam"],
+    "ops/grouped_matmul.py": ["moe_gmm"],
 }
 
 

@@ -227,15 +227,18 @@ def test_the_default_decode_reads_the_live_blocks_and_no_window(
                 line.split(" fusion(")[0]]
 
 
-def test_the_dropless_expert_layer_at_the_cells_size(v5e_sharding):
-    """``glm47flash-train-s4096``'s expert layer, forward and backward, at
-    its real size (4096 tokens, 8 of 64 experts held, 4 a token): the
-    held experts' three matmuls and their six transposes each reach the
-    chip as XLA's grouped-matmul kernel (named ``ragged-dot-*``, which
-    ``benchmarks/moe_trace.py`` reads by), not as a dense product per
-    expert over the whole buffer; and no row travels by a scatter: the
-    only scatters are the inverse permutation (int32) and the router's
-    ``take_along_axis`` transpose (64 scores a token)."""
+@pytest.fixture
+def the_platform_says_tpu(monkeypatch):
+    """What the expert layer's platform probe answers for a described v5e
+    (it reads ``jax.devices()``, which sees the CPU here)."""
+    import deepspeed_tpu.moe.dropless as dropless
+    monkeypatch.setattr(dropless, "on_tpu", lambda: True)
+
+
+def glm_expert_layer_lowered(v5e_sharding):
+    """``glm47flash-train-s4096``'s expert layer, forward and gradient, at
+    its real size (4096 tokens, 8 of 64 experts held, 4 a token: 256 rows
+    a held expert), lowered for one v5e."""
     import jax.numpy as jnp
     from deepspeed_tpu.moe.dropless import DroplessMoE, DroplessMoEConfig
 
@@ -253,9 +256,22 @@ def test_the_dropless_expert_layer_at_the_cells_size(v5e_sharding):
         y, counters = layer.apply({"params": p}, x)
         return y.astype(jnp.float32).sum(), counters
 
-    hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
-                  ).lower(params, on_chip((1, 4096, 2048))).compile(
-                  ).as_text()
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+                   ).lower(params, on_chip((1, 4096, 2048)))
+
+
+def test_the_dropless_expert_layer_at_the_cells_size(v5e_sharding,
+                                                     the_platform_says_tpu):
+    """``glm47flash-train-s4096``'s expert layer, forward and backward: the
+    held experts' three matmuls and their six transposes each reach the
+    chip as XLA's grouped-matmul kernel (named ``ragged-dot-*``, which
+    ``benchmarks/moe_trace.py`` reads by), not as a dense product per
+    expert over the whole buffer and not as ``moe_gmm``, which 256 rows a
+    group do not take; and no row travels by a scatter: the only scatters
+    are the inverse permutation (int32) and the router's
+    ``take_along_axis`` transpose (64 scores a token)."""
+    hlo = glm_expert_layer_lowered(v5e_sharding).compile().as_text()
+    assert "moe_gmm" not in hlo
     calls = re.findall(r"%(ragged-dot-[a-z]+)[.\d]* = ", hlo)
     assert calls.count("ragged-dot-none") == 9, sorted(set(calls))
     scattered = re.findall(r"= (\w+)\[([\d,]*)\][^=]* scatter\(", hlo)
@@ -263,16 +279,30 @@ def test_the_dropless_expert_layer_at_the_cells_size(v5e_sharding):
         scattered
 
 
+def test_the_glm_expert_layer_lowers_to_the_parents_text(
+        v5e_sharding, the_platform_says_tpu):
+    """At 256 rows a held expert the rule leaves XLA's kernel, and the
+    layer's forward and gradient lower to the text they had before
+    ``moe_gmm`` existed (PR 35's tree, by hash of the lowered module)."""
+    import hashlib
+
+    text = glm_expert_layer_lowered(v5e_sharding).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "6e08d0beedc311d1066dc948ad90b9eebb88b1abd77014812dd0c1902ada3017")
+
+
 @pytest.mark.parametrize("phase", ["decode", "prefill"])
-def test_the_nemotron_h_mixers_at_the_cells_widths(phase, v5e_sharding):
+def test_the_nemotron_h_mixers_at_the_cells_widths(phase, v5e_sharding,
+                                                   the_platform_says_tpu):
     """``nemotron3s-serve-chat``'s three mixers at their published widths
     (one layer of each, fewer slots and a shorter prompt than the cell:
     the widths are what Mosaic and the grouped matmul tile by): the
     Mamba-2 recurrence (one step on the slots' float32 state; the chunked
     scan of a prompt), grouped-query attention over the paged window, and
-    the latent experts, whose two matmuls reach the chip as XLA's
-    grouped-matmul kernel at decode as in training. The whole cell at its
-    real size: ``tools/rehearse_serving_compile.py``."""
+    the latent experts, whose two matmuls reach the chip as ``moe_gmm``
+    (a few rows a held expert: 0.3 at decode, 11 in the prompt) and not
+    as XLA's grouped-matmul kernel. The whole cell at its real size:
+    ``tools/rehearse_serving_compile.py``."""
     import jax.numpy as jnp
     from deepspeed_tpu.models.nemotron_h import NemotronH, NemotronHConfig
     from deepspeed_tpu.serving.kv_cache import (PagedLayerCache,
@@ -311,4 +341,5 @@ def test_the_nemotron_h_mixers_at_the_cells_widths(phase, v5e_sharding):
         args = (params, pools, ints(slots, blocks), ints(slots), ints(slots),
                 on_chip(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
     hlo = jax.jit(run).lower(*args).compile().as_text()
-    assert len(set(re.findall(r"%(ragged-dot-none[.\d]*) = ", hlo))) == 2
+    assert len(set(re.findall(r"%(moe_gmm[.\d]*) = ", hlo))) == 2
+    assert "ragged-dot" not in hlo
