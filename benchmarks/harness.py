@@ -123,16 +123,40 @@ def metrics_of(bench: Dict, kind: str, cell: str) -> List[Dict]:
     return [m for m in bench[kind] if cell in m.get("workloads", [cell])]
 
 
+COMPILE_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                  "/jax/core/compile/backend_compile_duration":
+                      "backend_compile"}
+
+
+@dataclass
+class CompileEvent:
+    """One span JAX reports while it makes a program: ``kind`` is
+    ``trace``, ``lower`` or ``backend_compile`` (XLA compiling, or the
+    persistent cache loading), times on ``time.perf_counter``'s clock."""
+    kind: str
+    fun_name: str
+    start: float
+    end: float
+
+
 class Compiles:
     """Counts what ``jax.monitoring`` reports: every executable built or
     loaded from the persistent cache (``backend_compile``), and the
-    cache's hits and misses."""
+    cache's hits and misses. It also keeps the log: each trace, lowering
+    and backend compile with its function's name, start and end
+    (``events``), and the time each hit and miss arrived
+    (``cache_events``: ``("hit" or "miss", time)``), all on the clock of
+    ``Run.t0`` and of the window's start."""
 
     def __init__(self):
         import jax.monitoring as mon
         self.count = self.hits = self.misses = 0
+        self.events: List[CompileEvent] = []
+        self.cache_events: List[tuple] = []
         mon.register_event_duration_secs_listener(self._on_duration)
         mon.register_event_listener(self._on_event)
+        mon.register_event_time_span_listener(self._on_span)
 
     def _on_duration(self, event, duration, **kw):
         if event == "/jax/core/compile/backend_compile_duration":
@@ -141,8 +165,19 @@ class Compiles:
     def _on_event(self, event, **kw):
         if event == "/jax/compilation_cache/cache_hits":
             self.hits += 1
+            self.cache_events.append(("hit", time.perf_counter()))
         elif event == "/jax/compilation_cache/cache_misses":
             self.misses += 1
+            self.cache_events.append(("miss", time.perf_counter()))
+
+    def _on_span(self, event, start, end, **kw):
+        kind = COMPILE_EVENTS.get(event)
+        if kind is None:
+            return
+        # JAX times the span on time.time(); the listener runs as it ends
+        shift = time.perf_counter() - time.time()
+        self.events.append(CompileEvent(kind, str(kw.get("fun_name", "")),
+                                        start + shift, end + shift))
 
 
 @dataclass
@@ -159,6 +194,7 @@ class Run:
     peaks: Optional[Dict]
     compiles: Compiles
     t0: float = 0.0
+    window_start: Optional[float] = None         # where set-up ends
     xplane_dir: str = ""
     phases: List = field(default_factory=list)   # (name, seconds) of set-up
     memory_peak_bytes: Optional[int] = None      # see note_memory_peak

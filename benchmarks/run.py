@@ -73,6 +73,7 @@ def main(argv=None) -> int:
               t0=T0)
     run.mark("imports and device start-up")
     out = driver.run(run)
+    run.window_start = out["window_start"]
 
     setup_s = out["window_start"] - run.t0
     say(f"{tag}set-up (process start to the window's first step or "

@@ -5,11 +5,12 @@ per-layer readers on a hand-made trace (and on one of a program that lacks
 the scopes, as the parent does), the ``BENCHMARK.json`` entries against
 their files, and a traced rehearsal on the CPU.
 
-The six readers WAIT for their ``BENCHMARK.json`` entries: a pin of
-``test_program_trace.py`` holds ``per_layer``'s last twelve names, and the
-builder's contract takes an entry put before them as a change to what was
-there, so only a ``benchmark`` PR can list them (PERF.md, section 7).
-Until then they are run by hand (``benchmarks/moe_trace.py``)."""
+The six readers have their ``BENCHMARK.json`` entries, and
+``benchmarks/moe_trace.py`` still runs them by hand on a capture. What
+this file holds of the entries are rules, not positions: the metrics the
+cell reported when it came are the first of its list and in their order,
+each of the six names the cell, and a later PR may append metrics and
+cells after them."""
 
 import json
 import os
@@ -242,7 +243,8 @@ def test_the_cell_is_appended_to_the_metrics_it_reports():
         assert find(BENCH["per_layer"], name, "metric")["workloads"][-1] \
             == CELL, name
     reported = [m["name"] for m in metrics_of(BENCH, "per_layer", CELL)]
-    assert reported == APPENDED_TO + NEW
+    # first and in order; metrics a later PR appends follow them
+    assert reported[:len(APPENDED_TO + NEW)] == APPENDED_TO + NEW
     # kernel.flash_roofline divides by ALL Mosaic time, and in this cell
     # XLA's grouped-matmul kernel is Mosaic time too: the cell stays off
     # it until its reader takes the flash kernels by name
@@ -252,14 +254,13 @@ def test_the_cell_is_appended_to_the_metrics_it_reports():
 
 @pytest.mark.parametrize("name", NEW)
 def test_a_reader_has_its_entry(name):
-    """Listed since PR 34 (the pins that kept them out are rules now,
-    ``test_program_trace.py``): the reader is a file the harness loads,
-    the entry names this cell alone, and ``moe_trace.py`` still runs the
-    six by hand on a capture."""
+    """The reader is a file the harness loads, the entry names this cell
+    (a cell that joins later follows it), and ``moe_trace.py`` still runs
+    the six by hand on a capture."""
     assert callable(reader(name))
     assert name in mt.READERS
     entry = find(BENCH["per_layer"], name, "metric")
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
     assert entry["moves"] == "tokens_per_s_per_chip"
     assert entry["layer"] == ("experts" if name.startswith("moe.")
                               else "model")

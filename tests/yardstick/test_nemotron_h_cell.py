@@ -235,7 +235,9 @@ def test_the_cell_is_appended_to_the_metrics_it_reports():
     end_to_end = {m["name"] for m in metrics_of(BENCH, "end_to_end", CELL)}
     assert end_to_end == {"itl_ms_p95", "setup_s"}
     listed = [m["name"] for m in metrics_of(BENCH, "per_layer", CELL)]
-    assert listed == JOINED + NEW
+    # what the cell reported when it came, first and in order; metrics a
+    # later PR appends follow them
+    assert listed[:len(JOINED + NEW)] == JOINED + NEW
     for name in JOINED + ["itl_ms_p95"]:
         entry = find(BENCH["per_layer"] + BENCH["end_to_end"], name, "metric")
         assert entry["workloads"][-1] == CELL
@@ -244,8 +246,12 @@ def test_the_cell_is_appended_to_the_metrics_it_reports():
 
 @pytest.mark.parametrize("name", NEW)
 def test_a_reader_has_its_entry_at_the_end(name):
+    """The six came at the end of ``per_layer``, where they stay together
+    and in their order; entries appended later come after them."""
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-len(NEW):] == NEW
+    first = names.index(NEW[0])
+    assert names[first:first + len(NEW)] == NEW
+    assert first + NEW.index(name) == names.index(name)
     entry = find(BENCH["per_layer"], name, "metric")
     assert entry["moves"] == "itl_ms_p95" and entry["workloads"] == [CELL]
     assert entry["unit"] == "%"
@@ -365,6 +371,35 @@ def test_a_reader_finds_nothing_in_a_program_without_the_scopes(
     run = SimpleNamespace(xplane=lambda: None, config=CONFIG,
                           traffic=TRAFFIC, peaks=None)
     assert reader(name)(run, {}, None) is None
+
+
+TILE_FILL = "serve.moe_tile_fill"
+
+
+def test_the_tile_fill_has_its_entry_after_the_six():
+    """The grouped matmul's ``moe_rows_computed`` counter, read after the
+    six."""
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names.index(TILE_FILL) > names.index(NEW[-1])
+    entry = find(BENCH["per_layer"], TILE_FILL, "metric")
+    assert (entry["layer"], entry["moves"], entry["unit"], entry["source"],
+            entry["workloads"]) == ("experts", "itl_ms_p95", "%",
+                                    "program_counter", [CELL])
+
+
+@pytest.mark.parametrize("computed, want", [
+    ((16000, 16000), 100 * (1000 + 1600) / 32000),
+    ((0, 0), None),             # XLA's kernel counts no tiles
+    (None, None)])              # a program without the counter
+def test_the_tile_fill_on_a_hand_made_stretch(computed, want, monkeypatch):
+    trace = traced_stretch()
+    spans = pt.decode_spans(trace)
+    assert len(spans) == 2
+    for span, rows in zip(spans, computed or ()):
+        span.stats["moe_rows_computed"] = rows
+    monkeypatch.setattr(pt, "load", lambda path: trace)
+    got = reader(TILE_FILL)(SimpleNamespace(xplane=lambda: "x"), {}, None)
+    assert got == (None if want is None else pytest.approx(want))
 
 
 # ---------------------------------------------------------------------------

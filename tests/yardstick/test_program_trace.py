@@ -67,6 +67,26 @@ def test_a_per_layer_entry_keeps_the_rules(entry):
                           "moves", "workloads"}
 
 
+HELD = load_json(os.path.dirname(os.path.abspath(__file__)),
+                 "per_layer_held.json")
+
+
+@pytest.mark.parametrize("position, entry", list(enumerate(HELD)),
+                         ids=[m["name"] for m in HELD])
+def test_an_entry_that_was_there_is_still_there_as_it_was(position, entry):
+    """Each entry of ``per_layer_held.json`` keeps its place at the head of
+    ``per_layer`` and every key byte for byte; its ``workloads`` may only
+    grow at the end (a cell joins a metric so). New entries come after
+    all of them."""
+    now = BENCH["per_layer"][position]
+    assert now["name"] == entry["name"]
+    cells = now.get("workloads")
+    if "workloads" in entry:
+        assert cells[:len(entry["workloads"])] == entry["workloads"]
+        now = dict(now, workloads=entry["workloads"])
+    assert json.dumps(now) == json.dumps(entry)
+
+
 @pytest.mark.parametrize("name", NEW)
 def test_an_entry_of_pr24_is_still_there_as_it_was(name):
     """Unit, source, layer and the metric it moves; its ``workloads`` may
