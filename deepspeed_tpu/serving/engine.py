@@ -54,6 +54,7 @@ SLO telemetry rides the established contract: metrics through the
 from the same metrics JSONL the training loop writes.
 """
 
+import contextlib
 import functools
 import time
 from typing import Any, Dict, List, Optional
@@ -71,7 +72,8 @@ from deepspeed_tpu.serving.kv_cache import (BlockPool, ChunkedLayerCache,
                                             live_block_list, pack_prefill)
 from deepspeed_tpu.serving.scheduler import (PrefixCache, Scheduler,
                                              Sequence)
-from deepspeed_tpu.telemetry.tracer import device_scope
+from deepspeed_tpu.telemetry.tracer import (device_scope,
+                                            profiler_session_live)
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.platform import on_tpu
 
@@ -374,7 +376,8 @@ class ServeEngine:
                                  donate_argnums=(0,))
         # The names of the int32 counters a model's serving programs
         # return beside the token (Nemotron-H: its expert layers'), which
-        # ride the decode and prefill spans; fetched WITH the token.
+        # ride the decode and prefill spans; fetched after the token, and
+        # only while a profiler session records.
         self._counter_names = tuple(
             getattr(engine.module, "SERVING_COUNTERS", ()))
         self._base_key = jax.random.PRNGKey(self.scfg.seed)
@@ -580,11 +583,13 @@ class ServeEngine:
         # -- decode one token for every running sequence ----------------
         # (a speculative round writes k+1 positions, so capacity is
         # ensured with that lookahead — capped at each row's lifetime)
-        active = self.sched.active
-        for seq in list(active):
-            if self.sched.running.get(seq.slot) is seq:
-                self.sched.ensure_capacity(seq, lookahead=self._spec_k)
-        active = self.sched.active          # preemption may have evicted
+        with self.telemetry.span("batch_inputs", step=self._step_count,
+                                 active=len(self.sched.running)):
+            active = self.sched.active
+            for seq in list(active):
+                if self.sched.running.get(seq.slot) is seq:
+                    self.sched.ensure_capacity(seq, lookahead=self._spec_k)
+            active = self.sched.active      # preemption may have evicted
         info["active"] = len(active)
         if acc is not None:
             acc.engine_mark("scheduler_admission")
@@ -596,27 +601,31 @@ class ServeEngine:
             if self._resil is not None:
                 n_tokens, dt_decode, active = self._resil.run_decode(
                     active, info)
-                self._resil.note_step(dt_decode)
             else:
                 n_tokens, dt_decode = self._decode_round(active, info)
-            if acc is not None:
-                grew = self._decode_programs() > n_djits
-                acc.engine_mark("compile" if grew else "decode")
-                still = [s for s in active
-                         if self.sched.running.get(s.slot) is s]
-                acc.on_decode_step(still, dt_decode, self._step_count)
-            self.stats["decode_steps"] += 1
-            self.stats["occupancy_sum"] += \
-                len(active) / self.scfg.max_batch_size
-            # Cumulative decode rate lives OUTSIDE the telemetry gate:
-            # the admission gate's projected-wait fallback needs it even
-            # on a telemetry-free engine (two host floats, no syncs).
-            if n_tokens and dt_decode > 0:
-                self._decode_tokens += n_tokens
-                self._decode_sec += dt_decode
-        # Gauges carry the SAME step index as this iteration's TTFT/
-        # completion rows (emitted above) — increment only afterwards.
-        self._emit_step_metrics(len(active), dt_decode, n_tokens)
+        with self._post_span(info):
+            if active:
+                if self._resil is not None:
+                    self._resil.note_step(dt_decode)
+                if acc is not None:
+                    grew = self._decode_programs() > n_djits
+                    acc.engine_mark("compile" if grew else "decode")
+                    still = [s for s in active
+                             if self.sched.running.get(s.slot) is s]
+                    acc.on_decode_step(still, dt_decode, self._step_count)
+                self.stats["decode_steps"] += 1
+                self.stats["occupancy_sum"] += \
+                    len(active) / self.scfg.max_batch_size
+                # Cumulative decode rate lives OUTSIDE the telemetry
+                # gate: the admission gate's projected-wait fallback needs
+                # it even on a telemetry-free engine (two host floats, no
+                # syncs).
+                if n_tokens and dt_decode > 0:
+                    self._decode_tokens += n_tokens
+                    self._decode_sec += dt_decode
+            # Gauges carry the SAME step index as this iteration's TTFT/
+            # completion rows (emitted above) — increment only afterwards.
+            self._emit_step_metrics(len(active), dt_decode, n_tokens)
         self._step_count += 1
         return info
 
@@ -764,28 +773,56 @@ class ServeEngine:
         bucket = seq.bucket
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :t] = seq.request.prompt          # right-pad: causal masking
-        dev_ids = jnp.asarray(ids)
-        length = jnp.asarray(t, jnp.int32)       # keeps pads invisible
-        rng = jax.random.fold_in(self._base_key, 2 * seq.request.rid + 1)
-        # Per-bucket detector scope: each bucket's one compile is the
-        # expected first trace, so a healthy engine never warns — a
-        # retrace under any of these names is a real bug.
-        self.engine.recompile_detector.check(
-            f"serving.prefill_b{bucket}", dev_ids, length)
+        with self._put_span(ids, np.int32(t), **self._prompt_ids(seq)):
+            dev_ids = jnp.asarray(ids)
+            length = jnp.asarray(t, jnp.int32)   # keeps pads invisible
+            rng = jax.random.fold_in(self._base_key,
+                                     2 * seq.request.rid + 1)
+            # Per-bucket detector scope: each bucket's one compile is the
+            # expected first trace, so a healthy engine never warns — a
+            # retrace under any of these names is a real bug.
+            self.engine.recompile_detector.check(
+                f"serving.prefill_b{bucket}", dev_ids, length)
         with self._prefill_span(seq, bucket, t) as span:
             tok, counters = self._prefill_and_pack(
                 seq, bucket, dev_ids, length, rng, measure=self._measure_kv)
-            first = int(tok)                     # host fetch = first token
+            (first,) = self._fetch(tok, **self._prompt_ids(seq))
             self._note_counters(span, counters)
-        self._record_first_token(seq, first)
+        self._record_first_token(seq, int(first))
 
     def _note_counters(self, span, counters) -> None:
         """A model's own counters onto the span of the dispatch that made
-        them; called after the token's fetch, so they are ready and cost
-        no wait of their own."""
-        if counters is not None:
+        them, fetched only while a profiler session records (a trace is
+        all that reads them); called after the token's fetch, so they are
+        ready and cost no wait of their own."""
+        if counters is not None and profiler_session_live():
             span.set_metadata(**dict(zip(
                 self._counter_names, map(int, np.asarray(counters)))))
+
+    # -- the host path of a dispatch, in spans ---------------------------
+    # Round each serving program's call: ``batch_inputs`` (the host
+    # arrays of a decode-side step), ``put`` (their transfers, the step's
+    # key and the recompile detector's check of what the program
+    # receives), ``dispatch`` (the jitted call), ``fetch`` (what the host
+    # waits for before it can go on) and ``post_step`` (what the host
+    # does with it). Spans of one step share ``step``, those of one prompt
+    # ``rid``.
+    def _prompt_ids(self, seq: Sequence) -> Dict[str, int]:
+        return {"step": self._step_count, "rid": seq.request.rid}
+
+    def _put_span(self, *host, **ids):
+        """The ``put`` span round the transfers of ``host`` (numpy arrays
+        and scalars, as the program receives them)."""
+        return self.telemetry.span(
+            "put", arrays=len(host),
+            bytes=int(sum(np.asarray(a).nbytes for a in host)), **ids)
+
+    def _fetch(self, *devs, **ids):
+        """``devs`` to the host under a ``fetch`` span: the blocking
+        transfer of what the host needs before it can go on."""
+        with self.telemetry.span("fetch", bytes=sum(d.nbytes for d in devs),
+                                 **ids):
+            return [np.asarray(d) for d in devs]
 
     def _prefill_and_pack(self, seq: Sequence, bucket: int, dev_ids, length,
                           rng, measure: bool = False):
@@ -799,13 +836,20 @@ class ServeEngine:
         if bucket not in self._prefill_jit:
             self._prefill_jit[bucket] = jax.jit(functools.partial(
                 self._prefill_impl, bucket=bucket))
-        tok, _logits, ks, vs, states, counters = self._prefill_jit[bucket](
-            self.engine.params, dev_ids, length, rng)
+        ids = self._prompt_ids(seq)
+        with self.telemetry.span("dispatch", program="prefill", **ids):
+            tok, _logits, ks, vs, states, counters = \
+                self._prefill_jit[bucket](self.engine.params, dev_ids,
+                                          length, rng)
         if measure:
             self._emit_kv_quant_error(ks, vs, length, bucket)
-        self._pools = self._pack_jit(
-            self._pools, jnp.asarray(seq.block_table, jnp.int32), ks, vs,
-            jnp.asarray(seq.slot, jnp.int32), states, kinds=self._kinds)
+        table = np.asarray(seq.block_table, np.int32)
+        slot = np.int32(seq.slot)
+        with self._put_span(table, slot, **ids):
+            table, slot = jnp.asarray(table), jnp.asarray(slot)
+        with self.telemetry.span("dispatch", program="pack", **ids):
+            self._pools = self._pack_jit(self._pools, table, ks, vs, slot,
+                                         states, kinds=self._kinds)
         return tok, counters
 
     def _prefill_tail(self, seq: Sequence) -> None:
@@ -827,22 +871,29 @@ class ServeEngine:
         ids[0, :tail] = seq.request.prompt[sl:]
         bt = np.zeros((1, self.max_blocks), np.int32)
         bt[0, :len(seq.block_table)] = seq.block_table
-        dev_ids, dev_bt = jnp.asarray(ids), jnp.asarray(bt)
-        start = jnp.asarray([sl], jnp.int32)
-        length = jnp.asarray(tail, jnp.int32)
-        rng = jax.random.fold_in(self._base_key, 2 * seq.request.rid + 1)
-        self.engine.recompile_detector.check(
-            f"serving.prefill_tail_b{tb}", dev_ids, dev_bt, start, length)
+        ids_of = self._prompt_ids(seq)
+        with self._put_span(ids, bt, np.int32([sl]), np.int32(tail),
+                            **ids_of):
+            dev_ids, dev_bt = jnp.asarray(ids), jnp.asarray(bt)
+            start = jnp.asarray([sl], jnp.int32)
+            length = jnp.asarray(tail, jnp.int32)
+            rng = jax.random.fold_in(self._base_key,
+                                     2 * seq.request.rid + 1)
+            self.engine.recompile_detector.check(
+                f"serving.prefill_tail_b{tb}", dev_ids, dev_bt, start,
+                length)
         if tb not in self._tail_prefill_jit:
             self._tail_prefill_jit[tb] = jax.jit(functools.partial(
                 self._prefill_tail_impl, tail_bucket=tb),
                 donate_argnums=(1,))
         with self._prefill_span(seq, tb, t, shared_len=sl):
-            tok, self._pools = self._tail_prefill_jit[tb](
-                self.engine.params, self._pools, dev_ids, dev_bt, start,
-                length, rng)
-            first = int(tok)                     # host fetch = first token
-        self._record_first_token(seq, first)
+            with self.telemetry.span("dispatch", program="prefill_tail",
+                                     **ids_of):
+                tok, self._pools = self._tail_prefill_jit[tb](
+                    self.engine.params, self._pools, dev_ids, dev_bt, start,
+                    length, rng)
+            (first,) = self._fetch(tok, **ids_of)
+        self._record_first_token(seq, int(first))
 
     def _prefill_span(self, seq: Sequence, bucket: int, prompt_len: int,
                       **ids):
@@ -933,7 +984,8 @@ class ServeEngine:
             c = min(self._chunk_budget, total - t0)
             rows = [(seq.slot, replay[t0 + i], t0 + i) for i in range(c)]
             with self._prefill_span(seq, seq.bucket, total, replay=1):
-                self._mixed_dispatch([seq], rows, 1)
+                self._mixed_dispatch(*self._mixed_inputs([seq], rows, 1),
+                                     len(rows))
             t0 += c
 
     @device_scope("prefill")
@@ -1010,15 +1062,25 @@ class ServeEngine:
             toks, logits = self._decode(active)
             dt_decode = time.perf_counter() - t_dec
             n_tokens = len(active)
-            for seq, tok in zip(active, toks):
-                seq.tokens.append(int(tok))
-                seq.pos += 1
-                if seq.finished():
-                    self._finish(seq, info)
-            if self.capture_logits:
-                info["logits"] = logits
-                info["slots"] = {s.slot: s.request.rid for s in active}
+            with self._post_span(info):
+                for seq, tok in zip(active, toks):
+                    seq.tokens.append(int(tok))
+                    seq.pos += 1
+                    if seq.finished():
+                        self._finish(seq, info)
+                if self.capture_logits:
+                    info["logits"] = logits
+                    info["slots"] = {s.slot: s.request.rid for s in active}
         return n_tokens, dt_decode
+
+    @contextlib.contextmanager
+    def _post_span(self, info: Dict[str, Any]):
+        """The ``post_step`` span round the host's bookkeeping after a
+        step's fetch; ``finished``, the requests the step has finished so
+        far, is set as it closes."""
+        with self.telemetry.span("post_step", step=self._step_count) as sp:
+            yield
+            sp.set_metadata(finished=len(info["finished"]))
 
     def _inject_storm(self) -> None:
         """FaultPlan request storm: a burst of duplicates of the last
@@ -1066,11 +1128,10 @@ class ServeEngine:
             toks[s] = seq.tokens[-1]
         return bt, pos, toks
 
-    def _dispatch_batch(self, active: List[Sequence], chunk: int,
-                        scope: str):
-        """Dispatch prep of the programs that read the table's width (the
-        kernel decode, every speculative round): the fault hook, the
-        batch matrices, the position counts and the detector check."""
+    def _table_inputs(self, active: List[Sequence], chunk: int):
+        """Host inputs of the programs that read the table's width (the
+        kernel and window decodes, every speculative round): the fault
+        hook, the batch matrices and the position counts."""
         self._fault_hook()
         bt, pos, toks = self._batch_inputs(active)
         if self._attn_impl == "kernel":
@@ -1079,15 +1140,13 @@ class ServeEngine:
         ids = self._count_positions(
             len(active), live=int(pos.sum()) + len(active) * chunk,
             read=bt.size * self.block_size)
-        bt, pos, toks = jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(toks)
-        self.engine.recompile_detector.check(scope, toks, pos, bt)
         return (bt, pos, toks), ids
 
-    def _dispatch_live(self, active: List[Sequence]):
-        """Dispatch prep of the default decode: the batch matrices and,
+    def _live_inputs(self, active: List[Sequence]):
+        """Host inputs of the default decode: the batch matrices and,
         beside them, the flat list of the batch's live blocks
         (kv_cache.live_block_list) that the program attends over. One
-        detector scope and one signature whatever is live."""
+        signature whatever is live."""
         self._fault_hook()
         bt, pos, toks = self._batch_inputs(active)
         live, n_live, n_chunks = live_block_list(
@@ -1099,10 +1158,7 @@ class ServeEngine:
             len(active), live=int(pos.sum()) + len(active),
             read=n_chunks * self.live_chunk_positions,
             live_blocks=n_live, chunks=n_chunks)
-        args = tuple(jnp.asarray(a) for a in (
-            bt, pos, toks, live, np.int32(n_chunks)))
-        self.engine.recompile_detector.check("serving.decode_step", *args)
-        return args, ids
+        return (bt, pos, toks, live, np.int32(n_chunks)), ids
 
     def _decode_programs(self) -> int:
         """How many of the decode-side programs (decode, speculative,
@@ -1121,32 +1177,41 @@ class ServeEngine:
         return {"step": self._step_count, "active": active, **counts}
 
     def _decode(self, active: List[Sequence]):
-        if self._attn_impl in ("kernel", "window"):
-            args, ids = self._dispatch_batch(
-                active, 1, "serving.decode_step")
-        else:
-            args, ids = self._dispatch_live(active)
-        bt, pos, toks, *live = args
-        rng = jax.random.fold_in(self._base_key, 2 * self._step_count)
+        step = self._step_count
+        with self.telemetry.span("batch_inputs", step=step,
+                                 active=len(active)):
+            if self._attn_impl in ("kernel", "window"):
+                host, ids = self._table_inputs(active, 1)
+            else:
+                host, ids = self._live_inputs(active)
+            more = {}
+            if not self._all_kv:
+                # which rows hold a request: a dead slot's recurrent state
+                # stays as it is and its row is routed to no expert
+                alive = np.zeros((self.scfg.max_batch_size,), bool)
+                alive[[s.slot for s in active]] = True
+                more["alive"] = alive
+                if self._stateful:
+                    ids["state_slots_live"] = len(active)
+        with self._put_span(*host, *more.values(), step=step):
+            bt, pos, toks, *live = map(jnp.asarray, host)
+            more = {k: jnp.asarray(v) for k, v in more.items()}
+            rng = jax.random.fold_in(self._base_key, 2 * step)
+            self.engine.recompile_detector.check(
+                "serving.decode_step", bt, pos, toks, *live)
         if self._decode_jit is None:
             self._decode_jit = jax.jit(
                 functools.partial(self._decode_impl,
                                   attn_impl=self._attn_impl),
                 donate_argnums=(1,))
-        more = {}
-        if not self._all_kv:
-            # which rows hold a request: a dead slot's recurrent state
-            # stays as it is and its row is routed to no expert
-            alive = np.zeros((self.scfg.max_batch_size,), bool)
-            alive[[s.slot for s in active]] = True
-            more["alive"] = jnp.asarray(alive)
-            if self._stateful:
-                ids["state_slots_live"] = len(active)
         with self.telemetry.span("decode_step", **ids) as span:
-            tok_dev, logits, self._pools, counters = self._decode_jit(
-                self.engine.params, self._pools, bt, pos, toks, rng, *live,
-                **more)
-            tok_host = np.asarray(tok_dev)       # host fetch: finish checks
+            with self.telemetry.span("dispatch", program="decode",
+                                     step=step):
+                tok_dev, logits, self._pools, counters = self._decode_jit(
+                    self.engine.params, self._pools, bt, pos, toks, rng,
+                    *live, **more)
+            # host fetch: finish checks
+            (tok_host,) = self._fetch(tok_dev, step=step)
             self._note_counters(span, counters)
         logits_host = np.asarray(logits) if self.capture_logits else None
         return [int(tok_host[s.slot]) for s in active], logits_host
@@ -1190,59 +1255,63 @@ class ServeEngine:
                 "capture_logits is not supported with chunked prefill — "
                 "a mixed step has no per-slot logits row to expose "
                 "(docs/SERVING.md)")
-        decoding = [s for s in active
-                    if s.prefilled >= len(s.request.prompt)]
-        prefilling = sorted(
-            (s for s in active if s.prefilled < len(s.request.prompt)),
-            key=lambda s: (s.admitted_step, s.request.rid))
-        self._fault_hook()   # live rounds only — replay never injects
-        rows = [(s.slot, s.tokens[-1], s.pos) for s in decoding]
-        chunks = []                              # (seq, first_row, count)
-        for s in prefilling:
-            if len(rows) >= self._chunk_budget:
-                break
-            t0 = s.prefilled
-            c = min(len(s.request.prompt) - t0,
-                    self._chunk_budget - len(rows))
-            chunks.append((s, len(rows), c))
-            rows.extend((s.slot, s.request.prompt[t0 + i], t0 + i)
-                        for i in range(c))
-        tok_host = self._mixed_dispatch(active, rows, len(active))
+        step = self._step_count
+        with self.telemetry.span("batch_inputs", step=step,
+                                 active=len(active)):
+            decoding = [s for s in active
+                        if s.prefilled >= len(s.request.prompt)]
+            prefilling = sorted(
+                (s for s in active if s.prefilled < len(s.request.prompt)),
+                key=lambda s: (s.admitted_step, s.request.rid))
+            self._fault_hook()   # live rounds only — replay never injects
+            rows = [(s.slot, s.tokens[-1], s.pos) for s in decoding]
+            chunks = []                          # (seq, first_row, count)
+            for s in prefilling:
+                if len(rows) >= self._chunk_budget:
+                    break
+                t0 = s.prefilled
+                c = min(len(s.request.prompt) - t0,
+                        self._chunk_budget - len(rows))
+                chunks.append((s, len(rows), c))
+                rows.extend((s.slot, s.request.prompt[t0 + i], t0 + i)
+                            for i in range(c))
+            host, ids = self._mixed_inputs(active, rows, len(active))
+        tok_host = self._mixed_dispatch(host, ids, len(rows))
         self._chunk_tokens_last = len(rows)
         appended = 0
-        for r, seq in enumerate(decoding):
-            seq.tokens.append(int(tok_host[r]))
-            seq.pos += 1
-            appended += 1
-            if seq.finished():
-                self._finish(seq, info)
-        for seq, r0, c in chunks:
-            seq.prefilled += c
-            seq.pos = seq.prefilled
-            if seq.prefilled == len(seq.request.prompt):
-                # Prompt complete: the chunk's final row sits at the
-                # last prompt position — its sampled token is the first
-                # generated token (TTFT lands here).
-                self._record_first_token(seq, int(tok_host[r0 + c - 1]))
+        with self._post_span(info):
+            for r, seq in enumerate(decoding):
+                seq.tokens.append(int(tok_host[r]))
+                seq.pos += 1
                 appended += 1
-                if self._req_acc is not None:
-                    self._req_acc.on_prefilled(seq)
-                self.sched.register_prefix(seq, self._step_count)
-                info["prefilled"].append(seq.request.rid)
-                if seq.finished():   # max_new_tokens == 1 / instant EOS
+                if seq.finished():
                     self._finish(seq, info)
+            for seq, r0, c in chunks:
+                seq.prefilled += c
+                seq.pos = seq.prefilled
+                if seq.prefilled == len(seq.request.prompt):
+                    # Prompt complete: the chunk's final row sits at the
+                    # last prompt position — its sampled token is the
+                    # first generated token (TTFT lands here).
+                    self._record_first_token(seq,
+                                             int(tok_host[r0 + c - 1]))
+                    appended += 1
+                    if self._req_acc is not None:
+                        self._req_acc.on_prefilled(seq)
+                    self.sched.register_prefix(seq, self._step_count)
+                    info["prefilled"].append(seq.request.rid)
+                    if seq.finished():   # max_new_tokens == 1 / instant EOS
+                        self._finish(seq, info)
         return appended
 
-    def _mixed_dispatch(self, table_seqs: List[Sequence], rows,
-                        n_active: int):
-        """Dispatch one ragged token batch. ``rows``: ``(slot, token,
-        position)`` triples (decode rows then chunk rows); the batch is
-        padded to the token budget with scratch rows — slot
+    def _mixed_inputs(self, table_seqs: List[Sequence], rows,
+                      n_active: int):
+        """Host inputs of one ragged token batch. ``rows``: ``(slot,
+        token, position)`` triples (decode rows then chunk rows); the
+        batch is padded to the token budget with scratch rows — slot
         ``max_batch_size`` maps to the spare all-zeros table row, so pad
         writes land in the reserved scratch block and pad reads stay
-        masked. ONE detector scope, ONE jit entry, ever: every mixed
-        step has the same signature regardless of the decode/prefill
-        mix."""
+        masked."""
         nb, mb = self.scfg.max_batch_size, self.max_blocks
         bt = np.zeros((nb + 1, mb), np.int32)    # row nb: pad/scratch row
         toks = np.zeros((self._chunk_budget,), np.int32)
@@ -1257,19 +1326,29 @@ class ServeEngine:
         ids = self._count_positions(
             n_active, live=int(pos.sum()) + len(rows),
             read=self._chunk_budget * mb * self.block_size)
-        bt, pos, toks, slots = (jnp.asarray(bt), jnp.asarray(pos),
-                                jnp.asarray(toks), jnp.asarray(slots))
-        self.engine.recompile_detector.check("serving.mixed_step", toks,
-                                             pos, slots, bt)
+        return (bt, pos, toks, slots), ids
+
+    def _mixed_dispatch(self, host, ids: Dict[str, int], n_rows: int):
+        """Dispatch one ragged token batch (``_mixed_inputs``). ONE
+        detector scope, ONE jit entry, ever: every mixed step has the same
+        signature regardless of the decode/prefill mix."""
+        step = self._step_count
+        with self._put_span(*host, step=step):
+            bt, pos, toks, slots = map(jnp.asarray, host)
+            self.engine.recompile_detector.check("serving.mixed_step", toks,
+                                                 pos, slots, bt)
+            rng = jax.random.fold_in(self._base_key, 2 * step)
         if self._mixed_jit is None:
             self._mixed_jit = jax.jit(self._mixed_impl,
                                       donate_argnums=(1,))
-        rng = jax.random.fold_in(self._base_key, 2 * self._step_count)
-        with self.telemetry.span("mixed_step", tokens=len(rows), **ids):
-            tok_dev, self._pools = self._mixed_jit(
-                self.engine.params, self._pools, bt, pos, slots, toks,
-                rng)
-            tok_host = np.asarray(tok_dev)       # host fetch: finish checks
+        with self.telemetry.span("mixed_step", tokens=n_rows, **ids):
+            with self.telemetry.span("dispatch", program="mixed",
+                                     step=step):
+                tok_dev, self._pools = self._mixed_jit(
+                    self.engine.params, self._pools, bt, pos, slots, toks,
+                    rng)
+            # host fetch: finish checks
+            (tok_host,) = self._fetch(tok_dev, step=step)
         return tok_host
 
     @device_scope("decode")
@@ -1341,40 +1420,51 @@ class ServeEngine:
                 "decoding — a spec round has no single per-step logits "
                 "row to expose (docs/SERVING.md)")
         k = self._spec_k
-        (bt, pos, toks), ids = self._dispatch_batch(
-            active, k + 1, "serving.spec_step")
+        step = self._step_count
+        with self.telemetry.span("batch_inputs", step=step,
+                                 active=len(active)):
+            host, ids = self._table_inputs(active, k + 1)
+        with self._put_span(*host, step=step):
+            bt, pos, toks = map(jnp.asarray, host)
+            self.engine.recompile_detector.check("serving.spec_step", bt,
+                                                 pos, toks)
         if self._spec_jit is None:
             self._spec_jit = jax.jit(
                 functools.partial(self._spec_impl, k=k,
                                   attn_impl=self._attn_impl),
                 donate_argnums=(1,))
         with self.telemetry.span("spec_step", k=k, **ids):
-            chunk_dev, greedy_dev, self._pools = self._spec_jit(
-                self.engine.params, self._pools, bt, pos, toks)
-            chunk = np.asarray(chunk_dev)        # [B, k+1] verify inputs
-            greedy = np.asarray(greedy_dev)      # [B, k+1] target argmax
+            with self.telemetry.span("dispatch", program="spec",
+                                     step=step):
+                chunk_dev, greedy_dev, self._pools = self._spec_jit(
+                    self.engine.params, self._pools, bt, pos, toks)
+            # [B, k+1] verify inputs, [B, k+1] target argmax
+            chunk, greedy = self._fetch(chunk_dev, greedy_dev, step=step)
         appended = 0
-        for seq in active:
-            s = seq.slot
-            drafted = chunk[s, 1:]               # d_1..d_k
-            target = greedy[s]                   # g_1..g_{k+1}
-            accept = 0
-            while accept < k and int(drafted[accept]) == int(target[accept]):
-                accept += 1
-            self.stats["spec_proposed"] += k
-            self.stats["spec_accepted"] += accept
-            # d_1..d_a are the target's own greedy tokens (they matched);
-            # g_{a+1} is the correction/bonus — every appended token is
-            # exactly what greedy non-speculative decode would emit.
-            for tok in list(drafted[:accept]) + [target[accept]]:
-                seq.tokens.append(int(tok))
-                seq.pos += 1
-                appended += 1
-                if seq.finished():
-                    self._finish(seq, info)
-                    break
-        self.stats["spec_rounds"] += 1
-        self.stats["spec_new_tokens"] += appended
+        with self._post_span(info):
+            for seq in active:
+                s = seq.slot
+                drafted = chunk[s, 1:]           # d_1..d_k
+                target = greedy[s]               # g_1..g_{k+1}
+                accept = 0
+                while accept < k and \
+                        int(drafted[accept]) == int(target[accept]):
+                    accept += 1
+                self.stats["spec_proposed"] += k
+                self.stats["spec_accepted"] += accept
+                # d_1..d_a are the target's own greedy tokens (they
+                # matched); g_{a+1} is the correction/bonus — every
+                # appended token is exactly what greedy non-speculative
+                # decode would emit.
+                for tok in list(drafted[:accept]) + [target[accept]]:
+                    seq.tokens.append(int(tok))
+                    seq.pos += 1
+                    appended += 1
+                    if seq.finished():
+                        self._finish(seq, info)
+                        break
+            self.stats["spec_rounds"] += 1
+            self.stats["spec_new_tokens"] += appended
         return appended
 
     @device_scope("decode")

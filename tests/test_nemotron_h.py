@@ -32,6 +32,7 @@ from benchmarks.reference import nemotron_h as reference        # noqa: E402
 from deepspeed_tpu.config.config import ConfigError             # noqa: E402
 from deepspeed_tpu.moe import dropless                          # noqa: E402
 from deepspeed_tpu.ops import ssm                               # noqa: E402
+from deepspeed_tpu.serving import engine as serving_engine    # noqa: E402
 
 CELL = "nemotron3s-serve-chat"
 TOL = 2e-5
@@ -345,7 +346,10 @@ class RecordingTelemetry:
         pass
 
 
-def test_the_spans_carry_the_counters_of_the_new_state():
+def test_the_spans_carry_the_counters_of_the_new_state(monkeypatch):
+    # they reach a span only while a profiler session records
+    monkeypatch.setattr(serving_engine, "profiler_session_live",
+                        lambda: True)
     model, params, _ = build()
     tel = RecordingTelemetry()
     srv = serve(model, params, telemetry=tel)
@@ -366,6 +370,52 @@ def test_the_spans_carry_the_counters_of_the_new_state():
         assert s["read_positions"] >= s["live_positions"] > 0
     for s in prefills:
         assert 0 <= s["moe_held_assignments"] <= s["prompt_len"] * k * layers
+    srv.close()
+
+
+class Watched:
+    """An output of the decode program that says when the host reads it:
+    the engine may hand it on, and every read (``np.asarray``) goes on
+    the list; any other use of it fails."""
+
+    def __init__(self, name, array, reads):
+        self.name, self.array, self.reads = name, array, reads
+        self.nbytes = array.nbytes
+
+    def __array__(self, dtype=None, copy=None):
+        self.reads.append(self.name)
+        return np.asarray(self.array, dtype=dtype)
+
+
+def test_an_untraced_step_fetches_nothing_but_its_tokens(monkeypatch):
+    """Of what the decode program returns the host reads the batch's
+    tokens and nothing else; the model's counters too only while a
+    profiler session records."""
+    model, params, _ = build()
+    srv = serve(model, params)
+    rng = np.random.default_rng(12)
+    for n in (6, 9):
+        srv.submit(rng.integers(0, 512, n).tolist(), 8)
+    while len(srv.sched.running) < 2:   # both prefilled, decoding
+        srv.step()
+    reads, program = [], srv._decode_jit
+
+    def watched(*args, **kw):
+        tok, logits, pools, counters = program(*args, **kw)
+        return (Watched("tokens", tok, reads),
+                Watched("logits", logits, reads), pools,
+                Watched("counters", counters, reads))
+
+    srv._decode_jit = watched
+    for traced, want in ((False, ["tokens"]),
+                         (True, ["tokens", "counters"]),
+                         (False, ["tokens"])):
+        monkeypatch.setattr(serving_engine, "profiler_session_live",
+                            lambda: traced)
+        reads.clear()
+        info = srv.step()
+        assert (info["active"], info["prefilled"]) == (2, [])
+        assert reads == want, traced
     srv.close()
 
 

@@ -352,15 +352,144 @@ def test_serve_spans_nest_and_carry_their_identifiers(serve_capture):
 
 def test_a_step_opens_few_spans_whatever_the_number_of_active_rows(
         serve_capture):
+    """Two levels below a step and no deeper: a prompt admitted opens
+    ``admit``, its ``put``, ``prefill`` and four spans inside it; the
+    decode ``batch_inputs`` twice, ``put``, ``decode_step`` with two
+    inside, ``post_step`` twice."""
     trace = serve_capture[0].trace
     steps = named(trace, "serve_step")
     seen = set()
     for s in steps:
-        opened = 1 + len(s.children)
-        assert all(not trace.spans[c].children for c in s.children)
-        assert opened <= 3 + 2 * MAX_PREFILLS, (s.stats, opened)
+        inner = [g for c in s.children for g in trace.spans[c].children]
+        assert all(not trace.spans[g].children for g in inner)
+        opened = 1 + len(s.children) + len(inner)
+        assert opened <= 9 + 7 * MAX_PREFILLS, (s.stats, opened)
         seen.add(s.stats["active"])
     assert len(seen) > 1            # steps of several occupancies were seen
+
+
+# Where each span of a step's host path lies, and what it carries
+HOST_PATH = {"batch_inputs": ({"serve_step"}, {"step", "active"}),
+             "put": ({"serve_step", "prefill"}, {"step", "arrays", "bytes"}),
+             "dispatch": ({"prefill", "decode_step", "mixed_step"},
+                          {"step", "program"}),
+             "fetch": ({"prefill", "decode_step", "mixed_step"},
+                       {"step", "bytes"}),
+             "post_step": ({"serve_step"}, {"step", "finished"})}
+
+
+def test_the_host_path_of_a_step_nests_in_the_spans_that_were_there(
+        serve_capture):
+    cap, srv, _, _, reports = serve_capture
+    trace = cap.trace
+    nb, mb = srv.scfg.max_batch_size, srv.max_blocks
+    for name, (parents, stats) in HOST_PATH.items():
+        spans = named(trace, name)
+        assert spans, name
+        for s in spans:
+            parent = trace.spans[s.parent]
+            assert parent.name in parents, (name, parent.name)
+            assert set(s.stats) - {"rid"} == stats, (name, s.stats)
+            # a step's spans share its step, a prompt's its rid
+            assert s.stats["step"] == parent.stats["step"]
+            if parent.name == "prefill":
+                assert s.stats["rid"] == parent.stats["rid"]
+    # what a decode step and a prompt's prefill hold, in order, and what
+    # went over the wire each way
+    for s in named(trace, "decode_step"):
+        dispatch, fetch = [trace.spans[c] for c in s.children]
+        assert (dispatch.name, fetch.name) == ("dispatch", "fetch")
+        assert dispatch.stats["program"] == "decode"
+        assert fetch.stats["bytes"] == 4 * nb          # the tokens
+    for s in named(trace, "prefill"):
+        inside = [trace.spans[c] for c in s.children]
+        assert [(c.name, c.stats.get("program")) for c in inside] == [
+            ("dispatch", "prefill"), ("put", None), ("dispatch", "pack"),
+            ("fetch", None)]
+        pack_put, fetch = inside[1], inside[3]
+        bucket = s.stats["bucket"]
+        # the pack's table (a block a 4 positions) and slot
+        assert (pack_put.stats["arrays"], pack_put.stats["bytes"]) == (
+            2, bucket + 4)
+        assert fetch.stats["bytes"] == 4               # the first token
+        # the prompt's own put comes just before its prefill
+        step = trace.spans[s.parent]
+        before = trace.spans[step.children[step.children.index(
+            trace.spans.index(s)) - 1]]
+        assert (before.name, before.stats["rid"]) == ("put", s.stats["rid"])
+        assert (before.stats["arrays"], before.stats["bytes"]) == (
+            2, 4 * bucket + 4)
+    for s in named(trace, "serve_step"):
+        order = [trace.spans[c].name for c in s.children]
+        if "decode_step" in order:
+            at = order.index("decode_step")
+            assert order[at - 3:at] == ["batch_inputs", "batch_inputs",
+                                        "put"]
+            assert order[at + 1:] == ["post_step", "post_step"]
+            put = trace.spans[s.children[at - 1]]
+            decode = trace.spans[s.children[at]]
+            # the batch matrices, the live list and its chunk count
+            assert put.stats["arrays"] == 5
+            assert put.stats["bytes"] == 4 * (
+                nb * mb + 2 * nb + 1 + srv._live_chunks
+                * srv.LIVE_CHUNK_RUNS * (srv.LIVE_RUN_BLOCKS + 2))
+            assert decode.stats["active"] == \
+                trace.spans[s.children[at - 2]].stats["active"]
+        else:
+            assert order[-2:] == ["batch_inputs", "post_step"]
+    # the reports say which requests a step finished; the last post_step
+    # of a step says how many
+    for s, report in zip(named(trace, "serve_step"), reports):
+        last = trace.spans[s.children[-1]]
+        assert last.stats["finished"] == len(report["finished"])
+
+
+@pytest.mark.parametrize("path", ["window", "chunked"])
+def test_the_host_path_nests_alike_in_the_other_decodes(gpt_setup, path,
+                                                        tmp_path):
+    """The table-window decode (the one a grouped-attention model takes,
+    here forced on GPT) and the chunked prefill's mixed step: the spans of
+    the host path lie where they lie in the default decode, and the spans
+    that were there hold nothing else."""
+    if path == "chunked":
+        srv = serve_engine(gpt_setup, chunked_prefill=True,
+                           chunked_token_budget=8)
+    else:
+        srv = serve_engine(gpt_setup)
+        srv._attn_impl = "window"
+    rng = np.random.default_rng(1)
+    srv.submit(rng.integers(0, 100, 5).tolist(), 2)     # compile outside
+    srv.run_until_complete()
+    with Capture(tmp_path) as cap:
+        for n, new in ((6, 5), (11, 3)):
+            srv.submit(rng.integers(0, 100, n).tolist(), new)
+        srv.run_until_complete()
+    trace = cap.trace
+    decoding = "mixed_step" if path == "chunked" else "decode_step"
+    assert named(trace, decoding)
+    for name, (parents, stats) in HOST_PATH.items():
+        for s in named(trace, name):
+            assert trace.spans[s.parent].name in parents, (name, s.stats)
+            assert set(s.stats) - {"rid"} == stats
+    for s in named(trace, decoding):
+        assert [trace.spans[c].name for c in s.children] == ["dispatch",
+                                                             "fetch"]
+        program = trace.spans[s.children[0]].stats["program"]
+        assert program == ("mixed" if path == "chunked" else "decode")
+    for s in named(trace, "serve_step"):
+        order = [trace.spans[c].name for c in s.children]
+        if decoding in order:
+            at = order.index(decoding)
+            assert order[at - 3:at] == ["batch_inputs", "batch_inputs",
+                                        "put"]
+            put = trace.spans[s.children[at - 1]]
+            # the table, positions, tokens (and the mixed step's slots)
+            assert put.stats["arrays"] == (4 if path == "chunked" else 3)
+    if path == "chunked":                   # no prefill program at all
+        assert not named(trace, "prefill")
+    assert {s.name for s in trace.spans} == {
+        "serve_step", "admit", "submit", "put", "prefill", decoding,
+        *HOST_PATH} - ({"prefill"} if path == "chunked" else set())
 
 
 def test_the_useful_share_of_the_kv_read_is_the_ratio_of_the_totals(
