@@ -245,6 +245,38 @@ def _grouped_matmul_case(slots: int) -> KernelCase:
             jax.lax.ragged_dot(lhs, rhs, sizes), sizes))
 
 
+# -- the Mamba-2 decode step over the live slots (ops/ssm.py) ---------------
+def _ssm_step_case(slots: int) -> KernelCase:
+    from deepspeed_tpu.ops.ssm import ssm_step, ssm_step_live
+
+    # nemotron3s-serve-chat's recurrence: 128 heads x 64 x 128 a slot, 8
+    # groups; every third slot and the last alive, the rest keep their state
+    heads, p, n, groups = 128, 64, 128, 8
+    live = (np.arange(slots) % 3 == 0) | (np.arange(slots) == slots - 1)
+
+    def make_args(rng):
+        return (_normal(rng, (slots, heads, p), jnp.bfloat16),
+                jax.nn.softplus(_normal(rng, (slots, heads)) - 2.0),
+                -jnp.arange(1, heads + 1, dtype=jnp.float32),
+                _normal(rng, (slots, groups, n), jnp.bfloat16),
+                _normal(rng, (slots, groups, n), jnp.bfloat16),
+                _normal(rng, (heads,)), _normal(rng, (slots, heads, p, n)),
+                jnp.asarray(live))
+
+    def reference(*args):
+        # without ``live`` the step is XLA's on every platform
+        *inputs, state, alive = args
+        y, new = ssm_step(*inputs, state)
+        return (jnp.where(alive[:, None, None], y, 0.0),
+                jnp.where(alive[:, None, None, None], new, state))
+
+    return KernelCase(
+        f"ssm_step_live {slots} slots x {heads}x{p}x{n}, {live.sum()} live",
+        make_args,
+        lambda interpret, *args: ssm_step_live(*args, interpret=interpret),
+        reference)
+
+
 def kernel_cases() -> List[KernelCase]:
     return [
         _flash_case(64, jnp.float32), _flash_case(128, jnp.float32),
@@ -261,4 +293,5 @@ def kernel_cases() -> List[KernelCase]:
         _chunked_case(int8=False), _chunked_case(int8=True),
         # the cell's decode (128 slots) and its largest prefill bucket
         _grouped_matmul_case(128), _grouped_matmul_case(2048),
+        _ssm_step_case(16),
     ]

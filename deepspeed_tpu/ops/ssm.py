@@ -15,6 +15,10 @@ scan's matmuls run at full float32 precision (they are small: 13 GFLOP a
 layer for a 2048-token prompt), so the state a prompt leaves in its slot
 is the recurrence's own and not a bfloat16 rendering of it.
 
+:func:`ssm_step` is one position of every slot; on a TPU, with the slots'
+live mask, it is :func:`ssm_step_live`, a Pallas kernel that reads and
+writes the state of the live slots alone, in place.
+
 :func:`ssm_scan` never loops over positions. A sequence is cut into
 chunks of ``chunk`` positions; inside a chunk every output is a masked
 ``[chunk, chunk]`` matmul (the decays between two positions of a chunk are
@@ -26,12 +30,17 @@ given (``dt`` zeroed past its length), and how a length that is no
 multiple of ``chunk`` is padded here.
 """
 
+from functools import lru_cache, partial
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.telemetry.tracer import device_scope
+from deepspeed_tpu.utils.logging import logger
+from deepspeed_tpu.utils.platform import on_tpu
 
 F32 = jnp.float32
 
@@ -136,7 +145,14 @@ def ssm_step(u: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     [B, G, N]``, ``state [B, H, P, N]`` float32. Returns ``(y [B, H, P]``
     float32, the new state``)``. A row that is not ``live`` (``[B]`` bool)
     keeps its state. Elementwise in float32 row by row, so a row's result
-    is the same bits whatever the other rows hold."""
+    is the same bits whatever the other rows hold.
+
+    With ``live`` on a TPU the step is :func:`ssm_step_live`, which moves
+    the live rows' state alone (and gives a dead row's ``y`` as 0);
+    elsewhere every row is computed and a dead one's new state dropped."""
+    if live is not None and on_tpu():
+        _log_live_step(state.shape)
+        return ssm_step_live(u, dt, a, b, c, d, state, live)
     h = u.shape[1]
     dt = dt.astype(F32)
     bh = _heads_of_groups(b, h).astype(F32)                  # [B, H, N]
@@ -148,3 +164,149 @@ def ssm_step(u: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     if live is not None:
         new = jnp.where(live[:, None, None, None], new, state)
     return y, new
+
+
+# -- the decode step over the live slots: Pallas TPU kernel ``ssm_step_live``
+
+# A block is one slot's whole state. Measured on a v5e
+# (tools/probe_ssm_step.py; PERF.md section 6), device us of one layer's
+# step at nemotron3s-serve-chat's shapes, 128 slots of 128 heads x 64 x
+# 128 float32 (4 MiB a slot), all of the step's ops; "least" is the live
+# slots' state read and written at 819 GB/s. "As here": a group's rows
+# written out, the groups in a loop, with a block of 128 heads (the whole
+# slot) and, in a form since dropped, of 64 and 32; "written out": all 64
+# rows of a slot; "rolled": every row in a loop; "first form": the state
+# in its own layout, a head's dt u broadcast across N from one lane, y
+# summed across the lanes:
+#
+#   slots   least   XLA's   as here, heads a block  written   rolled   first
+#   alive           step     128     64     32      out (128)  (128)    form
+#     1      10.2   1666       63     72     98         62       76      53
+#    16     163.9   1666      250    262    284        247      390     280
+#    38     389.2   1666      530    539    556        527      850     614
+#    80     819.4   1666     1062   1066   1077       1060     1728    1250
+#   128    1311.0   1666     1672   1670   1672       1669     2731    1977
+#
+# The kernel alone takes 12.7 us a live slot (81% of the 10.2 its bytes
+# need) and 33 us for the rest (the visit list, dt u and the decay laid
+# out by lanes, y's mask); XLA's step moves all 128 slots at 79%. The
+# first form took 15.2 us a slot, every row rolled 21. A whole slot a block
+# is the fastest, or level, at every count.
+
+
+def row_heads(p: int, per_group: int) -> int:
+    """The heads of one row of a block: as many as fill 128 lanes (two at
+    ``P`` 64) where they lie in one group, else a group's."""
+    r = 128 // p if 128 % p == 0 else 0
+    return r if r and per_group % r == 0 else per_group
+
+
+@lru_cache(maxsize=None)
+def _log_live_step(shape: Tuple[int, ...]) -> None:
+    """Say once per shape that the step takes the kernel."""
+    slots, heads, p, n = shape
+    logger.info(f"ssm_step over live slots -> pallas (slots {slots}, heads "
+                f"a block {heads}, bytes a slot {heads * p * n * 4})")
+
+
+def slot_visits(live: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``(slots, n)``: the live slots in slot order, then the last of them
+    again to the end (slot 0 where none is live), as int32 ``[B]``, and
+    how many are live (int32 ``[1]``). A comparison against the running
+    count, as ``grouped_matmul``'s visit list."""
+    b = live.shape[0]
+    upto = jnp.cumsum(live.astype(jnp.int32))          # live slots through s
+    n = upto[-1]
+    visit = jnp.minimum(jnp.arange(b, dtype=jnp.int32), n - 1)
+    slots = (upto[None, :] <= visit[:, None]).sum(1, dtype=jnp.int32)
+    return jnp.where(n > 0, slots, 0), n.reshape(1)
+
+
+def _kernel(slots_ref, n_ref, decay_ref, x_ref, b_ref, c_ref, state_ref,
+            y_ref, new_ref, *, per_group):
+    # one live slot, a row of heads at a time: the row's state
+    # [heads x P, N] turned to [N, heads x P], where each lane's decay and
+    # dt u are a row to broadcast down and B and C a column to broadcast
+    # across, y a sum down the sublanes; the new state turned back. A visit
+    # past the live slots computes and writes nothing.
+    n = n_ref[0]
+
+    @pl.when(pl.program_id(0) < n)
+    def _():
+        rows, width = x_ref.shape
+        heads, p, nn = state_ref.shape
+        r_heads = heads // rows
+        per_row = per_group // r_heads                  # rows of a group
+
+        def group(k, carry):
+            g = pl.ds(k, 1)
+            b_col = jnp.broadcast_to(b_ref[g, :], (width, nn)).T
+            c_col = jnp.broadcast_to(c_ref[g, :], (width, nn)).T
+
+            def row(i, carry):
+                r = k * per_row + i
+                mine = pl.ds(r * r_heads, r_heads)
+                s_t = state_ref[mine].reshape(width, nn).T      # [N, width]
+                new_t = (s_t * decay_ref[pl.ds(r, 1), :]
+                         + b_col * x_ref[pl.ds(r, 1), :])
+                new_ref[mine] = new_t.T.reshape(r_heads, p, nn)
+                y_ref[pl.ds(r, 1), :] = jnp.sum(new_t * c_col, axis=0,
+                                                keepdims=True)
+                return carry
+            # a group's rows written out, the groups in a loop: as fast as
+            # every row written out, and an eighth of its trace and lowering
+            return jax.lax.fori_loop(0, per_row, row, carry, unroll=True)
+        jax.lax.fori_loop(0, rows // per_row, group, 0)
+
+    @pl.when(n == 0)
+    def _():
+        # no slot live: the one slot the grid holds goes back as it came
+        new_ref[...] = state_ref[...]
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def ssm_step_live(u: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+                  c: jax.Array, d: jax.Array, state: jax.Array,
+                  live: jax.Array, *, interpret: bool = False
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """:func:`ssm_step` with ``live``, reading and writing the state of the
+    live slots alone: the new state is written into ``state``'s buffer
+    (donated by the caller, it is updated in place) and a dead slot's is
+    never read or written. ``y`` of a dead row is 0. The arithmetic is
+    :func:`ssm_step`'s, in float32, a row's result its own. A grid step
+    is one slot, its whole state a block."""
+    bsz, h, p, n = state.shape
+    g = b.shape[1]
+    r = row_heads(p, h // g)
+    rows = lambda x: x.reshape(bsz, h // r, r * p)     # r heads x P lanes
+    uf = u.astype(F32)
+    dt = dt.astype(F32)
+    slots, count = slot_visits(live)
+    # past the live slots a visit holds the last live one's slot: no bytes
+    lanes = pl.BlockSpec((None, h // r, r * p),
+                         lambda v, s, cnt: (s[v], 0, 0))
+    groups = pl.BlockSpec((None, g, n), lambda v, s, cnt: (s[v], 0, 0))
+    slab = pl.BlockSpec((None, h, p, n), lambda v, s, cnt: (s[v], 0, 0, 0))
+    # a slot's state in and out, each twice (the next streams in, the last
+    # streams out), and room for the kernel's own values
+    vmem = 4 * h * p * n * 4 + 8 * 2**20
+    y, new = pl.pallas_call(
+        partial(_kernel, per_group=h // g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bsz,),
+            in_specs=[lanes, lanes, groups, groups, slab],
+            out_specs=[lanes, slab]),
+        out_shape=[jax.ShapeDtypeStruct((bsz, h // r, r * p), F32),
+                   jax.ShapeDtypeStruct(state.shape, F32)],
+        # inputs counted with the two prefetched: the state is the 7th
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(16 * 2**20, vmem)),
+        interpret=interpret,
+        name="ssm_step_live",
+    )(slots, count, rows(jnp.repeat(jnp.exp(dt * a.astype(F32)), p, axis=-1)),
+      rows(dt[..., None] * uf), b.astype(F32), c.astype(F32), state)
+    y = y.reshape(bsz, h, p) + d.astype(F32)[:, None] * uf
+    return jnp.where(live[:, None, None], y, 0.0), new

@@ -559,6 +559,7 @@ KERNELS = {
         "sparse_attn_fwd", "sparse_attn_bwd_dq", "sparse_attn_bwd_dkv"],
     "ops/adam/fused_update.py": ["fused_adam"],
     "ops/grouped_matmul.py": ["moe_gmm"],
+    "ops/ssm.py": ["ssm_step_live"],
 }
 
 

@@ -229,10 +229,13 @@ def test_the_default_decode_reads_the_live_blocks_and_no_window(
 
 @pytest.fixture
 def the_platform_says_tpu(monkeypatch):
-    """What the expert layer's platform probe answers for a described v5e
-    (it reads ``jax.devices()``, which sees the CPU here)."""
+    """What the expert layer's and the recurrence's platform probes answer
+    for a described v5e (they read ``jax.devices()``, which sees the CPU
+    here)."""
     import deepspeed_tpu.moe.dropless as dropless
+    import deepspeed_tpu.ops.ssm as ssm
     monkeypatch.setattr(dropless, "on_tpu", lambda: True)
+    monkeypatch.setattr(ssm, "on_tpu", lambda: True)
 
 
 def glm_expert_layer_lowered(v5e_sharding):
@@ -301,8 +304,10 @@ def test_the_nemotron_h_mixers_at_the_cells_widths(phase, v5e_sharding,
     scan of a prompt), grouped-query attention over the paged window, and
     the latent experts, whose two matmuls reach the chip as ``moe_gmm``
     (a few rows a held expert: 0.3 at decode, 11 in the prompt) and not
-    as XLA's grouped-matmul kernel. The whole cell at its real size:
-    ``tools/rehearse_serving_compile.py``."""
+    as XLA's grouped-matmul kernel. The decode step's recurrence is
+    ``ssm_step_live``, updating the donated state in place: no copy of a
+    layer's state anywhere; a prompt's scan has no kernel. The whole cell
+    at its real size: ``tools/rehearse_serving_compile.py``."""
     import jax.numpy as jnp
     from deepspeed_tpu.models.nemotron_h import NemotronH, NemotronHConfig
     from deepspeed_tpu.serving.kv_cache import (PagedLayerCache,
@@ -340,6 +345,13 @@ def test_the_nemotron_h_mixers_at_the_cells_widths(phase, v5e_sharding,
         ints = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
         args = (params, pools, ints(slots, blocks), ints(slots), ints(slots),
                 on_chip(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
-    hlo = jax.jit(run).lower(*args).compile().as_text()
+    donated = (1,) if phase == "decode" else ()
+    hlo = jax.jit(run, donate_argnums=donated).lower(*args).compile(
+        ).as_text()
     assert len(set(re.findall(r"%(moe_gmm[.\d]*) = ", hlo))) == 2
     assert "ragged-dot" not in hlo
+    kernels = set(re.findall(r"%(ssm_step_live[.\d]*) = ", hlo))
+    assert len(kernels) == (phase == "decode")
+    if phase == "decode":
+        state = slots * math.prod(pools[1][0].shape[1:])
+        assert not _results(hlo, state, ("copy",))
